@@ -212,11 +212,12 @@ def main(argv=None):
                          "config per round; min over rounds)")
     args = ap.parse_args(argv)
 
-    from benchmark.harness import enable_compile_cache, sanitize_bench_row
+    from benchmark.harness import sanitize_bench_row
+    from paddle_tpu.utils import compile_cache
     from paddle_tpu.observe import regress as observe_regress
     from paddle_tpu.observe import steplog
 
-    enable_compile_cache()
+    compile_cache.enable()
     model_kw = dict(vocab=1000, labels=32, hidden=args.hidden, emb=32)
     workdir = tempfile.mkdtemp(prefix="exp_checkpoint_")
     try:

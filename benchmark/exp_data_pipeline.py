@@ -182,10 +182,11 @@ def main(argv=None):
                     help="padding A/B only (no device work)")
     args = ap.parse_args(argv)
 
-    from benchmark.harness import enable_compile_cache, sanitize_bench_row
+    from benchmark.harness import sanitize_bench_row
+    from paddle_tpu.utils import compile_cache
     from paddle_tpu.observe import steplog
 
-    enable_compile_cache()
+    compile_cache.enable()
     rows = []
     if not args.skip_feed:
         rows += measure_feed_ab(args.steps, args.batch)

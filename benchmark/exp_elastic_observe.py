@@ -219,11 +219,12 @@ def main(argv=None):
                          "dir per round; median skew over rounds)")
     args = ap.parse_args(argv)
 
-    from benchmark.harness import enable_compile_cache, sanitize_bench_row
+    from benchmark.harness import sanitize_bench_row
+    from paddle_tpu.utils import compile_cache
     from paddle_tpu.observe import regress as observe_regress
     from paddle_tpu.observe import steplog
 
-    enable_compile_cache()
+    compile_cache.enable()
     model_kw = dict(vocab=200, labels=16, hidden=args.hidden, emb=16)
     samples = _tagging_samples(args.steps * args.batch, seed=0,
                                vocab=model_kw["vocab"],

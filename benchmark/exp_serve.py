@@ -713,8 +713,8 @@ def measure_quant_ab(args):
     .replicas_that_fit); (3) zero post-warmup compiles on either side
     (``watch_compiles``). The qps delta is recorded, not gated: on a
     CPU host the dequant multiply costs FLOPs it saves in HBM reads —
-    the bandwidth win is the on-chip rerun's to prove
-    (benchmark/RESULTS.md)."""
+    the bandwidth win is the on-chip rerun's to prove (ROADMAP
+    Queue 1 #12)."""
     from paddle_tpu.analyze.topology_check import hbm_budget_bytes
     from paddle_tpu.observe import steplog as observe_steplog
     from paddle_tpu.observe.metrics import MetricsRegistry
@@ -1136,6 +1136,12 @@ def measure_hosts_ab(args):
                PYTHONPATH=(repo_root + os.pathsep
                            + os.environ.get("PYTHONPATH", "")))
     env.pop("PADDLE_TPU_TELEMETRY", None)  # hosts log to their own runs
+    # this process exported the bundle and ran the reference, so on a
+    # TPU host it holds the chips the serving hosts would need
+    from paddle_tpu.core.place import enforce_children_can_open_devices
+
+    enforce_children_can_open_devices(
+        n_hosts, "exp_serve --mode hosts-ab", env=env)
     port, coord = spawn_coordinator_on_free_port()
     endpoint = "127.0.0.1:%d" % port
     store = subprocess.Popen(
@@ -2022,9 +2028,9 @@ def main(argv=None):
     if args.hardcap_queue is None:
         args.hardcap_queue = 2 * args.decode_slots
 
-    from benchmark.harness import enable_compile_cache
+    from paddle_tpu.utils import compile_cache
 
-    enable_compile_cache()
+    compile_cache.enable()
     if args.mode == "openloop-ab":
         return _emit(measure_openloop_ab(args), "exp_serve_openloop")
     if args.mode == "priority":

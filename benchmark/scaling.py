@@ -25,11 +25,6 @@ import sys
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
                                 ".."))
 
-if os.environ.get("JAX_PLATFORMS") == "cpu":
-    from paddle_tpu.utils.cpu_mesh import force_cpu_backend
-
-    force_cpu_backend()
-
 from benchmark.harness import chain_slope_ms
 
 

@@ -83,15 +83,34 @@ def init(use_tpu=None, trainer_count=1, seed=None, log_level=None, **kwargs):
 
     Parity with ``paddle.v2.init(use_gpu=..., trainer_count=...)`` (reference:
     python/paddle/v2/__init__.py + paddle/utils/Flags.cpp flag plumbing), but
-    flags configure JAX/XLA instead of gflags: ``use_tpu`` selects the default
-    Place, ``trainer_count`` declares the data-parallel width used by
-    :mod:`paddle_tpu.parallel` when building the device mesh.
+    flags configure JAX/XLA instead of gflags: ``use_tpu=True`` demands a
+    TPU (an ``EnforceError`` names what ``jax.devices()`` returned when
+    there is none — nothing trains on a CPU by accident), ``False`` pins
+    the process to the CPU platform, ``None`` takes JAX's default backend.
+    ``trainer_count`` declares the data-parallel width used by
+    :mod:`paddle_tpu.parallel` when building the device mesh. Also places
+    the persistent compile cache (:mod:`paddle_tpu.utils.compile_cache`).
     """
     global _initialized
     import jax
 
-    if use_tpu is None:
-        use_tpu = any(d.platform != "cpu" for d in jax.devices())
+    from paddle_tpu.core.place import backend_initialized, tpu_devices
+    from paddle_tpu.utils import compile_cache
+
+    compile_cache.enable()
+    if use_tpu is not None and not use_tpu:
+        # really the CPU: pin the platform while that is still possible,
+        # and refuse once JAX has already opened another backend
+        if not backend_initialized():
+            jax.config.update("jax_platforms", "cpu")
+        enforce(jax.default_backend() == "cpu",
+                "init(use_tpu=False) after JAX initialised the %r backend: "
+                "call init before any other JAX use, or set "
+                "JAX_PLATFORMS=cpu", jax.default_backend())
+    elif use_tpu:
+        tpu_devices()  # EnforceError naming jax.devices() when none is a TPU
+    else:
+        use_tpu = jax.default_backend() == "tpu"
     _flags.set_flag("use_tpu", bool(use_tpu))
     _flags.set_flag("trainer_count", int(trainer_count))
     if seed is not None:

@@ -397,16 +397,25 @@ def _make_engine(bundle, args, reg, model=None, warmup="async",
         # multi-process data plane (docs/serving.md "Worker
         # processes"): each replica as its own OS worker process behind
         # the same duck-typed fleet front — the GIL-free path
+        from paddle_tpu.core.place import host_tpu_chips
         from paddle_tpu.serve import WorkerSet
-        from paddle_tpu.serve.fleet import auto_replicas
+        from paddle_tpu.serve.fleet import replicas_that_fit
 
-        # "auto" sizes like --replicas auto (one per device, or the
-        # manifest-HBM count under PADDLE_TPU_HBM_BUDGET) and then caps
-        # at the host's core count — worker PROCESSES beyond the cores
-        # only add context-switch overhead, never throughput
-        n = (min(auto_replicas(bundle, budget=budget_share),
-                 os.cpu_count() or 1)
-             if workers == "auto" else int(workers))
+        # "auto" must size the fleet WITHOUT opening a device: this
+        # parent only routes, and on a TPU host a parent that has
+        # touched JAX holds the chips its workers need. One worker per
+        # core (worker PROCESSES beyond the cores only add
+        # context-switch overhead), fewer when PADDLE_TPU_HBM_BUDGET
+        # fits fewer parameter copies; on a TPU host one worker, the
+        # only width that can open the chips (WorkerSet refuses more)
+        if workers != "auto":
+            n = int(workers)
+        elif host_tpu_chips():
+            n = 1
+        else:
+            cores = os.cpu_count() or 1
+            fit = replicas_that_fit(bundle, budget_share)
+            n = cores if fit is None else min(cores, fit)
         kwargs = (dict({"max_queue": args.max_queue_rows},
                        **_session_kwargs(args)) if args.continuous
                   else {"max_batch_size": args.max_batch_size,
@@ -703,20 +712,17 @@ def cmd_serve(args):
     controller = _make_controller(slo, [engine], args, model=bundle.name)
     heartbeat = None
     with contextlib.ExitStack() as stack:
-        compiles_fn = None
-        if join:
-            # post-warmup compile counter behind GET /debug/compiles:
-            # the hosts-ab bench diffs it across the chaos window to
-            # prove re-homed sessions resume without recompiling
-            from paddle_tpu.observe import steplog as observe_steplog
+        # process-wide compile counter behind GET /debug/compiles: a
+        # client diffs it across a window to prove serving compiled
+        # nothing after warm-up (chip_smoke.py; the hosts-ab bench does
+        # the same across its chaos window)
+        from paddle_tpu.observe import steplog as observe_steplog
 
-            watcher = stack.enter_context(
-                observe_steplog.watch_compiles())
-            compiles_fn = (lambda: watcher.compiles)
+        watcher = stack.enter_context(observe_steplog.watch_compiles())
         server = make_server(bundle, engine, host=args.host,
                              port=args.port, slo=slo,
                              controller=controller,
-                             compiles_fn=compiles_fn)
+                             compiles_fn=lambda: watcher.compiles)
         if join:
             from paddle_tpu.distributed.client import encode_host_meta
             from paddle_tpu.distributed.elastic import HeartbeatThread
@@ -1277,7 +1283,13 @@ def main(argv=None):
     p.add_argument("-o", "--output", required=True)
     p.set_defaults(fn=cmd_merge_model)
 
-    p = sub.add_parser("export")
+    # export/serve take the same device demand as the training jobs:
+    # fail instead of exporting or serving on another backend
+    use_tpu = argparse.ArgumentParser(add_help=False)
+    use_tpu.add_argument("--use-tpu", action="store_true", default=None,
+                         help="demand a TPU: an error names what "
+                              "jax.devices() returned when there is none")
+    p = sub.add_parser("export", parents=[use_tpu])
     p.add_argument("--config", default="")
     p.add_argument("--builder", default="")
     p.add_argument("--config-args", default="")
@@ -1326,7 +1338,7 @@ def main(argv=None):
                         "exported slot capacity)")
     p.set_defaults(fn=cmd_generate)
 
-    p = sub.add_parser("serve")
+    p = sub.add_parser("serve", parents=[use_tpu])
     p.add_argument("bundle", nargs="?", default="",
                    help="exported bundle directory (single-model mode)")
     p.add_argument("--model", action="append", default=[],
@@ -1444,6 +1456,9 @@ def main(argv=None):
     p.set_defaults(fn=cmd_serve)
 
     args = parser.parse_args(argv)
+    from paddle_tpu.utils import compile_cache
+
+    compile_cache.enable()  # before this process's first compile
     if getattr(args, "use_tpu", None) is not None \
             and args.fn is not cmd_cluster_train:
         # the cluster launcher must NOT touch jax in the parent: device

@@ -78,17 +78,22 @@ def main(argv=None):
     # metric labels all name it — overwrite, the launcher's choice wins
     os.environ["PADDLE_TPU_TRAIN_WORKER"] = "trainer-%d" % args.process_id
 
-    if args.use_tpu:
-        import paddle_tpu as paddle
-
-        paddle.init(use_tpu=True)
-
     from paddle_tpu.distributed.multihost import initialize_multihost
+    from paddle_tpu.utils import compile_cache
+
+    compile_cache.enable()  # before this process's first compile
 
     ok = initialize_multihost(coordinator_address=args.coordinator,
                               num_processes=args.num_processes,
                               process_id=args.process_id)
     assert ok, "jax.distributed initialization failed"
+
+    if args.use_tpu:
+        # after jax.distributed: init enumerates devices, which must not
+        # open the backend before the process group exists
+        import paddle_tpu as paddle
+
+        paddle.init(use_tpu=True)
 
     import jax
 

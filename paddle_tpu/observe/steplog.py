@@ -183,10 +183,7 @@ def _ensure_monitoring_listener():
     """Register the ONE process-wide jax.monitoring duration listener
     (registration is append-only in jax — there is no unregister)."""
     global _listener_registered
-    try:
-        from jax import monitoring
-    except Exception:
-        return
+    from jax import monitoring
 
     def _listener(event, secs, **kw):
         # snapshot under the same lock the writers take: WeakSet
@@ -202,11 +199,10 @@ def _ensure_monitoring_listener():
     with _registry_lock:
         if _listener_registered:
             return
-        try:
-            monitoring.register_event_duration_secs_listener(_listener)
-            _listener_registered = True
-        except Exception:
-            pass
+        # plainly: a registration that failed quietly would make every
+        # "zero compiles after warm-up" check pass by counting nothing
+        monitoring.register_event_duration_secs_listener(_listener)
+        _listener_registered = True
 
 
 class CompileWatcher:
@@ -427,6 +423,7 @@ class StepLog:
             tflops, mfu = achieved(self._flops, lead_ms)
             if tflops is not None:
                 rec["tflops"] = round(tflops, 2)
+            if mfu is not None:  # only devices attribution.DEVICE_PEAKS lists
                 rec["mfu_pct"] = round(mfu, 2)
         if metrics:
             rec["metrics"] = {k: float(v) for k, v in metrics.items()

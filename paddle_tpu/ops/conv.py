@@ -126,7 +126,7 @@ def stem_s2d_eligible(c, fh, fw, sh, sw, ph, pw, groups, dilation, trans):
         return False
     if mode == "on":
         return sh == sw and sh >= 2
-    # measured on v5e (RESULTS.md): the 11x11/s4 AlexNet stem gains
+    # measured on v5e (round 4, before PR 1): the 11x11/s4 AlexNet stem gains
     # (s*s*C = 48 contraction lanes vs 3), but the 7x7/s2 ResNet/GoogleNet
     # stem REGRESSES 27.2->35.2ms — XLA's native handling of the s2 stem
     # was already fine and the s2d reshapes cost HBM traffic — so auto

@@ -103,6 +103,37 @@ def _check_reset(reset_bt, reverse):
             "PackedSequenceBatch.reverse() and scan forward")
 
 
+def _per_device(fused, batch, arg_bdims, out_bdims):
+    """How a fused Pallas scan runs over a batch of ``batch`` rows:
+    ``(fn, rows)`` — the callable and the rows one kernel instance sees —
+    or ``(None, None)`` when it cannot run fused at all.
+
+    XLA cannot partition a Mosaic kernel, so inside a data-parallel step
+    (parallel.mesh.batch_axis_scope) the kernel is shard_mapped over the
+    batch axis and each device scans its own rows; ``*_bdims`` give the
+    batch dimension of every argument and output (None = replicated)."""
+    from paddle_tpu.parallel.mesh import current_batch_axis
+
+    scope = current_batch_axis()
+    if scope is None or scope[0].size == 1:
+        return fused, batch
+    mesh, axis = scope
+    shards = mesh.shape[axis]
+    if batch % shards:  # DataParallel.shard_batch replicates such a batch
+        return None, None
+    from jax import shard_map
+    from jax.sharding import PartitionSpec as P
+
+    def spec(bdim):
+        return P() if bdim is None else P(*([None] * bdim + [axis]))
+
+    return (shard_map(fused, mesh=mesh,
+                      in_specs=tuple(spec(d) for d in arg_bdims),
+                      out_specs=tuple(spec(d) for d in out_bdims),
+                      check_vma=False),
+            batch // shards)
+
+
 def lstm_scan(x_btd, mask_bt, w_in, b, w_rec, h0=None, c0=None,
               gate_act=jax.nn.sigmoid, state_act=jnp.tanh, reverse=False,
               use_peephole=False, w_peep=None, standard_acts=None,
@@ -157,12 +188,20 @@ def lstm_scan(x_btd, mask_bt, w_in, b, w_rec, h0=None, c0=None,
     # hl_cuda_lstm.cu handles all sizes). Only the real TPU backend (or the
     # tests' explicit interpret flag) takes this path — other backends
     # where pallas merely imports would fail at lowering.
+    fused = rows = None
     if (reset_bt is None and pk.enabled() and standard_acts
-            and gates_tm.dtype in (jnp.float32, jnp.bfloat16)
-            and pk.lstm_mode(b_, hidden, gates_tm.dtype) is not None):
-        h_seq_tm, h_f, c_f = pk.lstm_fused(
+            and gates_tm.dtype in (jnp.float32, jnp.bfloat16)):
+        # args: gates [T,B,4H], mask [T,B], w_rec, h0 [B,H], c0 [B,H]
+        # (, w_peep) -> h_seq [T,B,H], h_f [B,H], c_f [B,H]
+        fused, rows = _per_device(
+            pk.lstm_fused, b_,
+            (1, 1, None, 0, 0) + ((None,) if use_peephole else ()),
+            (1, 0, 0))
+    if fused is not None and pk.lstm_mode(rows, hidden,
+                                          gates_tm.dtype) is not None:
+        h_seq_tm, h_f, c_f = fused(
             gates_tm, mask_tm.astype(jnp.float32), w_rec, h0, c0,
-            w_peep if use_peephole else None)
+            *((w_peep,) if use_peephole else ()))
         ys = h_seq_tm
     else:
         step = partial(lstm_step, w_rec=w_rec, gate_act=gate_act,
@@ -226,12 +265,18 @@ def gru_scan(x_btd, mask_bt, w_in, b, w_rec_rz, w_rec_c, h0=None,
     from paddle_tpu.ops import pallas_kernels as pk
 
     standard = gate_act is jax.nn.sigmoid and state_act is jnp.tanh
+    fused = rows = None
     if (reset_bt is None and pk.enabled() and standard
-            and proj_tm.dtype in (jnp.float32, jnp.bfloat16)
-            and pk.gru_mode(b_, hidden, proj_tm.dtype) is not None):
+            and proj_tm.dtype in (jnp.float32, jnp.bfloat16)):
+        # args: proj [T,B,3H], mask [T,B], w_rz, w_c, h0 [B,H]
+        # -> h_seq [T,B,H], h_f [B,H]
+        fused, rows = _per_device(pk.gru_fused, b_,
+                                  (1, 1, None, None, 0), (1, 0))
+    if fused is not None and pk.gru_mode(rows, hidden,
+                                         proj_tm.dtype) is not None:
         # fused whole-sequence GRU kernel (hl_gpu_gru.cuh parity)
-        ys, h_f = pk.gru_fused(proj_tm, mask_tm.astype(jnp.float32),
-                               w_rec_rz, w_rec_c, h0)
+        ys, h_f = fused(proj_tm, mask_tm.astype(jnp.float32),
+                        w_rec_rz, w_rec_c, h0)
     elif reset_bt is None:
         def body(carry, xs):
             p_t, m_t = xs

@@ -11,6 +11,7 @@ parameter server, no RPC, no gradient copy threads.
 """
 
 import contextlib
+import threading
 
 import numpy as np
 
@@ -115,9 +116,6 @@ class DataParallel:
 
     # step wrappers ----------------------------------------------------------
     def shard_train_step(self, train_step, trainer):
-        repl = self.replicated()
-        mesh = self.mesh
-
         jitted = jax.jit(
             train_step,
             donate_argnums=(0, 1, 3, 4),
@@ -126,8 +124,9 @@ class DataParallel:
 
         def run(trainable, replica, static, state, opt_state, feed, rng):
             feed = self.shard_batch(feed)
-            return jitted(trainable, replica, static, state, opt_state,
-                          feed, rng)
+            with batch_axis_scope(self.mesh, self.axis):
+                return jitted(trainable, replica, static, state, opt_state,
+                              feed, rng)
 
         return run
 
@@ -142,8 +141,9 @@ class DataParallel:
 
         def run(trainable, replica, static, state, opt_state, feeds, rng):
             feeds = tuple(self.shard_batch(f) for f in feeds)
-            return jitted(trainable, replica, static, state, opt_state,
-                          feeds, rng)
+            with batch_axis_scope(self.mesh, self.axis):
+                return jitted(trainable, replica, static, state, opt_state,
+                              feeds, rng)
 
         return run
 
@@ -152,13 +152,39 @@ class DataParallel:
 
         def run(trainable, static, state, feed):
             feed = self.shard_batch(feed)
-            return jitted(trainable, static, state, feed)
+            with batch_axis_scope(self.mesh, self.axis):
+                return jitted(trainable, static, state, feed)
 
         return run
 
     def __repr__(self):
         return "DataParallel(mesh=%s, axis=%r)" % (
             dict(self.mesh.shape), self.axis)
+
+
+# -- batch-axis scope (kernels XLA cannot partition) --------------------------
+# A Mosaic kernel inside a multi-device jit does not lower: XLA cannot
+# partition a custom call ("Mosaic kernels cannot be automatically
+# partitioned"). While a data-parallel step is being traced this slot names
+# the mesh and the axis its batch is split over, so ops/rnn.py can
+# shard_map its fused scans and run each on the device's own rows.
+# Thread-local: a trace runs on the thread that calls the jitted step.
+_batch_axis = threading.local()
+
+
+def current_batch_axis():
+    """``(mesh, axis)`` of the data-parallel step being traced, or None."""
+    return getattr(_batch_axis, "scope", None)
+
+
+@contextlib.contextmanager
+def batch_axis_scope(mesh, axis):
+    prev = current_batch_axis()
+    _batch_axis.scope = (mesh, axis)
+    try:
+        yield
+    finally:
+        _batch_axis.scope = prev
 
 
 # -- active-mesh context (per-layer sharding constraints) --------------------

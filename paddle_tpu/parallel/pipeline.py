@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from paddle_tpu.parallel.shard_map_compat import shard_map
+from jax import shard_map
 
 from paddle_tpu.utils.error import enforce
 

@@ -342,17 +342,9 @@ def export_bundle(output_layer, parameters, out_dir,
                     "it through the ordinary batch buckets")
             # the carry is donated: slot state never round-trips the
             # host and the scheduler's step is a true in-place update
-            jitted_step = jax.jit(step, donate_argnums=(1,))
-            try:
-                exported_step = jax_export.export(
-                    jitted_step, **export_kwargs)(
-                        param_structs, state_structs, flat_structs)
-            except Exception:
-                # donation support varies across jax.export versions;
-                # the step stays correct without it, only less frugal
-                exported_step = jax_export.export(
-                    jax.jit(step), **export_kwargs)(
-                        param_structs, state_structs, flat_structs)
+            exported_step = jax_export.export(
+                jax.jit(step, donate_argnums=(1,)), **export_kwargs)(
+                    param_structs, state_structs, flat_structs)
             artifact = "step_s%d.jaxexp" % slots
             with open(os.path.join(out_dir, artifact), "wb") as fh:
                 fh.write(exported_step.serialize())

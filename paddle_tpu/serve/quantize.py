@@ -1,7 +1,7 @@
 """Weight-only int8 quantization for serving bundles (docs/serving.md
 "Quantized bundles").
 
-The round-4/5 bf16 read-replica experiments (benchmark/RESULTS.md)
+The round-4/5 bf16 read-replica experiments (benchmark/exp_bf16_replica.py)
 proved that lower-precision READS of full-precision masters win on HBM
 traffic without losing accuracy; this module pushes the same move one
 step further for the serve tier: ``cli export --quantize int8`` stores

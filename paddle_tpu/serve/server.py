@@ -236,7 +236,7 @@ class _Handler(_BaseHandler):
             # a re-homed session re-used the survivor's warm caches
             if self.compiles_fn is None:
                 self._send(404, {"error": "no compile watcher on this "
-                                          "server (serve --join)"})
+                                          "server"})
             else:
                 self._send(200, {"compiles": int(self.compiles_fn())})
         elif self.path == "/manifest":
@@ -427,9 +427,12 @@ def make_server(bundle, engine, host="127.0.0.1", port=0, slo=None,
     ``GET /debug/compiles``."""
     if slo is None:
         slo = observe_health.SloMonitor([engine])
+    # staticmethod: a plain function stored on the class would be bound
+    # and called with the handler as its argument
     handler = type("BundleHandler", (_Handler,),
                    {"engine": engine, "bundle": bundle, "slo": slo,
-                    "controller": controller, "compiles_fn": compiles_fn})
+                    "controller": controller,
+                    "compiles_fn": compiles_fn and staticmethod(compiles_fn)})
     return ThreadingHTTPServer((host, port), handler)
 
 

@@ -394,6 +394,9 @@ def _worker_main(index, bundle_dir, continuous, engine_kwargs, model,
 
     import jax
 
+    from paddle_tpu.utils import compile_cache
+
+    compile_cache.enable()  # before the child's first compile
     from paddle_tpu.observe import steplog as slog_mod
     from paddle_tpu.observe import tracing as tracing_mod
     from paddle_tpu.serve.bundle import load_bundle
@@ -1024,6 +1027,9 @@ class WorkerSet:
         n = 1 if workers is None else int(workers)
         if n < 1:
             raise ValueError("workers must be >= 1, got %r" % workers)
+        from paddle_tpu.core.place import enforce_children_can_open_devices
+
+        enforce_children_can_open_devices(n, "WorkerSet(workers=%d)" % n)
         self.bundle = bundle
         self.model = model
         self.continuous = bool(continuous)

@@ -192,12 +192,7 @@ class Topology:
                 note = "  in layer %r (type %s), inputs: %s" % (
                     node.name, node.layer_type,
                     [p.name for p in node.inputs])
-                if hasattr(exc, "add_note"):  # PEP 678, python >= 3.11
-                    exc.add_note(note)
-                elif exc.args and isinstance(exc.args[0], str):
-                    exc.args = (exc.args[0] + "\n" + note,) + exc.args[1:]
-                else:
-                    exc.args = exc.args + (note,)
+                exc.add_note(note)
                 raise
         return values
 

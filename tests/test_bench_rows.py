@@ -25,7 +25,7 @@ def test_collapsed_wall_demoted():
     assert rec["wall_collapsed_ms"] == 0.039
     # the published value stays device-derived, untouched
     assert rec["value"] == 54515.5 and rec["device_ms"] == 0.587
-    assert "tunnel-collapsed" in rec["sanity_note"]
+    assert "collapsed chain" in rec["sanity_note"]
 
 
 def test_excess_spread_demoted():

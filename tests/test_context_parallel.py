@@ -52,7 +52,11 @@ def test_ulysses_matches_full(qkv, seq_mesh, causal):
                                rtol=2e-5, atol=2e-5)
 
 
-@pytest.mark.parametrize("strategy", ["ring", "ulysses"])
+# the ring case compiles for ~40 s on one core: tier-1 has no such room
+# (ROADMAP Queue 3, "Tier-1 sits at 93% of its timeout"); its forward
+# stays in tier-1 (test_ring_matches_full), its gradient runs under -m slow
+@pytest.mark.parametrize("strategy", [
+    pytest.param("ring", marks=pytest.mark.slow), "ulysses"])
 def test_gradients_match_full(qkv, seq_mesh, strategy):
     q, k, v = qkv
     sp = SequenceParallel(seq_mesh, strategy=strategy)

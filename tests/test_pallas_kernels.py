@@ -14,9 +14,8 @@ from paddle_tpu.ops import rnn as rnn_ops
 
 pytestmark = pytest.mark.skipif(
     not pk.available(),
-    reason="pallas unavailable in stripped CPU env (tpu platform lowerings "
-           "not registered); the fused path is exercised on the real chip "
-           "by bench.py and the driver's compile check")
+    reason="PADDLE_TPU_DISABLE_PALLAS is set; the fused path on the chip "
+           "itself is checked by chip_smoke.py")
 
 @pytest.fixture(autouse=True)
 def _interpret_mode(monkeypatch):
@@ -338,3 +337,35 @@ def test_int8_matmul_gate_defaults_to_xla_path():
     want = np.asarray(x) @ (np.asarray(q, np.float32)
                             * np.asarray(scale))
     np.testing.assert_allclose(np.asarray(out), want, atol=1e-5)
+
+
+def test_fused_scans_shard_over_the_batch_in_a_data_parallel_step():
+    """XLA cannot partition a Mosaic kernel, so inside a DataParallel step
+    (parallel.mesh.batch_axis_scope) the fused scans run under shard_map,
+    each device on its own rows — and the weight gradient still sums over
+    all of them. On the chip the unwrapped call does not even lower
+    (chip_smoke.py --chips 4 is the check there, GRU included)."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from paddle_tpu.parallel.mesh import batch_axis_scope, build_mesh
+
+    gates, mask, w = _inputs()
+
+    def loss(g, w):
+        h_seq, (h_f, c_f) = _fused_path(g, mask, w)
+        return jnp.sum(h_seq ** 2) + jnp.sum(h_f) + jnp.sum(c_f)
+
+    grad = jax.value_and_grad(loss, argnums=(0, 1))
+    want = jax.jit(grad)(gates, w)
+    mesh = build_mesh({"data": 4}, devices=jax.devices()[:4])
+    rows = jax.device_put(gates, NamedSharding(mesh, P("data")))
+    with batch_axis_scope(mesh, "data"):
+        lowered = jax.jit(grad).lower(rows, w)
+        assert "manual" in lowered.as_text()  # the shard_map is there
+        got = lowered.compile()(rows, w)
+        # a batch the mesh does not divide stays on the scan path
+        assert rnn_ops._per_device(pk.lstm_fused, 6, (1,), (1,)) \
+            == (None, None)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-4, atol=1e-5)
