@@ -26,8 +26,9 @@ Any stage that fails makes the run exit non-zero. The last line of stdout
 is {"ok": true, "device": {...}} only when every stage passed on a TPU.
 
 --dry-run-cpu runs the same stages at a tiny size on the CPU backend
-(Pallas in interpret mode) to debug the script itself; its summary says so
-and it proves nothing about the chip.
+(Pallas in interpret mode) to debug the script itself. It proves nothing
+about the chip: its last line is {"dry_run_ok": true, ...}, never "ok", and
+its report and logs are chiprun_out/*_dryrun.{json,log}.
 """
 
 import argparse
@@ -93,11 +94,12 @@ def require(cond, msg, *args):
 # ======================================================================
 
 _children = []
+_suffix = ""  # "_dryrun" on a CPU dry run: its files never pass for a chip's
 
 
 def _spawn(name, cmd, env):
     os.makedirs(OUT, exist_ok=True)
-    log = open(os.path.join(OUT, "smoke_%s.log" % name), "w")
+    log = open(os.path.join(OUT, "smoke_%s%s.log" % (name, _suffix)), "w")
     proc = subprocess.Popen(cmd, stdout=log, stderr=subprocess.STDOUT,
                             cwd=REPO, env=env)
     proc.log_path = log.name
@@ -256,7 +258,9 @@ def _serve_stage(args, cfg, env, use_tpu):
 
 
 def parent(args):
+    global _suffix
     cfg = TINY if args.dry_run_cpu else FULL
+    _suffix = "_dryrun" if args.dry_run_cpu else ""
     shutil.rmtree(WORK, ignore_errors=True)
     os.makedirs(WORK)
     os.makedirs(OUT, exist_ok=True)
@@ -282,14 +286,13 @@ def parent(args):
             summary["serve"] = _serve_stage(args, cfg, env, use_tpu)
     finally:
         _stop_children()
-    with open(os.path.join(OUT, "chip_smoke_%s.json" % summary["mode"]),
-              "w") as fh:
+    with open(os.path.join(OUT, "chip_smoke_%s%s.json"
+                           % (summary["mode"], _suffix)), "w") as fh:
         json.dump(summary, fh, indent=1, sort_keys=True)
     print(json.dumps({"summary": summary}, sort_keys=True), flush=True)
-    verdict = {"ok": True, "device": summary["device"]}
-    if args.dry_run_cpu:
-        verdict["dry_run"] = True
-    print(json.dumps(verdict), flush=True)
+    # "ok" is the chip's word only: a dry run ends on another key
+    print(json.dumps({"dry_run_ok" if args.dry_run_cpu else "ok": True,
+                      "device": summary["device"]}), flush=True)
     return 0
 
 
@@ -331,10 +334,36 @@ def _rel_err(got, want):
         1.0, float(np.abs(want32).max()))
 
 
-def check_lstm_kernel(hidden, dtype_name, batch=8, t=12):
+def _run_check(both, args, mesh, kernel, label):
+    """Run ``both(*args)`` (scan path and fused path, value and grads).
+    With a mesh, args[0] is split on its batch axis over the mesh's "data"
+    axis and the call is traced under use_mesh(mesh, batch_axis="data"),
+    where the fused scan must lower as a shard_map round ``kernel``."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from paddle_tpu.ops import pallas_kernels as pk
+    from paddle_tpu.parallel.mesh import use_mesh
+
+    if mesh is None:
+        return jax.device_get(both(*args))
+    args = (jax.device_put(args[0], NamedSharding(mesh, P("data"))),
+            *args[1:])
+    with use_mesh(mesh, batch_axis="data"):
+        text = both.lower(*args).as_text()
+        require("manual_computation" in text, "%s: no shard_map in the "
+                "program lowered under the mesh", label)
+        if not pk._INTERPRET:
+            require('kernel_name = "%s' % kernel in text,
+                    "%s: no %s* Mosaic call under the mesh", label, kernel)
+        return jax.device_get(both(*args))
+
+
+def check_lstm_kernel(hidden, dtype_name, mesh=None, rows=8, t=12):
     """Fused LSTM (peephole on, as the flagship's lstmemory runs it) vs
     the lax.scan path on this backend: loss and every gradient within
-    GATE_TOL. Returns a label naming the kernel variant that ran."""
+    GATE_TOL, at ``rows`` rows on each device. Returns a label naming the
+    kernel variant that ran."""
     import numpy as np
 
     import jax
@@ -344,9 +373,10 @@ def check_lstm_kernel(hidden, dtype_name, batch=8, t=12):
     from paddle_tpu.ops import rnn as rnn_ops
 
     dtype = jnp.dtype(dtype_name)
-    mode = pk.lstm_mode(batch, hidden, dtype)
+    mode = pk.lstm_mode(rows, hidden, dtype)
     require(mode is not None, "no fused lstm mode for h=%d %s", hidden,
             dtype_name)
+    batch = rows * (mesh.size if mesh is not None else 1)
     rng = np.random.RandomState(hidden)
     gates = jnp.asarray(rng.randn(batch, t, 4 * hidden) * 0.3, dtype)
     lengths = rng.randint(1, t + 1, batch)
@@ -374,18 +404,17 @@ def check_lstm_kernel(hidden, dtype_name, batch=8, t=12):
                                  argnums=(0, 1, 2))(g, w, p)
         return ref, fus
 
-    (ref, gr), (fus, gf) = jax.device_get(both(gates, w, peep))
-    tol = GATE_TOL[dtype_name]
-    label = "lstm[h=%d,%s,%s,peephole]" % (hidden, dtype_name, mode)
-    require(abs(float(fus) - float(ref)) / max(1.0, abs(float(ref))) < tol,
-            "%s fwd mismatch: %r vs %r", label, float(fus), float(ref))
-    for got, want, nm in zip(gf, gr, ("dgates", "dw", "dpeep")):
-        require(_rel_err(got, want) < tol, "%s %s grad mismatch: rel %.4g",
-                label, nm, _rel_err(got, want))
+    label = "lstm[h=%d,%s,%s,peephole%s]" % (
+        hidden, dtype_name, mode,
+        ",%d devices" % mesh.size if mesh is not None else "")
+    (ref, gr), (fus, gf) = _run_check(both, (gates, w, peep), mesh,
+                                      "_lstm_fwd", label)
+    _require_close(label, GATE_TOL[dtype_name], ref, fus,
+                   zip(gf, gr, ("dgates", "dw", "dpeep")))
     return label
 
 
-def check_gru_kernel(hidden, dtype_name, batch=8, t=12):
+def check_gru_kernel(hidden, dtype_name, mesh=None, rows=8, t=12):
     """Fused GRU vs the lax.scan path; as check_lstm_kernel."""
     import numpy as np
 
@@ -396,8 +425,9 @@ def check_gru_kernel(hidden, dtype_name, batch=8, t=12):
     from paddle_tpu.ops import rnn as rnn_ops
 
     dtype = jnp.dtype(dtype_name)
-    require(pk.gru_mode(batch, hidden, dtype) is not None,
+    require(pk.gru_mode(rows, hidden, dtype) is not None,
             "no fused gru mode for h=%d %s", hidden, dtype_name)
+    batch = rows * (mesh.size if mesh is not None else 1)
     rng = np.random.RandomState(hidden + 7)
     proj = jnp.asarray(rng.randn(batch, t, 3 * hidden) * 0.3, dtype)
     lengths = rng.randint(1, t + 1, batch)
@@ -407,34 +437,47 @@ def check_gru_kernel(hidden, dtype_name, batch=8, t=12):
     w_c = jnp.asarray(rng.randn(hidden, hidden) / np.sqrt(hidden), dtype)
     sel = jnp.asarray(rng.randn(batch, t, hidden), jnp.float32)
 
-    def loss(p, wrz, wc):
-        h_seq, h_f = rnn_ops.gru_scan(p, mask, None, None, wrz, wc)
+    def loss(fused, p, wrz, wc):
+        # gru_scan takes its fused path only for jax.nn.sigmoid itself;
+        # any other callable, this equal one included, is the scan path
+        gate = jax.nn.sigmoid if fused else (lambda x: jax.nn.sigmoid(x))
+        h_seq, h_f = rnn_ops.gru_scan(p, mask, None, None, wrz, wc,
+                                      gate_act=gate)
         return (jnp.sum(h_seq.astype(jnp.float32) * sel)
                 + jnp.sum(h_f.astype(jnp.float32)))
 
-    grad = jax.value_and_grad(loss, argnums=(0, 1, 2))
-    fus, gf = jax.device_get(grad(proj, w_rz, w_c))
-    fused_mode, pk.gru_mode = pk.gru_mode, lambda *a: None  # scan path
-    try:
-        ref, gr = jax.device_get(grad(proj, w_rz, w_c))
-    finally:
-        pk.gru_mode = fused_mode
-    tol = GATE_TOL[dtype_name]
-    label = "gru[h=%d,%s]" % (hidden, dtype_name)
-    require(abs(float(fus) - float(ref)) / max(1.0, abs(float(ref))) < tol,
-            "%s fwd mismatch: %r vs %r", label, float(fus), float(ref))
-    for got, want, nm in zip(gf, gr, ("dproj", "dw_rz", "dw_c")):
-        require(_rel_err(got, want) < tol, "%s %s grad mismatch: rel %.4g",
-                label, nm, _rel_err(got, want))
+    @jax.jit
+    def both(p, wrz, wc):
+        ref = jax.value_and_grad(lambda *a: loss(False, *a),
+                                 argnums=(0, 1, 2))(p, wrz, wc)
+        fus = jax.value_and_grad(lambda *a: loss(True, *a),
+                                 argnums=(0, 1, 2))(p, wrz, wc)
+        return ref, fus
+
+    label = "gru[h=%d,%s%s]" % (
+        hidden, dtype_name,
+        ",%d devices" % mesh.size if mesh is not None else "")
+    (ref, gr), (fus, gf) = _run_check(both, (proj, w_rz, w_c), mesh,
+                                      "_gru_fwd", label)
+    _require_close(label, GATE_TOL[dtype_name], ref, fus,
+                   zip(gf, gr, ("dproj", "dw_rz", "dw_c")))
     return label
 
 
-def phase_kernels(cfg):
+def _require_close(label, tol, ref, fus, grads):
+    require(abs(float(fus) - float(ref)) / max(1.0, abs(float(ref))) < tol,
+            "%s fwd mismatch: %r vs %r", label, float(fus), float(ref))
+    for got, want, nm in grads:
+        require(_rel_err(got, want) < tol, "%s %s grad mismatch: rel %.4g",
+                label, nm, _rel_err(got, want))
+
+
+def phase_kernels(cfg, mesh=None):
     from paddle_tpu.ops import pallas_kernels as pk
 
     require(pk.enabled(), "fused kernels are off on this backend")
-    return ([check_lstm_kernel(h, dt) for h, dt in cfg["lstm_checks"]]
-            + [check_gru_kernel(h, dt) for h, dt in cfg["gru_checks"]])
+    return ([check_lstm_kernel(h, dt, mesh) for h, dt in cfg["lstm_checks"]]
+            + [check_gru_kernel(h, dt, mesh) for h, dt in cfg["gru_checks"]])
 
 
 def phase_serve_reference(cfg):
@@ -542,6 +585,35 @@ def train_and_check(trainer, params, cfg, platform):
     return costs
 
 
+def lowered_step(trainer, feed):
+    """StableHLO of the trainer's step on ``feed``. A data-parallel step
+    is a plain function round its jit; jitting that function once more
+    lowers the same trace."""
+    import jax
+
+    return jax.jit(trainer._train_step).lower(
+        trainer._trainable, trainer._replica, trainer._static,
+        trainer._state, trainer._opt_state, feed,
+        jax.random.PRNGKey(0)).as_text()
+
+
+def fused_calls(stablehlo, cell, layers, args):
+    """The fused ``cell`` ("lstm" | "gru") really is in the lowered step:
+    one Mosaic call per recurrent layer forward and one backward, and no
+    while loop (the lax.scan ops/rnn.py takes when pk.enabled() or
+    *_mode() say no)."""
+    if args.dry_run_cpu:  # interpreted Pallas: plain ops in a while loop
+        return None
+    calls = {k: stablehlo.count('kernel_name = "_%s_%s_kernel"' % (cell, k))
+             for k in ("fwd", "bwd")}
+    require(calls == {"fwd": layers, "bwd": layers}, "lowered %s train "
+            "step holds %r fused Mosaic calls, expected %d forward + %d "
+            "backward", cell, calls, layers, layers)
+    require("stablehlo.while" not in stablehlo,
+            "lowered %s train step holds a while loop: a scan path", cell)
+    return calls
+
+
 def phase_train(cfg, args, device):
     import jax
 
@@ -575,17 +647,8 @@ def phase_train(cfg, args, device):
     if args.expect_warm:
         require(not report["train_step_compiled"], "second run: the train "
                 "step compiled again (%r)", report["train_step_cache"])
+    calls = fused_calls(stablehlo, "lstm", 2, args)
     if not args.dry_run_cpu:
-        # the fused LSTM really is in the step: one Mosaic call per LSTM
-        # layer forward and one backward, and no while loop (the lax.scan
-        # ops/rnn.py takes when pk.enabled()/lstm_mode() say no)
-        calls = {k: stablehlo.count('kernel_name = "_lstm_%s_kernel"' % k)
-                 for k in ("fwd", "bwd")}
-        require(calls == {"fwd": 2, "bwd": 2}, "lowered train step holds "
-                "%r fused-LSTM Mosaic calls, expected 2 forward + 2 "
-                "backward", calls)
-        require("stablehlo.while" not in stablehlo,
-                "lowered train step holds a while loop: a scan path")
         mosaic = compiled.as_text().count('custom_call_target="tpu_custom_call"')
         require(mosaic >= 4, "compiled train step holds %d tpu_custom_call",
                 mosaic)
@@ -640,7 +703,8 @@ def stage_chip(args):
 
 
 def stage_multichip(args):
-    """--chips N in one process: the flagship through
+    """--chips N in one process: the fused kernels against the scan path
+    with the batch split over an N-device mesh, the flagship through
     DataParallel(build_mesh({"data": N})) with the DeviceFeeder placing
     the batch, and the tagger bundle as ReplicaSet(replicas=N)."""
     cfg = TINY if args.dry_run_cpu else FULL
@@ -656,6 +720,7 @@ def stage_multichip(args):
     from paddle_tpu.observe.metrics import MetricsRegistry
     from paddle_tpu.parallel.mesh import DataParallel, build_mesh
     from paddle_tpu.serve import ReplicaSet, load_bundle
+    from paddle_tpu.topology import convert_feed
     from paddle_tpu.utils import compile_cache
 
     use_tpu = None if args.dry_run_cpu else True
@@ -663,20 +728,45 @@ def stage_multichip(args):
                 matmul_precision="default")
     devices = jax.devices()[:n]
     report = dict(env_report, device=device)
+    dp = DataParallel(build_mesh({"data": n}, devices=devices))
 
-    # the loss of one fixed global batch: one chip, then N
-    def first_loss(parallelism):
+    # XLA cannot partition a Mosaic kernel: under the mesh each fused scan
+    # is a shard_map, a kernel instance per device on its own rows, and
+    # the weight gradients are summed over the devices
+    report["kernels"] = phase_kernels(cfg, dp.mesh)
+
+    # one step on one fixed global batch, one chip then N: the same loss
+    # (forward) and the same movement of every parameter (the first
+    # Momentum step is -lr * gradient, so this is the backward pass and the
+    # sum of the weight gradients over the devices)
+    def one_step(parallelism):
         params, trainer = build_flagship(cfg, parallelism)
+        before = {n: np.array(params.get(n)) for n in params.names()}
         costs = []
         trainer.train(flagship_reader(cfg, 1), num_passes=1,
                       event_handler=cost_collector(costs))
-        return costs[0], params, trainer
+        moved = {n: np.asarray(params.get(n)) - before[n] for n in before}
+        return costs[0], moved, params, trainer
 
-    one, _, _ = first_loss(None)
-    dp = DataParallel(build_mesh({"data": n}, devices=devices))
-    many, params, trainer = first_loss(dp)
+    one, moved_one, _, _ = one_step(None)
+    many, moved_many, params, trainer = one_step(dp)
     require(abs(many - one) <= 1e-3 * max(1.0, abs(one)), "loss of the "
             "same global batch: %r on %d chips, %r on one", many, n, one)
+    tol, worst = GATE_TOL["bfloat16"], 0.0
+    for name, want in moved_one.items():
+        require(np.linalg.norm(want) > 0, "%s did not move on one chip", name)
+        err = float(np.linalg.norm(moved_many[name] - want)
+                    / np.linalg.norm(want))
+        require(err <= tol, "one update moved %s differently on %d chips "
+                "than on one: relative %.3g (tol %g)", name, n, err, tol)
+        worst = max(worst, err)
+    feed = convert_feed(trainer.topology,
+                        next(iter(flagship_reader(cfg, 1)())))
+    # and the step really runs the fused kernel, one instance per device
+    stablehlo = lowered_step(trainer, feed)
+    require("manual_computation" in stablehlo,
+            "data-parallel step holds no shard_map")
+    mosaic = fused_calls(stablehlo, "lstm", 2, args)
 
     # what the trainer's DeviceFeeder hands the step spans all N devices
     feeder = DeviceFeeder(flagship_reader(cfg, 1), trainer.topology,
@@ -694,7 +784,9 @@ def stage_multichip(args):
                 in_use)
         report["bytes_in_use_per_device"] = in_use
     report["train"] = {"loss_one_chip": one, "loss_n_chips": many,
-                       "losses": losses, "feed_spans_devices": n}
+                       "update_rel_err_vs_one_chip": worst,
+                       "losses": losses, "feed_spans_devices": n,
+                       "mosaic_calls": mosaic}
 
     # the same bundle cli export writes, as N one-chip replicas; serving
     # runs under the framework's default precision, not the benchmark's

@@ -72,7 +72,8 @@ class ExtraAttr:
     ``device`` attr pinned layers to GPUs): a PartitionSpec-style tuple of
     mesh-axis names (or None), one per output dim, lowered to
     ``jax.lax.with_sharding_constraint`` on the layer's output whenever a
-    mesh is active (paddle_tpu.parallel.mesh.use_mesh). E.g.
+    mesh is active (paddle_tpu.parallel.mesh.use_mesh, or a DataParallel
+    step over a mesh that has the named axes). E.g.
     ``ExtraAttr(sharding=(None, "model"))`` shards an [B, F] output's
     feature axis over the 'model' axis — the SPMD re-expression of
     per-layer device placement.

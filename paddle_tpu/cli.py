@@ -399,23 +399,22 @@ def _make_engine(bundle, args, reg, model=None, warmup="async",
         # the same duck-typed fleet front — the GIL-free path
         from paddle_tpu.core.place import host_tpu_chips
         from paddle_tpu.serve import WorkerSet
-        from paddle_tpu.serve.fleet import replicas_that_fit
+        from paddle_tpu.serve.fleet import auto_replicas
 
-        # "auto" must size the fleet WITHOUT opening a device: this
-        # parent only routes, and on a TPU host a parent that has
-        # touched JAX holds the chips its workers need. One worker per
-        # core (worker PROCESSES beyond the cores only add
-        # context-switch overhead), fewer when PADDLE_TPU_HBM_BUDGET
-        # fits fewer parameter copies; on a TPU host one worker, the
-        # only width that can open the chips (WorkerSet refuses more)
+        # "auto" sizes like --replicas auto (one per device, or the
+        # PADDLE_TPU_HBM_BUDGET fit), capped at the core count: worker
+        # PROCESSES beyond the cores only add context-switch overhead.
+        # On a TPU host it must size WITHOUT opening a device: this
+        # parent only routes, and a parent that has touched JAX holds
+        # the chips its workers need. There it is one worker, the only
+        # width that can open the chips (WorkerSet refuses more)
         if workers != "auto":
             n = int(workers)
         elif host_tpu_chips():
             n = 1
         else:
-            cores = os.cpu_count() or 1
-            fit = replicas_that_fit(bundle, budget_share)
-            n = cores if fit is None else min(cores, fit)
+            n = min(auto_replicas(bundle, budget=budget_share),
+                    os.cpu_count() or 1)
         kwargs = (dict({"max_queue": args.max_queue_rows},
                        **_session_kwargs(args)) if args.continuous
                   else {"max_batch_size": args.max_batch_size,

@@ -16,13 +16,16 @@ gate-block column remap on import/export: paddle_tpu/interop.py
 _REF_TO_TPU / _TPU_TO_REF, golden-tested in tests/test_interop.py.)
 """
 
-from functools import partial
+from functools import cache, partial
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.sharding import PartitionSpec as P
 
+from paddle_tpu.core import mesh_scope
 from paddle_tpu.core.dtype import matmul_precision
+from paddle_tpu.utils.logger import logger
 
 
 def _mm(a, b):
@@ -108,30 +111,40 @@ def _per_device(fused, batch, arg_bdims, out_bdims):
     ``(fn, rows)`` — the callable and the rows one kernel instance sees —
     or ``(None, None)`` when it cannot run fused at all.
 
-    XLA cannot partition a Mosaic kernel, so inside a data-parallel step
-    (parallel.mesh.batch_axis_scope) the kernel is shard_mapped over the
-    batch axis and each device scans its own rows; ``*_bdims`` give the
-    batch dimension of every argument and output (None = replicated)."""
-    from paddle_tpu.parallel.mesh import current_batch_axis
-
-    scope = current_batch_axis()
+    XLA cannot partition a Mosaic kernel, so under a multi-device mesh
+    (core.mesh_scope.use_mesh) the kernel is shard_mapped over the batch
+    axis the scope names and each device scans its own rows; ``*_bdims``
+    give the batch dimension of every argument and output (None =
+    replicated). With no batch axis named the scan path runs, which XLA
+    partitions itself."""
+    scope = mesh_scope.current()
     if scope is None or scope[0].size == 1:
         return fused, batch
     mesh, axis = scope
+    if axis is None:
+        _log_scan_once(mesh)
+        return None, None
     shards = mesh.shape[axis]
     if batch % shards:  # DataParallel.shard_batch replicates such a batch
         return None, None
-    from jax import shard_map
-    from jax.sharding import PartitionSpec as P
 
     def spec(bdim):
         return P() if bdim is None else P(*([None] * bdim + [axis]))
 
-    return (shard_map(fused, mesh=mesh,
-                      in_specs=tuple(spec(d) for d in arg_bdims),
-                      out_specs=tuple(spec(d) for d in out_bdims),
-                      check_vma=False),
+    return (jax.shard_map(fused, mesh=mesh,
+                          in_specs=tuple(spec(d) for d in arg_bdims),
+                          out_specs=tuple(spec(d) for d in out_bdims),
+                          check_vma=False),
             batch // shards)
+
+
+@cache
+def _log_scan_once(mesh):
+    logger.warning(
+        "recurrent layers run as lax.scan under mesh %s: a fused kernel "
+        "cannot be partitioned by XLA; name the batch axis "
+        "(use_mesh(mesh, batch_axis=...)) to run it per device",
+        dict(mesh.shape))
 
 
 def lstm_scan(x_btd, mask_bt, w_in, b, w_rec, h0=None, c0=None,

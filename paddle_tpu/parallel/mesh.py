@@ -10,15 +10,15 @@ parameters replicated (or sharded ZeRO-style with
 parameter server, no RPC, no gradient copy threads.
 """
 
-import contextlib
-import threading
-
 import numpy as np
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+# the active-mesh context lives in core/ (ops/ reads it too); re-exported
+# here because this is where users look for it
+from paddle_tpu.core.mesh_scope import current_mesh, use_mesh  # noqa: F401
 from paddle_tpu.core.sequence import NestedSequenceBatch, SequenceBatch
 from paddle_tpu.utils.error import enforce
 from paddle_tpu.utils.logger import logger
@@ -124,7 +124,7 @@ class DataParallel:
 
         def run(trainable, replica, static, state, opt_state, feed, rng):
             feed = self.shard_batch(feed)
-            with batch_axis_scope(self.mesh, self.axis):
+            with use_mesh(self.mesh, batch_axis=self.axis):
                 return jitted(trainable, replica, static, state, opt_state,
                               feed, rng)
 
@@ -141,7 +141,7 @@ class DataParallel:
 
         def run(trainable, replica, static, state, opt_state, feeds, rng):
             feeds = tuple(self.shard_batch(f) for f in feeds)
-            with batch_axis_scope(self.mesh, self.axis):
+            with use_mesh(self.mesh, batch_axis=self.axis):
                 return jitted(trainable, replica, static, state, opt_state,
                               feeds, rng)
 
@@ -152,7 +152,7 @@ class DataParallel:
 
         def run(trainable, static, state, feed):
             feed = self.shard_batch(feed)
-            with batch_axis_scope(self.mesh, self.axis):
+            with use_mesh(self.mesh, batch_axis=self.axis):
                 return jitted(trainable, static, state, feed)
 
         return run
@@ -160,54 +160,3 @@ class DataParallel:
     def __repr__(self):
         return "DataParallel(mesh=%s, axis=%r)" % (
             dict(self.mesh.shape), self.axis)
-
-
-# -- batch-axis scope (kernels XLA cannot partition) --------------------------
-# A Mosaic kernel inside a multi-device jit does not lower: XLA cannot
-# partition a custom call ("Mosaic kernels cannot be automatically
-# partitioned"). While a data-parallel step is being traced this slot names
-# the mesh and the axis its batch is split over, so ops/rnn.py can
-# shard_map its fused scans and run each on the device's own rows.
-# Thread-local: a trace runs on the thread that calls the jitted step.
-_batch_axis = threading.local()
-
-
-def current_batch_axis():
-    """``(mesh, axis)`` of the data-parallel step being traced, or None."""
-    return getattr(_batch_axis, "scope", None)
-
-
-@contextlib.contextmanager
-def batch_axis_scope(mesh, axis):
-    prev = current_batch_axis()
-    _batch_axis.scope = (mesh, axis)
-    try:
-        yield
-    finally:
-        _batch_axis.scope = prev
-
-
-# -- active-mesh context (per-layer sharding constraints) --------------------
-# The DSL's ExtraAttr(sharding=...) needs a mesh to resolve axis names
-# against at trace time (ParallelNeuralNetwork-parity placement). One
-# process-global slot, managed by use_mesh().
-_current_mesh = None
-
-
-def current_mesh():
-    """The mesh use_mesh() made active, or None."""
-    return _current_mesh
-
-
-@contextlib.contextmanager
-def use_mesh(mesh):
-    """Make ``mesh`` the active mesh for layer-level sharding constraints
-    (and enter it as the jax mesh context)."""
-    global _current_mesh
-    prev = _current_mesh
-    _current_mesh = mesh
-    try:
-        with mesh:
-            yield mesh
-    finally:
-        _current_mesh = prev

@@ -39,13 +39,14 @@ def _external(value):
 
 def _layer_sharding_constraint(value, spec):
     """Lower ExtraAttr(sharding=...) to with_sharding_constraint against
-    the active mesh (parallel.mesh.use_mesh). No active mesh -> no-op, so
-    sharded configs still run single-device (the reference likewise ran
-    parallel_nn configs on one GPU by ignoring device attrs)."""
+    the active mesh (core.mesh_scope.use_mesh, which a DataParallel step
+    enters with its own mesh). No active mesh -> no-op, so sharded configs
+    still run single-device (the reference likewise ran parallel_nn
+    configs on one GPU by ignoring device attrs)."""
     from jax.sharding import NamedSharding, PartitionSpec
-    from paddle_tpu.parallel import mesh as mesh_mod
+    from paddle_tpu.core.mesh_scope import current_mesh
 
-    mesh = mesh_mod.current_mesh()
+    mesh = current_mesh()
     if mesh is None:
         return value
     # sharding specs address the flat [B, C*H*W] contract — materialize it

@@ -339,33 +339,50 @@ def test_int8_matmul_gate_defaults_to_xla_path():
     np.testing.assert_allclose(np.asarray(out), want, atol=1e-5)
 
 
-def test_fused_scans_shard_over_the_batch_in_a_data_parallel_step():
-    """XLA cannot partition a Mosaic kernel, so inside a DataParallel step
-    (parallel.mesh.batch_axis_scope) the fused scans run under shard_map,
-    each device on its own rows — and the weight gradient still sums over
-    all of them. On the chip the unwrapped call does not even lower
-    (chip_smoke.py --chips 4 is the check there, GRU included)."""
+@pytest.mark.parametrize("cell", ["lstm", "gru"])
+def test_fused_scans_shard_over_the_batch_in_a_data_parallel_step(cell):
+    """XLA cannot partition a Mosaic kernel, so under a multi-device mesh
+    with a batch axis (core.mesh_scope.use_mesh, which a DataParallel step
+    enters) the fused scans run under shard_map, each device on its own
+    rows — and the weight gradients still sum over all of them. On the
+    chip the unwrapped call does not even lower; chip_smoke.py --chips 4
+    trains the LSTM flagship and a GRU tagger that way."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from paddle_tpu.parallel.mesh import batch_axis_scope, build_mesh
+    from paddle_tpu.parallel.mesh import build_mesh, use_mesh
 
     gates, mask, w = _inputs()
+    if cell == "lstm":
+        weights = (w,)
 
-    def loss(g, w):
-        h_seq, (h_f, c_f) = _fused_path(g, mask, w)
-        return jnp.sum(h_seq ** 2) + jnp.sum(h_f) + jnp.sum(c_f)
+        def loss(x, w):
+            h_seq, (h_f, c_f) = _fused_path(x, mask, w)
+            return jnp.sum(h_seq ** 2) + jnp.sum(h_f) + jnp.sum(c_f)
+    else:
+        x, weights = gates[..., :3 * H], (w[:, :2 * H], w[:, 2 * H:3 * H])
+        gates = x
 
-    grad = jax.value_and_grad(loss, argnums=(0, 1))
-    want = jax.jit(grad)(gates, w)
+        def loss(x, w_rz, w_c):
+            h_seq, h_f = rnn_ops.gru_scan(x, mask, None, None, w_rz, w_c)
+            return jnp.sum(h_seq ** 2) + jnp.sum(h_f)
+
+    grad = jax.value_and_grad(loss, argnums=tuple(range(1 + len(weights))))
+    want = jax.jit(grad)(gates, *weights)
     mesh = build_mesh({"data": 4}, devices=jax.devices()[:4])
     rows = jax.device_put(gates, NamedSharding(mesh, P("data")))
-    with batch_axis_scope(mesh, "data"):
-        lowered = jax.jit(grad).lower(rows, w)
+    with use_mesh(mesh, batch_axis="data"):
+        lowered = jax.jit(grad).lower(rows, *weights)
         assert "manual" in lowered.as_text()  # the shard_map is there
-        got = lowered.compile()(rows, w)
+        got = lowered.compile()(rows, *weights)
         # a batch the mesh does not divide stays on the scan path
         assert rnn_ops._per_device(pk.lstm_fused, 6, (1,), (1,)) \
             == (None, None)
+    with use_mesh(mesh):  # and so does a mesh with no batch axis named
+        assert rnn_ops._per_device(pk.lstm_fused, B, (1,), (1,)) \
+            == (None, None)
+        # (a fresh function: the batch axis is not in jit's cache key)
+        assert "manual" not in jax.jit(lambda *a: grad(*a)).lower(
+            rows, *weights).as_text()
     for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-4, atol=1e-5)
