@@ -1,0 +1,8 @@
+"""100 x (1 - busy union / traced window), mean over the cell's devices."""
+
+
+def read(ctx):
+    trace = ctx["trace"]
+    if trace is None:
+        return None
+    return 100.0 * (1.0 - trace["busy_s"] / trace["window_s"])
