@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from paddle_tpu.observe import spans as observe_spans
 from paddle_tpu.utils.error import enforce
 
 
@@ -78,7 +79,8 @@ class SequenceBatch:
         for i, s in enumerate(seqs):
             enforce(len(s) <= tmax, "sequence %d longer than max_len %d", i, tmax)
             data[i, : len(s)] = s
-        return SequenceBatch(jnp.asarray(data), jnp.asarray(lengths))
+        with observe_spans.span("feed_place"):
+            return SequenceBatch(jnp.asarray(data), jnp.asarray(lengths))
 
     @staticmethod
     def from_flat(flat, start_positions, max_len=None):
@@ -263,9 +265,10 @@ class NestedSequenceBatch:
                 s = np.asarray(s)
                 data[i, j, : len(s)] = s
                 inner[i, j] = len(s)
-        return NestedSequenceBatch(
-            jnp.asarray(data), jnp.asarray(outer), jnp.asarray(inner)
-        )
+        with observe_spans.span("feed_place"):
+            return NestedSequenceBatch(
+                jnp.asarray(data), jnp.asarray(outer), jnp.asarray(inner)
+            )
 
     def flatten_to_subsequences(self):
         """Collapse to a SequenceBatch over all sub-sequences [B*S, T, ...]

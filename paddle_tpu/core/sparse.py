@@ -22,6 +22,7 @@ import numpy as np
 
 import jax.numpy as jnp
 
+from paddle_tpu.observe import spans as observe_spans
 from paddle_tpu.utils.error import enforce
 
 
@@ -65,8 +66,9 @@ class SparseRows:
             ids[i, :len(r)] = r
             if with_values:
                 vals[i, :len(r)] = vals_l[i]
-        return cls(jnp.asarray(ids), None if vals is None
-                   else jnp.asarray(vals), dim)
+        with observe_spans.span("feed_place"):
+            return cls(jnp.asarray(ids), None if vals is None
+                       else jnp.asarray(vals), dim)
 
     def weights(self):
         """[B, K] float32 combination weights (mask * values)."""

@@ -33,13 +33,18 @@ Observability: every yielded batch updates the process-wide metrics
 registry (``paddle_tpu_data_*`` series: feed-stall histogram, queue
 depth, per-bucket fill/waste gauges — the training twins of the serve
 engine's per-bucket series) and the stall is recorded as a ``feed``
-span so traces show the step thread's wait. The trainer additionally
-writes a ``feed`` steplog record per step (docs/observability.md).
+span so traces show the step thread's wait. The producer's cycle is
+four spans on its own thread, each with a histogram of its own:
+``feed_read`` (the reader's ``next()``), ``feed_convert`` (rows to a
+feed on the device: its ``feed_place`` children are the hand-overs of
+host bytes to a device, its self time is host assembly) and ``feed_put``
+(blocked on a full queue: the feed is ahead, the device is the bound).
+The trainer additionally writes a ``feed`` steplog record per step
+(docs/observability.md).
 """
 
 import queue
 import threading
-import time
 
 import numpy as np
 
@@ -66,23 +71,37 @@ class _Error:
 
 class FeedBatch:
     """One pipelined batch: the device-resident ``feed`` dict plus its
-    accounting — ``examples`` (rows), ``convert_ms`` (host assembly +
-    device dispatch on the producer thread), ``stall_ms`` (time the
-    consumer blocked waiting for it), and for sequence feeds ``bucket``
-    (padded length), ``fill_tokens``/``pad_tokens``."""
+    accounting — ``seq`` (the producer's count of batches, the pass's
+    batch number), ``examples`` (rows), the producer thread's four
+    durations ``read_ms`` (the reader's ``next()``), ``convert_ms`` =
+    ``host_ms`` (host assembly) + ``place_ms`` (handing the bytes to the
+    device), and ``backpressure_ms`` (the producer blocked on a full
+    queue, written once the batch is in the queue), ``stall_ms`` (time
+    the consumer blocked waiting for it), and for sequence feeds
+    ``bucket`` (padded length), ``fill_tokens``/``pad_tokens``."""
 
-    __slots__ = ("feed", "examples", "convert_ms", "stall_ms", "bucket",
+    __slots__ = ("feed", "seq", "examples", "read_ms", "convert_ms",
+                 "place_ms", "backpressure_ms", "stall_ms", "bucket",
                  "fill_tokens", "pad_tokens")
 
     def __init__(self, feed, examples, convert_ms, bucket=None,
-                 fill_tokens=None, pad_tokens=None):
+                 fill_tokens=None, pad_tokens=None, seq=None, read_ms=0.0,
+                 place_ms=0.0):
         self.feed = feed
+        self.seq = seq
         self.examples = examples
+        self.read_ms = read_ms
         self.convert_ms = convert_ms
+        self.place_ms = place_ms
+        self.backpressure_ms = 0.0  # set by the producer after the put
         self.stall_ms = None  # set by the consumer
         self.bucket = bucket
         self.fill_tokens = fill_tokens
         self.pad_tokens = pad_tokens
+
+    @property
+    def host_ms(self):
+        return self.convert_ms - self.place_ms
 
 
 class ChunkBatch:
@@ -168,6 +187,20 @@ class DeviceFeeder:
         self._m_convert = m.histogram(
             "paddle_tpu_data_feed_convert_ms",
             help="producer-thread batch conversion + device dispatch time")
+        self._m_read = m.histogram(
+            "paddle_tpu_data_feed_read_ms",
+            help="producer-thread time in the reader's next()")
+        self._m_host = m.histogram(
+            "paddle_tpu_data_feed_host_ms",
+            help="producer-thread host assembly: conversion less placement")
+        self._m_place = m.histogram(
+            "paddle_tpu_data_feed_place_ms",
+            help="producer-thread hand-over of a batch's bytes to the "
+                 "device(s)")
+        self._m_backpressure = m.histogram(
+            "paddle_tpu_data_feed_backpressure_ms",
+            help="time the producer blocked on a full queue: the feed is "
+                 "ahead of the step")
         self._m_batches = m.counter(
             "paddle_tpu_data_batches_total",
             help="batches assembled by the feed pipeline")
@@ -198,9 +231,16 @@ class DeviceFeeder:
         def put(item):
             return _cancellable_put(q, item, cancel)
 
+        span = observe_spans.span
+        seq = 0  # of the batch being made: the pass's batch number
         try:
-            for data_batch in self.reader():
-                if skip > 0:
+            batch_iter = iter(self.reader())
+            while True:
+                with span("feed_read", args={"batch": seq}) as read:
+                    data_batch = next(batch_iter, _End)
+                if data_batch is _End:
+                    break
+                if seq < skip:
                     # deterministic-resume cursor (trainer train(resume=)):
                     # the already-trained batch prefix is consumed from
                     # the reader (so ordering downstream is untouched)
@@ -209,17 +249,26 @@ class DeviceFeeder:
                     # must not leak this thread for the rest of it
                     if cancel.is_set():
                         return
-                    skip -= 1
+                    seq += 1
                     continue
-                t0 = time.perf_counter()
-                feed = self._convert_batch(data_batch)
-                convert_ms = (time.perf_counter() - t0) * 1e3
+                with span("feed_convert", args={"batch": seq}) as convert:
+                    feed = self._convert_batch(data_batch)
                 bucket, fill, pad = _seq_stats(feed)
-                fb = FeedBatch(feed, len(data_batch), convert_ms,
+                fb = FeedBatch(feed, len(data_batch), convert.dur * 1e3,
                                bucket=bucket, fill_tokens=fill,
-                               pad_tokens=pad)
-                if not put(fb):
+                               pad_tokens=pad, seq=seq,
+                               read_ms=read.dur * 1e3,
+                               place_ms=convert.child_dur * 1e3)
+                self._m_read.observe(fb.read_ms)
+                self._m_host.observe(fb.host_ms)
+                self._m_place.observe(fb.place_ms)
+                with span("feed_put", args={"batch": seq}) as blocked:
+                    taken = put(fb)
+                if not taken:
                     return
+                fb.backpressure_ms = blocked.dur * 1e3
+                self._m_backpressure.observe(fb.backpressure_ms)
+                seq += 1
                 if cancel.is_set():
                     return
         except BaseException as exc:  # re-raised on the consumer thread
@@ -239,11 +288,13 @@ class DeviceFeeder:
             target=self._produce, args=(q, cancel, int(skip)),
             name="data-feeder-producer", daemon=True)
         thread.start()
+        seq = int(skip)  # of the batch about to be taken: FIFO, one producer
         try:
             while True:
-                with observe_spans.span("feed",
-                                        args={"pipelined": True}) as scope:
+                with observe_spans.span("feed", args={"pipelined": True,
+                                                      "batch": seq}) as scope:
                     item = q.get()
+                seq += 1
                 if item is _End:
                     return
                 if isinstance(item, _Error):

@@ -5,9 +5,11 @@ The observability subsystem the rest of the stack instruments against
 in gserver/NeuralNetwork.cpp:248, and the hl_profiler_start/end CUDA
 profiler window). Three pieces behind one package:
 
-* :mod:`paddle_tpu.observe.spans` — nested named host-side spans with
-  optional device sync, thread-safe, exportable as Chrome-trace/Perfetto
-  JSON, feeding the :class:`paddle_tpu.utils.stat.StatSet` aggregates.
+* :mod:`paddle_tpu.observe.spans` — nested named spans with optional
+  device sync, thread-safe, each knowing its parent and its self time:
+  one ``span()`` call feeds the :class:`paddle_tpu.utils.stat.StatSet`
+  aggregates, the Chrome-trace/Perfetto JSON export, and a
+  ``jax.profiler.TraceAnnotation`` on the profiler's own clock.
 * :mod:`paddle_tpu.observe.attribution` — device-trace attribution
   (promoted from benchmark/traceutil.py): per-op device time, fusion
   grouping, MXU-utilization estimates, and the dispatch-gap detector that
@@ -34,7 +36,8 @@ profiler window). Three pieces behind one package:
   the tail-attribution report (``cli observe``).
 
 Everything degrades to a no-op when profiling is unavailable: spans always
-work (pure host timing), attribution returns None without a usable
+work (their profiler annotation is inert while nobody traces),
+attribution returns None without a usable
 profiler backend, and the steplog is simply not created without the env
 flag.
 """

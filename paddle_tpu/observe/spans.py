@@ -1,18 +1,34 @@
-"""Nested named spans with Chrome-trace export.
+"""Nested named spans: one ``span()`` call, every sink.
 
 Host-side wall-time spans (reference: REGISTER_TIMER scopes,
 paddle/utils/Stat.h:230-233), kept deliberately cheap: a span is one
-``perf_counter`` pair plus an appended tuple, so the trainer can wrap
-every batch phase without measurable overhead. Each closed span also
-feeds the :data:`paddle_tpu.utils.stat.global_stats` StatSet under the
-span name, so ``PADDLE_TPU_STATS=1`` per-pass dumps and the exported
-trace can never disagree about what was measured.
+``perf_counter`` pair, a push and a pop of its thread's stack and an
+appended tuple, so the trainer can wrap every batch phase without
+measurable overhead. Each closed span feeds the
+:data:`paddle_tpu.utils.stat.global_stats` StatSet under the span name,
+so ``PADDLE_TPU_STATS=1`` per-pass dumps and the exported trace can
+never disagree about what was measured.
+
+Every span also holds a ``jax.profiler.TraceAnnotation("paddle_tpu." +
+name)`` open for exactly its lifetime, so whenever anyone traces
+(``paddle_tpu.utils.stat.profiler_trace(dir)``, the chip benchmark's
+traced run) the span lies on the profiler's clock, on its own thread's
+line of the ``/host:CPU`` plane, beside the device's "XLA Ops": an idle
+gap of the device can be put down to the span that covers it. With no
+profiler session the annotation costs a third of a microsecond. Span
+names are a closed vocabulary (docs/observability.md); a number that
+varies goes into ``args``, which the profiler stores as the event's
+stats, never into the name.
+
+A span knows its parent: each thread keeps a stack of its open spans, a
+closed span adds its duration to its parent's ``child_dur``, and
+``scope.self_dur`` is the span's own time, its duration less what its
+children covered.
 
 Export is the Chrome trace-event JSON format ("X" complete events, µs
 timestamps) — the file loads directly in Perfetto (ui.perfetto.dev) or
 chrome://tracing. Spans opened on different threads land on different
-trace rows; nesting within a thread is expressed by containment, which
-holds by construction (a nested span closes before its parent).
+trace rows; a span's ``args`` carry its ``parent``'s name.
 
 An optional ``sync`` pytree is blocked on (``jax.block_until_ready``)
 before the span closes, so spans timing device work record real wall
@@ -28,11 +44,14 @@ the HTTP thread, the dispatch loop and the spill writer renders as ONE
 connected lane in Perfetto. :meth:`SpanTracer.add_event` records a span
 retrospectively from stamped timestamps — the serving workers measure
 phases as plain perf_counter pairs on the hot path and emit the spans
-once, at request completion.
+once, at request completion. Such a span is over when it is recorded, so
+it reaches the StatSet and the Chrome export but not the profiler's
+trace.
 """
 
 import json
 import os
+import sys
 import threading
 import time
 from contextlib import contextmanager
@@ -40,16 +59,38 @@ from contextlib import contextmanager
 from paddle_tpu.utils.stat import global_stats
 
 
+def _annotation(name, args):
+    """An entered profiler annotation for a span, or None in a process
+    that has not imported jax: nobody can be tracing there, and a span
+    must not be what pulls jax in."""
+    profiler = sys.modules.get("jax.profiler")
+    if profiler is None:
+        return None
+    note = profiler.TraceAnnotation("paddle_tpu." + name, **(args or {}))
+    note.__enter__()
+    return note
+
+
 class _Scope:
     """Handle yielded by :meth:`SpanTracer.span`; ``dur`` (seconds) is set
     when the span closes, so callers timing a window can reuse the span's
-    own measurement instead of keeping a second clock."""
+    own measurement instead of keeping a second clock. ``parent`` is the
+    name of the span that was open on this thread when this one opened,
+    ``child_dur`` the summed durations of the spans that closed inside
+    it."""
 
-    __slots__ = ("name", "dur")
+    __slots__ = ("name", "dur", "parent", "child_dur")
 
-    def __init__(self, name):
+    def __init__(self, name, parent=None):
         self.name = name
         self.dur = None
+        self.parent = parent
+        self.child_dur = 0.0
+
+    @property
+    def self_dur(self):
+        """The span's own time: ``dur`` less what its children covered."""
+        return self.dur - self.child_dur
 
 
 class SpanTracer:
@@ -70,9 +111,11 @@ class SpanTracer:
         # the trainer/run.py telemetry paths — flip it to True)
         self.record_events = record_events
         self._lock = threading.Lock()
-        # (name, t_start_s, dur_s, thread_ident, args, trace) — trace is
-        # (trace_id, span_id, parent_id) or None
+        # (name, t_start_s, dur_s, thread_ident, args, trace, parent) —
+        # trace is (trace_id, span_id, parent_id) or None, parent the
+        # enclosing span's name or None
         self._events = []
+        self._open = threading.local()  # .stack: this thread's open spans
         self._dropped = 0
         self._stats = stats
         self._t0 = time.perf_counter()
@@ -85,11 +128,19 @@ class SpanTracer:
     @contextmanager
     def span(self, name, sync=None, args=None, trace=None):
         """Time a scope. ``sync`` is an optional array/pytree blocked on
-        before the span closes; ``args`` is a small JSON-able dict shown
-        in the trace viewer; ``trace`` is an optional sampled
+        before the span closes; ``args`` is a small dict of scalars shown
+        in the trace viewer and stored as the profiler event's stats;
+        ``trace`` is an optional sampled
         :class:`~paddle_tpu.observe.tracing.TraceContext` linking this
         span into its request's cross-thread flow lane."""
-        scope = _Scope(name)
+        try:
+            stack = self._open.stack
+        except AttributeError:
+            stack = self._open.stack = []
+        parent = stack[-1] if stack else None
+        scope = _Scope(name, parent.name if parent is not None else None)
+        stack.append(scope)
+        note = _annotation(name, args)
         start = time.perf_counter()
         try:
             yield scope
@@ -102,18 +153,29 @@ class SpanTracer:
                 except Exception:
                     pass
             end = time.perf_counter()
+            if note is not None:
+                note.__exit__(None, None, None)
+            # a span left open across a generator's yield can close out
+            # of order: take this one off the stack wherever it sits
+            if stack[-1] is scope:
+                stack.pop()
+            else:
+                stack.remove(scope)
             # a disabled tracer still stamps dur (callers like the trainer
             # and harness consume scope.dur arithmetically) — it only stops
             # recording events and feeding stats
             scope.dur = end - start
+            if parent is not None:
+                parent.child_dur += scope.dur
             if self.enabled:
                 if self._stats is not None:
                     self._stats.get(name).add(scope.dur)
                 if self._recording():
                     self._record(name, start, scope.dur,
-                                 threading.get_ident(), args, trace)
+                                 threading.get_ident(), args, trace,
+                                 scope.parent)
 
-    def _record(self, name, t_start, dur, ident, args, trace):
+    def _record(self, name, t_start, dur, ident, args, trace, parent=None):
         """Append one event; ``t_start`` is absolute perf_counter time
         (made clock-relative under the lock, next to the ``_t0`` that
         reset() rewrites)."""
@@ -122,7 +184,7 @@ class SpanTracer:
         with self._lock:
             if len(self._events) < self.MAX_EVENTS:
                 self._events.append((name, t_start - self._t0, dur,
-                                     ident, args, tup))
+                                     ident, args, tup, parent))
             else:
                 self._dropped += 1
 
@@ -133,7 +195,9 @@ class SpanTracer:
         ``dur`` seconds). The serving workers time request phases as
         plain perf_counter pairs on the hot path and emit the spans
         once, at completion — same stats feed, same export, no
-        contextmanager overhead per phase."""
+        contextmanager overhead per phase. The span is over by now, so no
+        profiler annotation can cover it: it stays off the profiler's
+        clock, and has no parent."""
         dur = max(float(dur), 0.0)
         if not self.enabled:
             return
@@ -143,11 +207,6 @@ class SpanTracer:
             self._record(name, t_start, dur,
                          ident if ident is not None
                          else threading.get_ident(), args, trace)
-
-    def instant(self, name, args=None):
-        """Record a zero-duration marker (rendered as a thin slice)."""
-        with self.span(name, args=args):
-            pass
 
     def events(self):
         with self._lock:
@@ -177,12 +236,14 @@ class SpanTracer:
                 "args": {"name": self.name}}]
         tids = {}
         flows = {}  # trace_id -> [(ts_s, tid)]
-        for name, ts, dur, ident, args, trace in snapshot:
+        for name, ts, dur, ident, args, trace, parent in snapshot:
             tid = tids.setdefault(ident, len(tids))
             ev = {"ph": "X", "name": name, "pid": pid, "tid": tid,
                   "ts": round(ts * 1e6, 3), "dur": round(dur * 1e6, 3)}
-            if args or trace:
+            if args or trace or parent:
                 ev["args"] = dict(args or {})
+            if parent:
+                ev["args"]["parent"] = parent
             if trace:
                 trace_id, span_id, parent_id = trace
                 ev["args"]["trace_id"] = trace_id

@@ -172,7 +172,9 @@ def resolve(trace):
 class TraceExemplars:
     """Bounded slowest-N reservoir of per-request phase breakdowns —
     the always-on half of tail attribution: even at sample rate 0 the
-    worst requests keep their phase story (``GET /debug/traces``)."""
+    worst requests keep their phase story (``GET /debug/traces``). The
+    trainer keeps one of training steps (``SGD.slow_steps``, entries
+    tagged with ``step``)."""
 
     def __init__(self, capacity=16):
         self.capacity = int(capacity)
@@ -182,7 +184,7 @@ class TraceExemplars:
         self._offered = 0
 
     def offer(self, latency_ms, phases, model=None, replica=None,
-              trace_id=None, session=None):
+              trace_id=None, session=None, step=None):
         """O(log N) on admission, O(1) rejection for the common
         fast-request case."""
         latency_ms = float(latency_ms)
@@ -203,6 +205,8 @@ class TraceExemplars:
                 entry["trace"] = str(trace_id)
             if session is not None:
                 entry["session"] = str(session)
+            if step is not None:
+                entry["step"] = int(step)
             self._seq += 1
             item = (latency_ms, self._seq, entry)
             if len(self._heap) >= self.capacity:

@@ -20,6 +20,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 # here because this is where users look for it
 from paddle_tpu.core.mesh_scope import current_mesh, use_mesh  # noqa: F401
 from paddle_tpu.core.sequence import NestedSequenceBatch, SequenceBatch
+from paddle_tpu.observe import spans as observe_spans
 from paddle_tpu.utils.error import enforce
 from paddle_tpu.utils.logger import logger
 
@@ -77,7 +78,8 @@ class DataParallel:
         """Place a host batch onto the mesh, sharded on axis 0.
         Idempotent: leaves already carrying their target sharding pass
         through untouched, so a feed the DeviceFeeder pre-placed
-        (paddle_tpu.data.feeder) costs the step thread nothing here."""
+        (paddle_tpu.data.feeder) costs the step thread nothing here.
+        Each leaf that does move is a ``feed_place`` span."""
         repl = self.replicated()
 
         def place(x):
@@ -88,7 +90,8 @@ class DataParallel:
                 want = repl
             if getattr(x, "sharding", None) == want:
                 return x
-            return jax.device_put(x, want)
+            with observe_spans.span("feed_place"):
+                return jax.device_put(x, want)
 
         return jax.tree_util.tree_map(place, tree)
 

@@ -17,6 +17,7 @@ from paddle_tpu.core import dtype as dtype_mod
 from paddle_tpu.core.sequence import NestedSequenceBatch, SequenceBatch
 from paddle_tpu.data_type import DENSE, INDEX, SEQ_NESTED, SEQ_NONE, SEQ_SINGLE, SPARSE_BINARY, SPARSE_FLOAT
 from paddle_tpu.graph import Context, LayerNode, topo_sort
+from paddle_tpu.observe import spans as observe_spans
 from paddle_tpu.utils import flags
 from paddle_tpu.utils.error import enforce
 
@@ -288,9 +289,9 @@ def convert_feed(topology, data_batch, feeding=None, max_len=None):
 def convert_column(col, itype, max_len=None):
     if itype.seq_type == SEQ_NONE:
         if itype.value_type == DENSE:
-            return jnp.asarray(np.asarray(col, dtype=np.float32))
+            return _place(np.asarray(col, dtype=np.float32))
         if itype.value_type == INDEX:
-            return jnp.asarray(np.asarray(col, dtype=np.int32))
+            return _place(np.asarray(col, dtype=np.int32))
         if itype.value_type in (SPARSE_BINARY, SPARSE_FLOAT):
             if itype.dim >= flags.get_flag("sparse_feed_threshold"):
                 # true sparse path: padded id lists + gather/weighted-sum
@@ -301,7 +302,7 @@ def convert_column(col, itype, max_len=None):
                 return SparseRows.from_rows(
                     col, itype.dim,
                     with_values=itype.value_type == SPARSE_FLOAT)
-            return jnp.asarray(_densify(col, itype))
+            return _place(_densify(col, itype))
     elif itype.seq_type == SEQ_SINGLE:
         if itype.value_type == DENSE:
             seqs = [np.asarray(s, dtype=np.float32) for s in col]
@@ -319,6 +320,14 @@ def convert_column(col, itype, max_len=None):
             nested = [[_densify(s, itype) for s in subs] for subs in col]
         return NestedSequenceBatch.from_nested(nested)
     raise TypeError("unsupported input type %r" % (itype,))
+
+
+def _place(host):
+    """Hand an assembled host array to the device, as a ``feed_place``
+    span: what is left of the enclosing ``feed_convert`` is host
+    assembly."""
+    with observe_spans.span("feed_place"):
+        return jnp.asarray(host)
 
 
 def _densify(rows, itype):

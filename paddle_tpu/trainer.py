@@ -36,8 +36,15 @@ from paddle_tpu.observe import metrics as observe_metrics
 from paddle_tpu.observe import sentinel as observe_sentinel
 from paddle_tpu.observe import spans as observe_spans
 from paddle_tpu.observe import steplog as observe_steplog
+from paddle_tpu.observe import tracing as observe_tracing
 from paddle_tpu.observe import trainview as observe_trainview
 from paddle_tpu.utils.stat import global_stats
+
+
+# a finalized step's phases: the step thread's own, and the producer
+# thread's for the batch it waited for (FeedBatch.<name>_ms)
+_STEP_PHASES = ("wait", "dispatch", "readback", "handler")
+_FEED_PHASES = ("read", "host", "place", "backpressure")
 
 
 def _make_replica(trainable):
@@ -75,6 +82,9 @@ class SGD:
         self.optimizer = update_equation
         self.feeding = feeding
         self.parallelism = parallelism
+        # the slowest steps with the phases of their wall interval
+        # (_close_step); dumped and reset per pass under PADDLE_TPU_STATS=1
+        self.slow_steps = observe_tracing.TraceExemplars(capacity=5)
         self.__prepare__()
 
     def __prepare__(self):
@@ -304,8 +314,16 @@ class SGD:
         fused loop (``steps_per_call=K``) checkpoints land at chunk
         boundaries — the first step boundary at or past the cadence.
         """
-        if event_handler is None:
-            event_handler = default_event_handler
+        user_handler = event_handler or default_event_handler
+        # what the step thread did since the last finalized step, in ms:
+        # each span of the loops below adds its duration as it closes
+        phases = dict.fromkeys(_STEP_PHASES, 0.0)
+
+        def event_handler(event):
+            with observe_spans.span("handler") as scope:
+                user_handler(event)
+            phases["handler"] += scope.dur * 1e3
+
         feeding = feeding or self.feeding
         if buckets is not None and buckets is not False:
             from paddle_tpu.data import bucketing as data_bucketing
@@ -383,7 +401,7 @@ class SGD:
         # first record honestly includes compile time (the compile shows
         # up as an ``event`` record too when jax.monitoring emits it)
         completed = False
-        last_final = {"t": time.perf_counter()}
+        last_final = {"t": time.perf_counter(), "phases": phases}
         try:
             if k:
                 self._train_passes_fused(
@@ -466,17 +484,56 @@ class SGD:
                         help="last finalized step loss", labels=labels),
                 m.gauge("paddle_tpu_train_examples_per_sec",
                         help="examples/s of the last finalized step",
-                        labels=labels))
+                        labels=labels),
+                (m.histogram("paddle_tpu_train_dispatch_ms",
+                             help="step-thread time dispatching the jitted "
+                                  "step, per finalized step", labels=labels),
+                 m.histogram("paddle_tpu_train_readback_ms",
+                             help="step-thread time blocked reading the "
+                                  "loss and evaluator stats back, per "
+                                  "finalized step", labels=labels),
+                 m.histogram("paddle_tpu_train_handler_ms",
+                             help="step-thread time inside the event "
+                                  "handler, per finalized step",
+                             labels=labels)))
+
+    def _close_step(self, last_final, m_phases, step, batches=()):
+        """A step's (a fused chunk's) wall interval ends here, its loss
+        just read: observe what the step thread did in it, offer it with
+        the producer's phases of the ``batches`` taken in it to the
+        slowest-steps reservoir, and start the next. Returns its ms."""
+        now = time.perf_counter()
+        wall_ms = (now - last_final["t"]) * 1000.0
+        phases = last_final["phases"]
+        for hist, name in zip(m_phases, _STEP_PHASES[1:]):
+            hist.observe(phases[name])
+        entry = dict(phases)
+        for fb in batches:
+            for name in _FEED_PHASES:
+                entry[name] = entry.get(name, 0.0) + getattr(fb, name + "_ms")
+        self.slow_steps.offer(wall_ms, entry, step=step)
+        self._reanchor(last_final, now)
+        return wall_ms
+
+    @staticmethod
+    def _reanchor(last_final, now=None):
+        """Start the next wall interval at ``now``: what came before it
+        (an eval pass, pass-boundary work) is charged to no step."""
+        last_final["t"] = time.perf_counter() if now is None else now
+        phases = last_final["phases"]
+        for name in phases:
+            phases[name] = 0.0
 
     def _train_passes(self, reader, num_passes, event_handler, feeding,
                       sync_params, test_reader, log_period, test_period,
                       slog, last_final, sentinel=None, feed_pipeline=False,
                       start_pass=0, start_cursor=0, ckpt=None):
-        (m_steps, m_examples, m_loss,
-         m_examples_per_sec) = self._train_metrics()
+        (m_steps, m_examples, m_loss, m_examples_per_sec,
+         m_phases) = self._train_metrics()
         # per-worker windowed health (observe/trainview.py): the fleet
         # view's live counterpart to the steplog, O(1) memory
         thist = observe_trainview.get_train_history()
+        phases = last_final["phases"]
         # ONE feeder across passes (batches() starts a fresh producer
         # thread per pass) so its cumulative per-bucket fill/waste
         # gauges span the whole run, like the serve engine's
@@ -517,19 +574,21 @@ class SGD:
             # in order with exact values, one dispatch behind; handlers
             # reading live parameters mid-pass see the in-flight step.
             pending = None  # (batch_id, loss, stats, feed, feed_ms, n_ex)
+            taken = ()  # the FeedBatch taken since the last finalize
 
             def finalize(item):
                 b_id, loss, stats, feed, feed_ms, n_examples = item
                 metrics = {}
-                with observe_spans.span("eval_readback"):
+                with observe_spans.span("eval_readback",
+                                        args={"batch": b_id}) as readback:
                     for e in self.evaluators:
                         eval_acc[e.name] = e.merge(
                             eval_acc[e.name], jax.device_get(stats[e.name]))
                         metrics[e.name] = e.result(eval_acc[e.name])
                     loss = float(loss)
-                now = time.perf_counter()
-                wall_ms = (now - last_final["t"]) * 1000.0
-                last_final["t"] = now
+                phases["readback"] += readback.dur * 1e3
+                wall_ms = self._close_step(last_final, m_phases,
+                                           self._pending_step_of(b_id), taken)
                 if slog is not None:
                     slog.log_step(
                         step=self._pending_step_of(b_id), pass_id=pass_id,
@@ -571,7 +630,7 @@ class SGD:
                     event_handler(result)
                     # the eval pass must not be charged to the next step's
                     # wall_ms interval
-                    last_final["t"] = time.perf_counter()
+                    self._reanchor(last_final)
                 event_handler(v2_event.EndIteration(
                     pass_id, b_id, loss, metrics))
 
@@ -579,16 +638,20 @@ class SGD:
             if not feed_pipeline:
                 for data_batch in batch_iter:
                     event_handler(v2_event.BeginIteration(pass_id, batch_id))
-                    with observe_spans.span("feed") as feed_scope:
+                    with observe_spans.span(
+                            "feed", args={"batch": batch_id}) as feed_scope:
                         feed = convert_feed(
                             self.topology, data_batch, feeding,
                             max_len=getattr(data_batch, "bucket", None))
+                    phases["wait"] += feed_scope.dur * 1e3
                     self._rng, step_rng = jax.random.split(self._rng)
-                    with observe_spans.span("train_step"):
+                    with observe_spans.span(
+                            "train_step", args={"batch": batch_id}) as step:
                         (loss, self._trainable, self._replica, self._state,
                          self._opt_state, stats) = self._train_step(
                             self._trainable, self._replica, self._static,
                             self._state, self._opt_state, feed, step_rng)
+                    phases["dispatch"] += step.dur * 1e3
                     self._step_count += 1
                     self._checkpoint_maybe(ckpt, pass_id, batch_id + 1)
                     if pending is not None:
@@ -606,13 +669,17 @@ class SGD:
                 # step record = the stall, the host time actually charged
                 # to the step thread.
                 for fb in batch_iter:
+                    taken = (fb,)
+                    phases["wait"] += fb.stall_ms
                     event_handler(v2_event.BeginIteration(pass_id, batch_id))
                     self._rng, step_rng = jax.random.split(self._rng)
-                    with observe_spans.span("train_step"):
+                    with observe_spans.span(
+                            "train_step", args={"batch": fb.seq}) as step:
                         (loss, self._trainable, self._replica, self._state,
                          self._opt_state, stats) = self._train_step(
                             self._trainable, self._replica, self._static,
                             self._state, self._opt_state, fb.feed, step_rng)
+                    phases["dispatch"] += step.dur * 1e3
                     self._step_count += 1
                     self._checkpoint_maybe(ckpt, pass_id, batch_id + 1)
                     if slog is not None:
@@ -627,6 +694,7 @@ class SGD:
                     pending = (batch_id, loss, stats, fb.feed,
                                fb.stall_ms, fb.examples)
                     batch_id += 1
+            taken = ()
             if pending is not None:
                 finalize(pending)
             self._finish_pass(pass_id, eval_acc, event_handler, feeding,
@@ -648,8 +716,6 @@ class SGD:
             logger.info("pass %d test: cost=%.6f %s", pass_id,
                         result.cost, _fmt_metrics(result.metrics))
             event_handler(result)
-            # next pass's first step must not absorb this eval pass
-            last_final["t"] = time.perf_counter()
         if sync_params:
             self._sync_back()
         pass_metrics = {e.name: e.result(eval_acc[e.name])
@@ -661,11 +727,21 @@ class SGD:
             # + reset at FinishTrainPass (paddle/trainer/Trainer.cpp)
             global_stats.print_all()
             global_stats.reset()
+            # and the pass's slowest steps, each with where its wall
+            # interval went (docs/observability.md "Slow steps")
+            logger.info("======= slowest steps of pass %d: step wall_ms "
+                        "phase=ms =======", pass_id)
+            for entry in self.slow_steps.slowest():
+                logger.info("  step %d %.1f %s", entry["step"],
+                            entry["latency_ms"],
+                            " ".join("%s=%.1f" % kv
+                                     for kv in entry["phases"].items()))
+            self.slow_steps.reset()
         event_handler(v2_event.EndPass(pass_id, pass_metrics, gm=self))
-        # pass-boundary work (_sync_back, stats dump, EndPass handlers
-        # — e.g. a checkpoint save) must not be charged to the next
-        # pass's first step wall_ms
-        last_final["t"] = time.perf_counter()
+        # pass-boundary work (the per-pass test, _sync_back, stats dump,
+        # EndPass handlers — e.g. a checkpoint save) must not be charged
+        # to the next pass's first step wall_ms
+        self._reanchor(last_final)
 
     def _train_passes_fused(self, reader, num_passes, event_handler,
                             feeding, sync_params, test_reader, log_period,
@@ -683,10 +759,11 @@ class SGD:
         ``train_chunk`` record instead."""
         from paddle_tpu.data.feeder import DeviceFeeder
 
-        (m_steps, m_examples, m_loss,
-         m_examples_per_sec) = self._train_metrics()
+        (m_steps, m_examples, m_loss, m_examples_per_sec,
+         m_phases) = self._train_metrics()
         # per-worker windowed health, chunk-amortized (trainview.py)
         thist = observe_trainview.get_train_history()
+        phases = last_final["phases"]
         # ONE feeder across passes, like the per-step pipelined loop
         feeder = DeviceFeeder(reader, self.topology, feeding=feeding,
                               depth=max(int(feed_depth), k),
@@ -706,17 +783,19 @@ class SGD:
             eval_acc = {e.name: None for e in self.evaluators}
             batch_id = cursor0
             pending = None  # (batch_id, base_step, losses, stats, chunk)
+            taken = ()  # the FeedBatches taken since the last finalize
 
             def finalize(item):
                 b_id, base_step, losses, stats, chunk = item
-                with observe_spans.span("eval_readback"):
+                with observe_spans.span("eval_readback",
+                                        args={"batch": b_id}) as readback:
                     costs = np.atleast_1d(
                         np.asarray(jax.device_get(losses), dtype=np.float64))
                     host_stats = (jax.device_get(stats)
                                   if self.evaluators else {})
-                now = time.perf_counter()
-                wall_ms = (now - last_final["t"]) * 1000.0
-                last_final["t"] = now
+                phases["readback"] += readback.dur * 1e3
+                wall_ms = self._close_step(last_final, m_phases,
+                                           base_step + 1, taken)
                 n = len(costs)
                 if slog is not None:
                     slog.log_train_chunk(
@@ -789,11 +868,13 @@ class SGD:
                         event_handler(result)
                         # the eval pass must not be charged to the next
                         # chunk's wall interval
-                        last_final["t"] = time.perf_counter()
+                        self._reanchor(last_final)
                     event_handler(v2_event.EndIteration(
                         pass_id, b_id + i, cost_i, metrics))
 
             for chunk in chunk_iter:
+                taken = chunk.batches
+                phases["wait"] += chunk.stall_ms
                 # every real step of the chunk announces itself before
                 # the fused dispatch, so the reference ordering
                 # BeginIteration(b) < EndForwardBackward(b) <
@@ -801,8 +882,9 @@ class SGD:
                 for i in range(chunk.steps):
                     event_handler(v2_event.BeginIteration(
                         pass_id, batch_id + i))
-                with observe_spans.span("train_chunk",
-                                        args={"steps": chunk.steps}):
+                with observe_spans.span(
+                        "train_chunk", args={"steps": chunk.steps,
+                                             "batch": batch_id}) as step:
                     if chunk.stacked:
                         # the rng carry advances INSIDE the fused program
                         # through the same sequential split stream as the
@@ -825,6 +907,7 @@ class SGD:
                             self._trainable, self._replica, self._static,
                             self._state, self._opt_state, chunk.feed,
                             step_rng)
+                phases["dispatch"] += step.dur * 1e3
                 base_step = self._step_count
                 self._step_count += chunk.steps
                 # chunk boundary == step boundary: the first one at or
@@ -843,6 +926,7 @@ class SGD:
                     finalize(pending)
                 pending = (batch_id, base_step, losses, stats, chunk)
                 batch_id += chunk.steps
+            taken = ()
             if pending is not None:
                 finalize(pending)
             self._finish_pass(pass_id, eval_acc, event_handler, feeding,
@@ -967,7 +1051,9 @@ class SGD:
     def _sync_back(self):
         """Copy device training state back into the Parameters object so
         save/inspect sees current values (v2's gm<->parameters append)."""
-        host = jax.device_get({**self._expanded_trainable(), **self._state})
+        with observe_spans.span("sync_back"):
+            host = jax.device_get({**self._expanded_trainable(),
+                                   **self._state})
         self.parameters.update_from(host)
 
     def save_parameter_to_tar(self, f):

@@ -8,4 +8,4 @@ from paddle_tpu.utils import flags
 from paddle_tpu.utils.error import EnforceError, enforce
 from paddle_tpu.utils.logger import logger, set_level
 from paddle_tpu.utils.registry import Registry
-from paddle_tpu.utils.stat import StatSet, global_stats, timer
+from paddle_tpu.utils.stat import StatSet, global_stats

@@ -3,12 +3,13 @@
 Equivalent of REGISTER_TIMER / StatSet (reference: paddle/utils/Stat.h:63,114,
 230-233; per-layer timers at gserver NeuralNetwork.cpp:248). On TPU the inner
 compute is one fused XLA program, so timers wrap host-visible phases (trace,
-compile, device step, data feed) plus any user scopes; ``block_until_ready``
-is used when timing device work so wall time is real, not dispatch time.
+compile, device step, data feed) plus any user scopes. The one scope timer
+is :func:`paddle_tpu.observe.spans.span`, which feeds a StatSet under the
+span's name (``sync=`` blocks on device work so wall time is real, not
+dispatch time).
 """
 
 import threading
-import time
 from contextlib import contextmanager
 
 
@@ -52,19 +53,6 @@ class StatSet:
                 stat = self._stats[name] = StatInfo(name)
             return stat
 
-    @contextmanager
-    def timer(self, name, sync=None):
-        """Time a scope. ``sync`` is an optional array/pytree to block on first."""
-        start = time.perf_counter()
-        try:
-            yield
-        finally:
-            if sync is not None:
-                import jax
-
-                jax.block_until_ready(sync)
-            self.get(name).add(time.perf_counter() - start)
-
     def print_all(self, log=None):
         if log is None:
             from paddle_tpu.utils.logger import logger as log_mod
@@ -89,7 +77,6 @@ class StatSet:
 
 
 global_stats = StatSet("global")
-timer = global_stats.timer
 
 
 @contextmanager

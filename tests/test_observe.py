@@ -40,6 +40,73 @@ def test_span_nesting_durations_and_stats():
     assert agg["outer"]["count"] == 1 and agg["inner"]["count"] == 1
 
 
+def test_span_parent_and_self_time_on_two_threads_at_once():
+    """Each thread keeps its own stack of open spans: a span's parent is
+    the one open on ITS thread, its self time its duration less what its
+    children covered, and the Chrome export's args carry the parent."""
+    import threading
+    import time
+
+    tracer = spans.SpanTracer("t", stats=None)
+    both_open = threading.Barrier(2)
+    seen = {}
+
+    def work(tag, nap):
+        with tracer.span(tag + "_outer") as outer:
+            both_open.wait(timeout=10)  # the other thread's outer is open
+            with tracer.span(tag + "_inner") as first:
+                time.sleep(nap)
+            with tracer.span(tag + "_inner") as second:
+                with tracer.span(tag + "_leaf") as leaf:
+                    time.sleep(nap)
+        seen[tag] = (outer, first, second, leaf)
+
+    threads = [threading.Thread(target=work, args=(tag, nap),
+                                name="span-test-" + tag)
+               for tag, nap in (("a", 0.01), ("b", 0.02))]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=10)
+        assert not t.is_alive()
+    for tag in ("a", "b"):
+        outer, first, second, leaf = seen[tag]
+        assert outer.parent is None
+        assert first.parent == second.parent == tag + "_outer"
+        assert leaf.parent == tag + "_inner"
+        assert outer.child_dur == pytest.approx(first.dur + second.dur)
+        assert outer.self_dur == pytest.approx(
+            outer.dur - first.dur - second.dur)
+        assert first.child_dur == 0.0 and first.self_dur == first.dur
+        assert second.self_dur == pytest.approx(second.dur - leaf.dur)
+        assert 0.0 <= second.self_dur < leaf.dur
+    parents = {e["name"]: e.get("args", {}).get("parent")
+               for e in tracer.to_chrome_trace()["traceEvents"]
+               if e["ph"] == "X"}
+    assert parents == {"a_outer": None, "a_inner": "a_outer",
+                       "a_leaf": "a_inner", "b_outer": None,
+                       "b_inner": "b_outer", "b_leaf": "b_inner"}
+
+
+def test_span_left_open_across_a_yield_closes_out_of_order():
+    """A generator that yields inside a span lets the caller open and
+    close spans of its own meanwhile: each still leaves the stack."""
+    tracer = spans.SpanTracer("t", stats=None)
+
+    def gen():
+        with tracer.span("held"):
+            yield
+
+    with tracer.span("outer"):
+        g = gen()
+        next(g)  # "held" is open, above "outer"
+    g.close()  # closes "held" after "outer" has gone
+    with tracer.span("after") as after:
+        pass
+    assert after.parent is None
+    assert [ev[0] for ev in tracer.events()] == ["outer", "held", "after"]
+
+
 def test_span_disabled_records_nothing_but_still_times():
     stats = StatSet("test")
     tracer = spans.SpanTracer("t", stats=stats)
@@ -91,7 +158,8 @@ def test_chrome_trace_export_parses(tmp_path):
 
 def test_chrome_trace_gz_export(tmp_path):
     tracer = spans.SpanTracer("unit", stats=None)
-    tracer.instant("marker")
+    with tracer.span("marker"):
+        pass
     path = tracer.export(str(tmp_path / "trace.json.gz"))
     with gzip.open(path, "rt") as fh:
         data = json.load(fh)

@@ -64,10 +64,13 @@ def test_registry():
 
 
 def test_statset():
+    from paddle_tpu.observe.spans import SpanTracer
+
     stats = StatSet("test")
-    with stats.timer("op"):
+    tracer = SpanTracer("t", stats=stats)
+    with tracer.span("op"):
         pass
-    with stats.timer("op"):
+    with tracer.span("op"):
         pass
     info = stats.get("op")
     assert info.count == 2
