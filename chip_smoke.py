@@ -16,7 +16,10 @@ It runs its stages as children, one after another:
           Mosaic; the serving reference (paddle.inference.infer); the
           flagship 2xLSTM text classifier through paddle.init ->
           SGD.train, first a dispatch per step, then steps_per_call=K;
-          the Mosaic call in the lowered step; one profiler capture.
+          the Mosaic call in the lowered step; one profiler capture;
+          twelve batches through a DeviceFeeder, each read back from the
+          device and compared with its rows (the feeder's recycled host
+          buffers against transfers still in flight).
   export  python -m paddle_tpu.cli export --use-tpu ... --decode-slots
   serve   python -m paddle_tpu.cli serve --use-tpu <bundle> --continuous;
           /readyz, POST /infer at several lengths against the reference,
@@ -67,6 +70,9 @@ FULL = {
                "hidden": 256},
     "batch_sizes": "1,8", "seq_len": 128, "slots": 48, "window": 6,
     "request_lens": [5, 17, 40, 3, 64, 128],
+    # the ResNet-50 cells' rows (3x224x224 float32, 256 a chip): three
+    # turns of the feeder's ring of four host buffers
+    "feed": {"dim": 3 * 224 * 224, "batch": 256, "batches": 12},
 }
 TINY = {
     "flagship": {"dict_size": 50, "emb": 8, "hidden": 8},
@@ -76,6 +82,7 @@ TINY = {
     "tagger": {"dict_size": 50, "label_size": 4, "emb_size": 8, "hidden": 8},
     "batch_sizes": "1,2", "seq_len": 16, "slots": 4, "window": 3,
     "request_lens": [5, 2, 16, 9],
+    "feed": {"dim": 48, "batch": 8, "batches": 12},
 }
 
 
@@ -685,6 +692,76 @@ def phase_train(cfg, args, device):
     return report
 
 
+def phase_feed_readback(cfg, parallelism=None):
+    """What only a chip can show of the feeder's recycled host buffers: a
+    placement returns before the bytes have left the host, so a buffer
+    written too early sends a mixture of two batches to the device, in
+    silence. Batches of distinct seeded rows go through a DeviceFeeder as
+    fast as its producer makes them (all kept, so the ring of depth + 2
+    buffers wraps with transfers in flight); each yielded feed is then read
+    back and must equal its rows exactly. With a ``parallelism`` the batch
+    is one of 256 rows a device, placed on the mesh."""
+    import numpy as np
+
+    import jax
+
+    from paddle_tpu import data_type as dt, layer as L
+    from paddle_tpu.data.feeder import DeviceFeeder
+    from paddle_tpu.observe.metrics import MetricsRegistry
+    from paddle_tpu.topology import Topology
+
+    sizes = cfg["feed"]
+    devices = 1 if parallelism is None else parallelism.mesh.size
+    rows, n, classes = sizes["batch"] * devices, sizes["batches"], 1000
+    tag = "readback%d" % devices  # layer names are the process's
+    image = L.data(name=tag + "_image", type=dt.dense_vector(sizes["dim"]))
+    label = L.data(name=tag + "_label", type=dt.integer_value(classes))
+    topology = Topology(L.classification_cost(
+        input=L.fc(input=image, size=classes), label=label))
+    made = []
+    for i in range(n):
+        rng = np.random.default_rng(SEED + i)
+        made.append((rng.random((rows, sizes["dim"]), dtype=np.float32),
+                     rng.integers(0, classes, rows)))
+    batches = [[(images[j], int(labels[j])) for j in range(rows)]
+               for images, labels in made]
+    registry = MetricsRegistry()
+    depth = 2
+    feeder = DeviceFeeder(lambda: iter(batches), topology, depth=depth,
+                          parallelism=parallelism, metrics_registry=registry)
+    taken = list(feeder.batches())
+    require(len(taken) == n, "the feeder yielded %d of %d batches",
+            len(taken), n)
+    for i, (fb, (images, labels)) in enumerate(zip(taken, made)):
+        got = jax.device_get(fb.feed)
+        spans = len(fb.feed[tag + "_image"].sharding.device_set)
+        require(spans == devices, "batch %d lies on %d devices, expected %d",
+                i, spans, devices)
+        require(np.array_equal(got[tag + "_image"], images),
+                "batch %d of %d read back from the device differs from its "
+                "rows: %d of %d values", i, n,
+                int((got[tag + "_image"] != images).sum()), images.size)
+        require(np.array_equal(got[tag + "_label"],
+                               labels.astype(np.int32)),
+                "batch %d: labels read back differ from its rows", i)
+    snap = registry.snapshot()
+    ring = depth + 2
+    counted = {k: snap["counters"].get(
+        "paddle_tpu_data_feed_buffers_%s_total" % k, 0)
+        for k in ("reused", "allocated")}
+    require(counted == {"reused": 2 * (n - ring), "allocated": 2 * ring},
+            "%d batches of two columns over a ring of %d: %r", n, ring,
+            counted)
+    waits = snap["histograms"]["paddle_tpu_data_feed_buffer_wait_ms"]
+    return dict(counted, batches=n, rows=rows, devices=devices,
+                megabytes_a_batch=round(rows * sizes["dim"] * 4 / 1e6, 1),
+                buffer_wait_ms_mean=waits["sum"] / waits["count"],
+                host_ms_mean=sum(fb.host_ms for fb in taken[ring:])
+                / (n - ring),
+                place_ms_mean=sum(fb.place_ms for fb in taken[ring:])
+                / (n - ring))
+
+
 def stage_chip(args):
     cfg = TINY if args.dry_run_cpu else FULL
     device, env_report = open_device(args)
@@ -696,6 +773,7 @@ def stage_chip(args):
     report["kernels"] = phase_kernels(cfg)
     phase_serve_reference(cfg)
     report["train"] = phase_train(cfg, args, device)
+    report["feed_readback"] = phase_feed_readback(cfg)
     report["compile_cache"] = compile_cache.stats()
     with open(os.path.join(WORK, "chip.json"), "w") as fh:
         json.dump(report, fh)
@@ -787,6 +865,8 @@ def stage_multichip(args):
                        "update_rel_err_vs_one_chip": worst,
                        "losses": losses, "feed_spans_devices": n,
                        "mosaic_calls": mosaic}
+    report["feed_readback"] = [phase_feed_readback(cfg),
+                               phase_feed_readback(cfg, dp)]
 
     # the same bundle cli export writes, as N one-chip replicas; serving
     # runs under the framework's default precision, not the benchmark's

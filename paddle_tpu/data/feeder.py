@@ -37,10 +37,11 @@ span so traces show the step thread's wait. The producer's cycle is
 four spans on its own thread, each with a histogram of its own:
 ``feed_read`` (the reader's ``next()``), ``feed_convert`` (rows to a
 feed on the device: its ``feed_place`` children are the hand-overs of
-host bytes to a device, its self time is host assembly) and ``feed_put``
-(blocked on a full queue: the feed is ahead, the device is the bound).
-The trainer additionally writes a ``feed`` steplog record per step
-(docs/observability.md).
+host bytes to a device, its ``feed_buffer_wait`` children the waits for
+a recycled host buffer's last transfer, its self time is host assembly)
+and ``feed_put`` (blocked on a full queue: the feed is ahead, the device
+is the bound). The trainer additionally writes a ``feed`` steplog record
+per step (docs/observability.md).
 """
 
 import queue
@@ -72,27 +73,30 @@ class _Error:
 class FeedBatch:
     """One pipelined batch: the device-resident ``feed`` dict plus its
     accounting — ``seq`` (the producer's count of batches, the pass's
-    batch number), ``examples`` (rows), the producer thread's four
+    batch number), ``examples`` (rows), the producer thread's
     durations ``read_ms`` (the reader's ``next()``), ``convert_ms`` =
     ``host_ms`` (host assembly) + ``place_ms`` (handing the bytes to the
-    device), and ``backpressure_ms`` (the producer blocked on a full
+    device) + ``buffer_wait_ms`` (waiting for the last transfer out of a
+    recycled host buffer; 0 for a batch that recycled none), and
+    ``backpressure_ms`` (the producer blocked on a full
     queue, written once the batch is in the queue), ``stall_ms`` (time
     the consumer blocked waiting for it), and for sequence feeds
     ``bucket`` (padded length), ``fill_tokens``/``pad_tokens``."""
 
     __slots__ = ("feed", "seq", "examples", "read_ms", "convert_ms",
-                 "place_ms", "backpressure_ms", "stall_ms", "bucket",
-                 "fill_tokens", "pad_tokens")
+                 "place_ms", "buffer_wait_ms", "backpressure_ms", "stall_ms",
+                 "bucket", "fill_tokens", "pad_tokens")
 
     def __init__(self, feed, examples, convert_ms, bucket=None,
                  fill_tokens=None, pad_tokens=None, seq=None, read_ms=0.0,
-                 place_ms=0.0):
+                 place_ms=0.0, buffer_wait_ms=0.0):
         self.feed = feed
         self.seq = seq
         self.examples = examples
         self.read_ms = read_ms
         self.convert_ms = convert_ms
         self.place_ms = place_ms
+        self.buffer_wait_ms = buffer_wait_ms
         self.backpressure_ms = 0.0  # set by the producer after the put
         self.stall_ms = None  # set by the consumer
         self.bucket = bucket
@@ -101,7 +105,7 @@ class FeedBatch:
 
     @property
     def host_ms(self):
-        return self.convert_ms - self.place_ms
+        return self.convert_ms - self.place_ms - self.buffer_wait_ms
 
 
 class ChunkBatch:
@@ -158,6 +162,117 @@ def _seq_stats(feed):
     return bucket, fill, slots - fill
 
 
+class _Slot:
+    """One recycled host array and the last device leaf made from it."""
+
+    __slots__ = ("host", "leaf")
+
+    def __init__(self, host):
+        self.host = host
+        self.leaf = None
+
+
+class _Ring:
+    """A column's slots, oldest first, all of one shape and dtype."""
+
+    __slots__ = ("shape", "dtype", "slots", "next")
+
+    def __init__(self, shape, dtype):
+        self.shape = shape
+        self.dtype = dtype
+        self.slots = []
+        self.next = 0  # of the slot the next batch is assembled into
+
+
+class HostBuffers:
+    """The host memory a :class:`DeviceFeeder` assembles its batches in.
+
+    For each fixed-shape dense or index column a ring of ``slots`` host
+    arrays, taken in turn, so that once the ring has filled no batch
+    allocates, first-touches or unmaps host memory. A fresh 154 MB array
+    costs ten times its copy in page faults (PERF.md).
+
+    The owner calls :meth:`assemble` for a column of each batch (through
+    ``topology.convert_feed`` inside ``topology.recycling_into``) and
+    :meth:`sent` once the batch is in its final place. The rule that makes recycling safe: a slot
+    remembers the **final** device leaf made from it (on a mesh, the
+    sharded one: ready means host to device 0 and device 0 to mesh are
+    both done), and before the slot is written again the producer waits
+    for that leaf (``block_until_ready``) and lets go of it. A transfer
+    reads the host array for tens of milliseconds after the placing call
+    has returned, and nothing is written under it. The placement itself
+    is a copy the slot does not share (``topology._place``). With as many
+    slots as batches can be alive at once the wait is zero whenever the
+    feed is ahead.
+
+    What it does not take, by what it sees: rows that do not form one
+    rectangular array (``assemble`` returns None and the caller's own
+    ``np.asarray`` raises what it raised), and a batch of fewer rows than
+    the column's ring, the short last batch of a pass, which gets a fresh
+    array while the full-size slots stay. Any other new shape starts the
+    column's ring afresh. Not safe for two threads at once: one producer
+    uses it at a time.
+    """
+
+    def __init__(self, slots, reused, allocated):
+        self.slots = int(slots)  # of each column's ring
+        self._reused = reused        # counters of the owner's registry
+        self._allocated = allocated
+        self._rings = {}    # column name -> _Ring
+        self._filled = {}   # column name -> the slot this batch was put in
+        self._waited = None  # seconds this batch waited; None: reused none
+
+    def assemble(self, name, col, dtype):
+        """``col``'s rows in one of the column's host arrays, equal to
+        ``np.asarray(col, dtype=dtype)``, or None for rows this does not
+        take."""
+        try:
+            rows = [row if isinstance(row, np.ndarray)
+                    else np.asarray(row, dtype=dtype) for row in col]
+        except (TypeError, ValueError, OverflowError):
+            return None  # the caller's np.asarray says what is wrong
+        if not rows or any(row.shape != rows[0].shape for row in rows):
+            return None
+        shape = (len(rows),) + rows[0].shape
+        ring = self._rings.get(name)
+        if ring is None or (shape[1:], dtype) != (ring.shape[1:], ring.dtype) \
+                or shape[0] > ring.shape[0]:
+            ring = self._rings[name] = _Ring(shape, dtype)
+        elif shape[0] < ring.shape[0]:
+            return None  # a short last batch
+        if len(ring.slots) < self.slots:
+            slot = _Slot(np.empty(shape, dtype))
+            ring.slots.append(slot)
+            self._allocated.inc()
+        else:
+            slot = ring.slots[ring.next % len(ring.slots)]
+            self._reused.inc()
+            self._wait_for(slot)
+        ring.next += 1
+        # rows of another dtype are cast as np.asarray casts them
+        np.stack(rows, out=slot.host, casting="unsafe")
+        self._filled[name] = slot
+        return slot.host
+
+    def _wait_for(self, slot):
+        with observe_spans.span("feed_buffer_wait") as wait:
+            if slot.leaf is not None:
+                slot.leaf.block_until_ready()
+                slot.leaf = None
+        self._waited = (self._waited or 0.0) + wait.dur
+
+    def sent(self, feed):
+        """The batch is in its final place: every slot it was assembled
+        into holds its leaf of ``feed`` until that slot's next turn.
+        Returns the milliseconds the batch waited for its slots, None for
+        a batch that recycled none."""
+        for name, slot in self._filled.items():
+            slot.leaf = feed[name]
+        waited, self._waited = self._waited, None
+        self._filled = {}
+        return None if waited is None else waited * 1e3
+
+
 class DeviceFeeder:
     """Background-thread feed pipeline over a minibatch reader.
 
@@ -166,6 +281,16 @@ class DeviceFeeder:
     training pass, mirroring the per-pass ``reader()`` iterator). Use
     ``convert=`` to override batch conversion (e.g. ``pack_feed``) —
     signature ``convert(topology, data_batch, feeding, max_len)``.
+
+    The feeder owns the host memory its batches are assembled in
+    (:class:`HostBuffers`): one pool a feeder, so it lives across the
+    passes of one ``train`` call and dies with it. Fixed-shape dense and
+    index columns are copied into a ring of ``depth + 2`` recycled arrays
+    (the queue, the batch being assembled and the one in the step), and
+    a reader's rows are copied, never kept. Sequence, nested and sparse
+    slots, a short last batch and a custom ``convert=`` get fresh arrays
+    as before; so does every caller of ``convert_feed`` that is not a
+    feeder, since nobody there can say when a batch is dead.
     """
 
     def __init__(self, reader, topology, feeding=None, depth=2,
@@ -201,6 +326,19 @@ class DeviceFeeder:
             "paddle_tpu_data_feed_backpressure_ms",
             help="time the producer blocked on a full queue: the feed is "
                  "ahead of the step")
+        self._m_buffer_wait = m.histogram(
+            "paddle_tpu_data_feed_buffer_wait_ms",
+            help="producer-thread wait for the last transfer out of the "
+                 "host buffers a batch recycled (batches that recycled "
+                 "one)")
+        self._buffers = HostBuffers(
+            self.depth + 2,
+            reused=m.counter(
+                "paddle_tpu_data_feed_buffers_reused_total",
+                help="columns assembled into a recycled host buffer"),
+            allocated=m.counter(
+                "paddle_tpu_data_feed_buffers_allocated_total",
+                help="host buffers allocated for the feeder's rings"))
         self._m_batches = m.counter(
             "paddle_tpu_data_batches_total",
             help="batches assembled by the feed pipeline")
@@ -211,7 +349,7 @@ class DeviceFeeder:
 
     # -- producer side ------------------------------------------------------
     def _convert_batch(self, data_batch):
-        from paddle_tpu.topology import convert_feed
+        from paddle_tpu import topology
 
         max_len = data_batch.bucket if isinstance(data_batch, BucketBatch) \
             else None
@@ -219,11 +357,14 @@ class DeviceFeeder:
             feed = self._convert(self.topology, data_batch, self.feeding,
                                  max_len)
         else:
-            feed = convert_feed(self.topology, data_batch, self.feeding,
-                                max_len=max_len)
+            with topology.recycling_into(self._buffers):
+                feed = topology.convert_feed(self.topology, data_batch,
+                                             self.feeding, max_len=max_len)
         if self.parallelism is not None:
             # the DataParallel global-mesh placement shard_train_step
-            # would apply — done HERE so the transfer overlaps compute
+            # would apply — done HERE so the transfer overlaps compute.
+            # The device-0 arrays die with this rebinding: the slots hold
+            # the mesh's leaves
             feed = self.parallelism.shard_batch(feed)
         return feed
 
@@ -251,17 +392,28 @@ class DeviceFeeder:
                         return
                     seq += 1
                     continue
+                if cancel.is_set():
+                    # an abandoned producer that outlived its join must
+                    # not touch the buffers its successor is using
+                    return
                 with span("feed_convert", args={"batch": seq}) as convert:
                     feed = self._convert_batch(data_batch)
+                waited = self._buffers.sent(feed)  # ms, None: recycled none
                 bucket, fill, pad = _seq_stats(feed)
+                # feed_convert's children are its placements and its
+                # waits for a recycled buffer: place_ms is the former only
+                wait_ms = waited or 0.0
                 fb = FeedBatch(feed, len(data_batch), convert.dur * 1e3,
                                bucket=bucket, fill_tokens=fill,
                                pad_tokens=pad, seq=seq,
                                read_ms=read.dur * 1e3,
-                               place_ms=convert.child_dur * 1e3)
+                               place_ms=convert.child_dur * 1e3 - wait_ms,
+                               buffer_wait_ms=wait_ms)
                 self._m_read.observe(fb.read_ms)
                 self._m_host.observe(fb.host_ms)
                 self._m_place.observe(fb.place_ms)
+                if waited is not None:
+                    self._m_buffer_wait.observe(waited)
                 with span("feed_put", args={"batch": seq}) as blocked:
                     taken = put(fb)
                 if not taken:
@@ -283,6 +435,10 @@ class DeviceFeeder:
         ``skip=N`` drops the reader's first N batches unconverted — the
         resume cursor of a checkpointed run (docs/distributed.md)."""
         q = queue.Queue(maxsize=self.depth)
+        # as many slots as batches can be alive at once: the queue, the
+        # one being assembled, the one in the step (chunks() deepens the
+        # queue to k for the members of an open chunk)
+        self._buffers.slots = self.depth + 2
         cancel = threading.Event()
         thread = threading.Thread(
             target=self._produce, args=(q, cancel, int(skip)),

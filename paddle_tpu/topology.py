@@ -8,6 +8,10 @@ so the runtime artifact is a single fused XLA program, not a per-layer
 interpreter.
 """
 
+import contextlib
+import functools
+import threading
+
 import numpy as np
 
 import jax
@@ -253,6 +257,28 @@ class Topology:
         return self._data_types
 
 
+# the HostBuffers of the DeviceFeeder that is converting on this thread
+_owner = threading.local()
+
+
+@contextlib.contextmanager
+def recycling_into(buffers):
+    """Inside this scope, on this thread, :func:`convert_feed` assembles
+    fixed-shape dense and index columns into ``buffers`` (a
+    ``data.feeder.HostBuffers``). Only an owner that knows when a batch's
+    bytes have left the host may enter it: the DeviceFeeder's producer. A
+    scope and not an argument of ``convert_feed``, so that the pool
+    reaches the real ``convert_feed`` through whatever a caller has put in
+    its place (a wrapper of its four arguments, as the benchmark's
+    stand-ins are), and no caller without an owner passes one."""
+    previous = getattr(_owner, "buffers", None)
+    _owner.buffers = buffers
+    try:
+        yield
+    finally:
+        _owner.buffers = previous
+
+
 def convert_feed(topology, data_batch, feeding=None, max_len=None):
     """Convert a host minibatch (list of tuples, v2 reader convention) into
     device-ready feed values according to each data layer's InputType.
@@ -269,7 +295,13 @@ def convert_feed(topology, data_batch, feeding=None, max_len=None):
     pad single-level sequence slots to exactly this width instead of the
     batch-max bucket — one jit cache entry per bucket. Default None is
     the historical behavior, bit for bit.
+
+    Inside a DeviceFeeder's :func:`recycling_into` scope the fixed-shape
+    dense and index columns are assembled into its recycled host memory;
+    every other caller gets a fresh ``np.asarray`` per column, bit for
+    bit.
     """
+    buffers = getattr(_owner, "buffers", None)
     names = [name for name, _ in topology.data_types()]
     if feeding is None:
         feeding = {name: i for i, name in enumerate(names)}
@@ -282,16 +314,25 @@ def convert_feed(topology, data_batch, feeding=None, max_len=None):
                 "sample tuple of length %d has no column %d for data layer %r "
                 "(feeding=%r)", len(row), idx, name, feeding)
         col = [row[idx] for row in data_batch]
-        feed[name] = convert_column(col, itype, max_len=max_len)
+        assemble = None if buffers is None else \
+            functools.partial(buffers.assemble, name)
+        feed[name] = convert_column(col, itype, max_len=max_len,
+                                    assemble=assemble)
     return feed
 
 
-def convert_column(col, itype, max_len=None):
+def convert_column(col, itype, max_len=None, assemble=None):
+    """One column of rows to its feed value. ``assemble(col, dtype)``,
+    where given, copies the rows of a fixed-shape column into a host
+    array its owner recycles, or returns None for rows it does not take
+    (``data.feeder.HostBuffers.assemble``)."""
     if itype.seq_type == SEQ_NONE:
-        if itype.value_type == DENSE:
-            return _place(np.asarray(col, dtype=np.float32))
-        if itype.value_type == INDEX:
-            return _place(np.asarray(col, dtype=np.int32))
+        if itype.value_type in (DENSE, INDEX):
+            dtype = np.float32 if itype.value_type == DENSE else np.int32
+            host = None if assemble is None else assemble(col, dtype)
+            if host is None:
+                return _place(np.asarray(col, dtype=dtype))
+            return _place(host, recycled=True)
         if itype.value_type in (SPARSE_BINARY, SPARSE_FLOAT):
             if itype.dim >= flags.get_flag("sparse_feed_threshold"):
                 # true sparse path: padded id lists + gather/weighted-sum
@@ -322,12 +363,30 @@ def convert_column(col, itype, max_len=None):
     raise TypeError("unsupported input type %r" % (itype,))
 
 
-def _place(host):
+def _place(host, recycled=False):
     """Hand an assembled host array to the device, as a ``feed_place``
     span: what is left of the enclosing ``feed_convert`` is host
-    assembly."""
+    assembly. A ``recycled`` array will be written again, so what is
+    placed from it must be a copy: the CPU platform wraps a suitably
+    aligned numpy array instead of copying it (alignment decides, and
+    ``device_put(may_alias=False)`` does not reach numpy inputs on jax
+    0.9), and there the placed array is copied on the device."""
     with observe_spans.span("feed_place"):
-        return jnp.asarray(host)
+        placed = jnp.asarray(host)
+        if recycled and _lives_in(placed, host):
+            placed = jnp.copy(placed)
+        return placed
+
+
+def _lives_in(placed, host):
+    """Whether a placed array's buffer lies inside ``host``'s own bytes.
+    Only a device whose memory is the host's can; a TPU's transfer is
+    never asked (its pointer would wait for the bytes to land)."""
+    device, = placed.devices()  # jnp.asarray places on one
+    if device.platform != "cpu":
+        return False
+    start = host.ctypes.data
+    return start <= placed.unsafe_buffer_pointer() < start + host.nbytes
 
 
 def _densify(rows, itype):

@@ -44,7 +44,7 @@ from paddle_tpu.utils.stat import global_stats
 # a finalized step's phases: the step thread's own, and the producer
 # thread's for the batch it waited for (FeedBatch.<name>_ms)
 _STEP_PHASES = ("wait", "dispatch", "readback", "handler")
-_FEED_PHASES = ("read", "host", "place", "backpressure")
+_FEED_PHASES = ("read", "host", "place", "buffer_wait", "backpressure")
 
 
 def _make_replica(trainable):

@@ -596,3 +596,460 @@ def test_feeder_cancel_honored_mid_skip_prefix():
     cancel.set()
     t.join(timeout=2.0)
     assert not t.is_alive()
+
+
+# ---- the feeder's recycled host buffers ------------------------------------
+
+from paddle_tpu.data import feeder as feeder_mod  # noqa: E402
+from paddle_tpu.topology import convert_column  # noqa: E402
+
+RING = 4  # depth 2 + the batch being assembled + the one in the step
+REUSED = "paddle_tpu_data_feed_buffers_reused_total"
+ALLOCATED = "paddle_tpu_data_feed_buffers_allocated_total"
+BUFFER_WAIT = "paddle_tpu_data_feed_buffer_wait_ms"
+
+
+def _labelled_model(dim=6, classes=4):
+    reset_name_counters()
+    x = L.data(name="x", type=dt.dense_vector(dim))
+    y = L.data(name="y", type=dt.integer_value(classes))
+    return L.classification_cost(input=L.fc(input=x, size=classes), label=y)
+
+
+ROW_KINDS = {
+    "float32": lambda v: v.astype(np.float32),
+    "float64": lambda v: v.astype(np.float64),
+    "int16": lambda v: (v * 100).astype(np.int16),
+    "list": lambda v: [float(e) for e in v],
+    "tuple": lambda v: tuple(float(e) for e in v),
+    "nested_list": lambda v: [[float(e) for e in v[:3]],
+                              [float(e) for e in v[3:]]],
+}
+LABEL_KINDS = {
+    "int": int,
+    "numpy_int64": np.int64,
+    "zero_d_array": lambda i: np.array(i, dtype=np.int64),
+    "float": float,
+}
+
+
+def _labelled_batches(n, rows=8, dim=6, classes=4, seed=0, row=None,
+                      label=int):
+    rng = np.random.RandomState(seed)
+    row = row or ROW_KINDS["float32"]
+    return [[(row(rng.randn(dim)), label(rng.randint(classes)))
+             for _ in range(rows)] for _ in range(n)]
+
+
+def _counters(reg):
+    counters = reg.snapshot()["counters"]
+    return counters.get(REUSED, 0), counters.get(ALLOCATED, 0)
+
+
+def _aligned_like(host, align=64):
+    """An empty array of ``host``'s shape whose bytes start on an
+    ``align`` boundary: what the CPU platform wraps instead of copying."""
+    raw = np.empty(host.nbytes + align, np.uint8)
+    start = (-raw.ctypes.data) % align
+    return raw[start:start + host.nbytes].view(host.dtype).reshape(host.shape)
+
+
+@pytest.fixture
+def aligned_slots(monkeypatch):
+    """Every recycled buffer lies where the CPU platform would share it
+    with the array placed from it: the worst case for an overwrite."""
+    class AlignedSlot(feeder_mod._Slot):
+        def __init__(self, host):
+            super().__init__(_aligned_like(host))
+
+    monkeypatch.setattr(feeder_mod, "_Slot", AlignedSlot)
+
+
+@pytest.mark.parametrize("placement", ["one_device", "mesh"])
+def test_recycled_buffers_never_change_a_feed_that_is_alive(
+        placement, aligned_slots):
+    """(a) ring + 3 batches of distinct rows, every feed kept alive: after
+    the last batch was made each still holds its own rows."""
+    from paddle_tpu.parallel.mesh import DataParallel, build_mesh
+
+    parallelism = None if placement == "one_device" else DataParallel(
+        build_mesh({"data": 2}, devices=jax.devices()[:2]))
+    topo = Topology(_labelled_model())
+    batches = _labelled_batches(RING + 3)
+    reg = observe_metrics.MetricsRegistry()
+    feeder = DeviceFeeder(lambda: iter(batches), topo, depth=2,
+                          parallelism=parallelism, metrics_registry=reg)
+    kept = list(feeder.batches())
+    assert len(kept) == RING + 3
+    assert _counters(reg) == (2 * 3, 2 * RING)  # the ring did wrap
+    for fb, batch in zip(kept, batches):
+        np.testing.assert_array_equal(
+            np.asarray(fb.feed["x"]),
+            np.asarray([r[0] for r in batch], dtype=np.float32))
+        np.testing.assert_array_equal(
+            np.asarray(fb.feed["y"]),
+            np.asarray([r[1] for r in batch], dtype=np.int32))
+
+
+def test_a_recycled_array_is_placed_as_a_copy():
+    """The CPU platform wraps an aligned numpy array; what is placed from
+    a recycled one must not see the next batch's bytes."""
+    from paddle_tpu.topology import _lives_in, _place
+
+    host = _aligned_like(np.empty((4, 6), np.float32))
+    host[...] = 1.0
+    fresh = _place(host)
+    assert _lives_in(fresh, host)  # today's placement: shared, never written
+    placed = _place(host, recycled=True)
+    assert not _lives_in(placed, host)
+    host[...] = 2.0
+    assert float(np.asarray(placed).max()) == 1.0
+
+
+@pytest.mark.parametrize("kind", sorted(ROW_KINDS))
+def test_recycled_feeds_are_bit_identical_to_convert_feed(kind):
+    """(b) with the pool a feeder's feeds equal `convert_feed` without it,
+    value, dtype and shape, whatever the rows are made of."""
+    topo = Topology(_labelled_model())
+    batches = _labelled_batches(RING + 3, row=ROW_KINDS[kind], seed=3)
+    reg = observe_metrics.MetricsRegistry()
+    feeder = DeviceFeeder(lambda: iter(batches), topo, metrics_registry=reg)
+    got = list(feeder.batches())
+    assert _counters(reg) == (2 * 3, 2 * RING)
+    for fb, batch in zip(got, batches):
+        want = convert_feed(topo, batch)
+        for name in ("x", "y"):
+            assert fb.feed[name].dtype == want[name].dtype
+            assert fb.feed[name].shape == want[name].shape
+            np.testing.assert_array_equal(np.asarray(fb.feed[name]),
+                                          np.asarray(want[name]))
+
+
+@pytest.mark.parametrize("kind", sorted(LABEL_KINDS))
+def test_recycled_labels_are_bit_identical_to_convert_feed(kind):
+    topo = Topology(_labelled_model())
+    batches = _labelled_batches(RING + 2, label=LABEL_KINDS[kind], seed=5)
+    reg = observe_metrics.MetricsRegistry()
+    got = list(DeviceFeeder(lambda: iter(batches), topo,
+                            metrics_registry=reg).batches())
+    assert _counters(reg) == (2 * 2, 2 * RING)
+    for fb, batch in zip(got, batches):
+        want = convert_feed(topo, batch)["y"]
+        assert fb.feed["y"].dtype == want.dtype == jnp.int32
+        np.testing.assert_array_equal(np.asarray(fb.feed["y"]),
+                                      np.asarray(want))
+
+
+def _pool(slots=RING):
+    reg = observe_metrics.MetricsRegistry()
+    return feeder_mod.HostBuffers(
+        slots, reused=reg.counter(REUSED), allocated=reg.counter(ALLOCATED)
+    ), reg
+
+
+@pytest.mark.parametrize("rows, error", [
+    ([np.zeros(3), np.zeros(4)], ValueError),          # ragged arrays
+    ([[1.0, 2.0], [1.0]], ValueError),                 # ragged lists
+    ([np.zeros(3), np.zeros(1)], ValueError),          # would broadcast
+    ([1, 2 ** 40], OverflowError),                     # no int32
+    ([], None),                                        # no rows
+])
+def test_rows_that_form_no_array_take_the_old_line(rows, error):
+    """The pool declines, and the caller's `np.asarray` raises what it
+    raised (or makes the empty array it made)."""
+    pool, reg = _pool()
+    dtype = np.int32 if error is OverflowError else np.float32
+    assert pool.assemble("x", rows, dtype) is None
+    assert _counters(reg) == (0, 0)
+    itype = dt.integer_value(4) if dtype == np.int32 else dt.dense_vector(3)
+    if error is None:
+        assert convert_column(rows, itype, assemble=lambda c, d:
+                              pool.assemble("x", c, d)).shape == (0,)
+        return
+    with pytest.raises(error) as new:
+        convert_column(rows, itype,
+                       assemble=lambda c, d: pool.assemble("x", c, d))
+    with pytest.raises(error) as old:
+        convert_column(rows, itype)
+    assert str(new.value) == str(old.value)
+
+
+def test_allocations_stop_once_the_ring_has_filled():
+    """(c) a 20-batch pass allocates a ring a column and reuses it for
+    the rest; the pool outlives the pass, so a second pass allocates
+    nothing."""
+    topo = Topology(_labelled_model())
+    batches = _labelled_batches(20)
+    reg = observe_metrics.MetricsRegistry()
+    feeder = DeviceFeeder(lambda: iter(batches), topo, depth=2,
+                          metrics_registry=reg)
+    seen = [_counters(reg) for _ in feeder.batches()]
+    reused, allocated = _counters(reg)
+    assert allocated == 2 * RING and reused == 2 * (20 - RING)
+    assert reused / (reused + allocated) == pytest.approx(1 - RING / 20)
+    # the producer runs ahead of the consumer, never behind it
+    assert all(a <= 2 * RING for _, a in seen) and seen[-1][1] == 2 * RING
+    waits = reg.snapshot()["histograms"][BUFFER_WAIT]
+    assert waits["count"] == 20 - RING  # once a batch that recycled
+    assert len(list(feeder.batches())) == 20
+    assert _counters(reg) == (2 * (40 - RING), 2 * RING)
+    assert reg.snapshot()["histograms"][BUFFER_WAIT]["count"] == 40 - RING
+
+
+class _RecordingLeaf:
+    """Stands for the device array made from a slot: records what the
+    slot's host array held when the producer waited for it."""
+
+    def __init__(self, host, log):
+        self.host, self.log = host, log
+
+    def block_until_ready(self):
+        self.log.append(self.host.copy())
+        return self
+
+
+def test_a_slot_is_not_written_before_its_last_leaf_is_ready():
+    """(d) the wait for the previous transfer comes before the write."""
+    pool, reg = _pool(slots=1)
+    first = [np.full(3, 1.0, np.float32), np.full(3, 2.0, np.float32)]
+    second = [np.full(3, 7.0, np.float32), np.full(3, 8.0, np.float32)]
+    host = pool.assemble("x", first, np.float32)
+    log = []
+    leaf = _RecordingLeaf(host, log)
+    assert pool.sent({"x": leaf}) is None  # allocated, nothing to wait for
+    again = pool.assemble("x", second, np.float32)
+    assert again is host  # the one slot, recycled
+    assert len(log) == 1  # waited once, and at that time ...
+    np.testing.assert_array_equal(log[0], np.asarray(first))  # ... unwritten
+    np.testing.assert_array_equal(host, np.asarray(second))
+    slot = pool._rings["x"].slots[0]
+    assert slot.leaf is None  # let go of: the pool pins no dead batch
+    waited = pool.sent({"x": _RecordingLeaf(host, log)})
+    assert waited is not None and waited >= 0.0
+    assert _counters(reg) == (1, 1)
+
+
+def _tagging_batches():
+    samples = _seq_samples(16, lengths=(2, 3))
+    base = minibatch.batch(lambda: iter(samples), 4)
+    return bucketing.rebucket_batches(base, buckets=[4, 8])
+
+
+def test_a_short_last_batch_gets_a_fresh_array_and_the_ring_stays():
+    """(e) the short batch equals today's feed, touches no counter, and
+    the full-size slots go on being recycled after it."""
+    topo = Topology(_labelled_model())
+    full = _labelled_batches(RING + 2, seed=1)
+    short = _labelled_batches(1, rows=3, seed=2)
+    stream = full + short + full[:2]
+    reg = observe_metrics.MetricsRegistry()
+    feeder = DeviceFeeder(lambda: iter(stream), topo, metrics_registry=reg)
+    got = list(feeder.batches())
+    for fb, batch in zip(got, stream):
+        want = convert_feed(topo, batch)
+        for name in ("x", "y"):
+            np.testing.assert_array_equal(np.asarray(fb.feed[name]),
+                                          np.asarray(want[name]))
+    assert got[RING + 2].feed["x"].shape == (3, 6)
+    assert _counters(reg) == (2 * 4, 2 * RING)  # as if it had not come
+
+
+def test_sequence_slots_bypass_the_pool():
+    """(e) a BucketBatch of sequence slots: padded shapes change from
+    batch to batch, nothing is pooled."""
+    topo = Topology(_tagging_model())
+    reg = observe_metrics.MetricsRegistry()
+    got = list(DeviceFeeder(_tagging_batches(), topo,
+                            metrics_registry=reg).batches())
+    assert got and _counters(reg) == (0, 0)
+    assert BUFFER_WAIT not in reg.snapshot()["histograms"] or \
+        reg.snapshot()["histograms"][BUFFER_WAIT]["count"] == 0
+    for fb, batch in zip(got, _tagging_batches()()):
+        want = convert_feed(topo, batch, max_len=batch.bucket)
+        for name, value in want.items():
+            np.testing.assert_array_equal(np.asarray(fb.feed[name].data),
+                                          np.asarray(value.data))
+            np.testing.assert_array_equal(np.asarray(fb.feed[name].lengths),
+                                          np.asarray(value.lengths))
+
+
+@pytest.mark.parametrize("itype, col", [
+    (dt.sparse_binary_vector(5000), [[1, 7], [4999]]),         # SparseRows
+    (dt.sparse_vector(5000), [[(1, 0.5)], [(9, 2.0)]]),  # SparseRows
+    (dt.sparse_binary_vector(16), [[1, 7], [3]]),              # densified
+    (dt.integer_value_sequence(9), [[1, 2, 3], [4]]),
+    (dt.dense_vector_sequence(2), [[[1.0, 2.0]], [[3.0, 4.0], [5.0, 6.0]]]),
+    (dt.integer_value_sub_sequence(9), [[[1, 2], [3]], [[4]]]),
+], ids=["sparse_binary_rows", "sparse_float_rows", "sparse_densified",
+        "index_sequence", "dense_sequence", "nested_sequence"])
+def test_only_fixed_shape_dense_and_index_columns_ask_for_a_buffer(itype,
+                                                                   col):
+    """(e) every other slot kind converts as before and never asks."""
+    asked = []
+
+    def assemble(col, dtype):
+        asked.append(dtype)
+
+    got = convert_column(col, itype, assemble=assemble)
+    want = convert_column(col, itype)
+    assert asked == []
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_a_custom_convert_bypasses_the_pool():
+    """(e) `convert=`: the feeder cannot say what that function keeps."""
+    topo = Topology(_labelled_model())
+    batches = _labelled_batches(RING + 2)
+    seen = []
+
+    def convert(topology, data_batch, feeding, max_len):
+        seen.append(len(data_batch))
+        return convert_feed(topology, data_batch, feeding, max_len=max_len)
+
+    reg = observe_metrics.MetricsRegistry()
+    got = list(DeviceFeeder(lambda: iter(batches), topo, convert=convert,
+                            metrics_registry=reg).batches())
+    assert seen == [8] * (RING + 2) and _counters(reg) == (0, 0)
+    assert all(fb.buffer_wait_ms == 0.0 for fb in got)
+    for fb, batch in zip(got, batches):
+        np.testing.assert_array_equal(
+            np.asarray(fb.feed["x"]), np.asarray(convert_feed(topo, batch)["x"]))
+
+
+@pytest.mark.parametrize("call", ["convert_feed", "train", "test", "infer"])
+def test_callers_without_a_feeder_recycle_nothing(call):
+    """`convert_feed` outside a feeder's scope, `SGD.train(feed_pipeline=
+    False)`, `SGD.test` and `paddle.infer` allocate per batch as they did:
+    the process-wide counters do not move."""
+    cost = _labelled_model()
+    topo = Topology(cost)
+    batches = _labelled_batches(RING + 2)
+    reg = observe_metrics.get_registry()
+    before = _counters(reg)
+    if call == "convert_feed":
+        for batch in batches:
+            feed = convert_feed(topo, batch)
+            np.testing.assert_array_equal(
+                np.asarray(feed["x"]),
+                np.asarray([r[0] for r in batch], dtype=np.float32))
+    else:
+        params = Parameters.create(cost)
+        trainer = paddle.trainer.SGD(cost, params,
+                                     opt.Momentum(learning_rate=1e-2))
+        if call == "train":
+            trainer.train(lambda: iter(batches), num_passes=1,
+                          event_handler=lambda e: None)
+        elif call == "test":
+            trainer.test(lambda: iter(batches))
+        else:
+            out = cost.inputs[0]
+            paddle.infer(output_layer=out, parameters=params,
+                         input=[(r[0],) for r in batches[0]])
+    assert _counters(reg) == before
+
+
+def test_a_stand_in_for_convert_feed_keeps_its_four_arguments(monkeypatch):
+    """The pool reaches `convert_feed` by a scope of the producer's
+    thread, not by an argument: a function of the old signature put in
+    its place (the benchmark's stand-ins) works, and still recycles."""
+    from paddle_tpu import topology as topology_mod
+
+    whole = topology_mod.convert_feed
+    calls = []
+
+    def doubled(topo, data_batch, feeding=None, max_len=None):
+        calls.append(len(data_batch))
+        return whole(topo, data_batch + data_batch, feeding, max_len=max_len)
+
+    monkeypatch.setattr(topology_mod, "convert_feed", doubled)
+    topo = Topology(_labelled_model())
+    batches = _labelled_batches(RING + 2)
+    reg = observe_metrics.MetricsRegistry()
+    got = list(DeviceFeeder(lambda: iter(batches), topo,
+                            metrics_registry=reg).batches())
+    assert calls == [8] * (RING + 2)
+    assert _counters(reg) == (2 * 2, 2 * RING)
+    for fb, batch in zip(got, batches):
+        np.testing.assert_array_equal(
+            np.asarray(fb.feed["x"]),
+            np.asarray([r[0] for r in batch + batch], dtype=np.float32))
+    # the scope was the producer thread's, and is closed
+    assert getattr(topology_mod._owner, "buffers", None) is None
+
+
+def test_the_recycling_scope_nests_and_restores():
+    from paddle_tpu import topology as topology_mod
+
+    outer, _ = _pool()
+    inner, reg = _pool()
+    topo = Topology(_labelled_model())
+    batch = _labelled_batches(1)[0]
+    with topology_mod.recycling_into(outer):
+        with topology_mod.recycling_into(inner):
+            convert_feed(topo, batch)
+        assert topology_mod._owner.buffers is outer
+        assert set(inner._rings) == {"x", "y"} and not outer._rings
+    assert topology_mod._owner.buffers is None
+    assert _counters(reg) == (0, 2)
+
+
+def test_an_open_chunk_holds_k_distinct_buffers_bytes():
+    """(f) chunks(k=3) over depth=2: the queue deepens to 3, the ring to
+    5, and the three members of an open chunk (and every chunk kept) hold
+    their own rows."""
+    topo = Topology(_labelled_model())
+    batches = _labelled_batches(12, seed=4)
+    reg = observe_metrics.MetricsRegistry()
+    feeder = DeviceFeeder(lambda: iter(batches), topo, depth=2,
+                          metrics_registry=reg)
+    chunks = list(feeder.chunks(3))
+    assert feeder.depth == 3 and feeder._buffers.slots == 5
+    assert [c.steps for c in chunks] == [3, 3, 3, 3]
+    assert _counters(reg) == (2 * (12 - 5), 2 * 5)
+    members = [fb for c in chunks for fb in c.batches]
+    for chunk in chunks:
+        assert len({id(m) for m in chunk.feed}) == 3
+    for fb, batch in zip(members, batches):
+        np.testing.assert_array_equal(
+            np.asarray(fb.feed["x"]),
+            np.asarray([r[0] for r in batch], dtype=np.float32))
+    first = [np.asarray(m["x"]) for m in chunks[0].feed]
+    assert not np.array_equal(first[0], first[1])
+    assert not np.array_equal(first[1], first[2])
+
+
+def test_on_a_mesh_a_slot_holds_the_mesh_leaf_not_the_device0_array():
+    """(g) under a DataParallel the slot waits for the sharded leaf (ready
+    means both crossings are done) and keeps nothing of device 0's copy."""
+    from paddle_tpu.parallel.mesh import DataParallel, build_mesh
+
+    n = 4
+    dp = DataParallel(build_mesh({"data": n}, devices=jax.devices()[:n]))
+    topo = Topology(_labelled_model())
+    batches = _labelled_batches(3)
+    feeder = DeviceFeeder(lambda: iter(batches), topo, parallelism=dp,
+                          metrics_registry=observe_metrics.MetricsRegistry())
+    got = list(feeder.batches())
+    for name in ("x", "y"):
+        slots = feeder._buffers._rings[name].slots
+        assert len(slots) == 3
+        for slot, fb in zip(slots, got):
+            assert slot.leaf is fb.feed[name]
+            assert len(slot.leaf.sharding.device_set) == n
+            assert not slot.leaf.sharding.is_fully_replicated
+
+
+def test_a_new_full_shape_starts_the_columns_ring_afresh():
+    """More rows than the ring's (or other trailing dims) is not a short
+    last batch: the ring is remade at the new shape."""
+    pool, reg = _pool(slots=2)
+    small = [np.zeros(3, np.float32)] * 2
+    big = [np.ones(3, np.float32)] * 5
+    assert pool.assemble("x", small, np.float32).shape == (2, 3)
+    pool.sent({"x": _RecordingLeaf(None, [])})
+    assert pool.assemble("x", big, np.float32).shape == (5, 3)
+    pool.sent({"x": _RecordingLeaf(None, [])})
+    assert pool.assemble("x", small, np.float32) is None  # now the short one
+    assert _counters(reg) == (0, 2)

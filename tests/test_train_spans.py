@@ -24,15 +24,17 @@ from paddle_tpu.parameters import Parameters
 from paddle_tpu.utils.logger import logger
 from paddle_tpu.utils.stat import profiler_trace
 
-STEP_NAMES = ("feed_read", "feed_convert", "feed_place", "feed_put", "feed",
-              "train_step", "eval_readback", "handler")
+STEP_NAMES = ("feed_read", "feed_convert", "feed_place", "feed_buffer_wait",
+              "feed_put", "feed", "train_step", "eval_readback", "handler")
 HISTOGRAMS = ("paddle_tpu_data_feed_read_ms", "paddle_tpu_data_feed_host_ms",
               "paddle_tpu_data_feed_place_ms",
               "paddle_tpu_data_feed_backpressure_ms",
               "paddle_tpu_train_dispatch_ms", "paddle_tpu_train_readback_ms",
               "paddle_tpu_train_handler_ms")
 PRODUCERS = HISTOGRAMS[:4]
+BUFFER_WAIT = "paddle_tpu_data_feed_buffer_wait_ms"
 DEPTH = 2
+RING = DEPTH + 2  # the feeder's recycled host buffers a column
 
 
 def _trainer(parallelism=None, dim=8, classes=4):
@@ -80,8 +82,9 @@ def test_a_steps_spans_lie_on_the_profilers_host_lines(tmp_path):
     trainer = _trainer()
     trainer.train(_batches(1), event_handler=lambda e: None,
                   feed_pipeline=True)  # compiles outside the trace
+    steps = RING + 2  # the last two batches recycle a host buffer
     with profiler_trace(str(tmp_path)):
-        trainer.train(_batches(3), event_handler=lambda e: None,
+        trainer.train(_batches(steps), event_handler=lambda e: None,
                       feed_pipeline=True)
     path = sorted(glob.glob(os.path.join(
         str(tmp_path), "plugins", "profile", "*", "*.xplane.pb")))[-1]
@@ -104,19 +107,22 @@ def test_a_steps_spans_lie_on_the_profilers_host_lines(tmp_path):
     step = [n for n, names in lines.items() if "train_step" in names]
     assert len(producer) == 1 and len(step) == 1 and producer != step
     # the producer's four are on its line, the step thread's four on its own
-    assert {"feed_read", "feed_convert", "feed_place",
+    assert {"feed_read", "feed_convert", "feed_place", "feed_buffer_wait",
             "feed_put"} <= set(lines[producer[0]])
     assert {"feed", "train_step", "eval_readback",
             "handler"} <= set(lines[step[0]])
-    assert "feed_place" not in lines[step[0]]
+    assert not {"feed_place", "feed_buffer_wait"} & set(lines[step[0]])
     converts = lines[producer[0]]["feed_convert"]
-    assert len(converts) == 3
-    for start, end, _ in lines[producer[0]]["feed_place"]:
-        assert any(c0 <= start and end <= c1 for c0, c1, _ in converts)
+    assert len(converts) == steps
+    for name in ("feed_place", "feed_buffer_wait"):
+        for start, end, _ in lines[producer[0]][name]:
+            assert any(c0 <= start and end <= c1 for c0, c1, _ in converts)
+    assert len(lines[producer[0]]["feed_buffer_wait"]) == 2 * 2
     # the number that varies is the event's stat, never part of its name
-    assert sorted(stats["batch"] for _, _, stats in converts) == [0, 1, 2]
     assert sorted(stats["batch"] for _, _, stats
-                  in lines[step[0]]["train_step"]) == [0, 1, 2]
+                  in converts) == list(range(steps))
+    assert sorted(stats["batch"] for _, _, stats
+                  in lines[step[0]]["train_step"]) == list(range(steps))
 
 
 # -- (c) the seven histograms, and host + place == convert -------------------
@@ -150,6 +156,9 @@ def test_host_plus_place_is_convert_and_the_mesh_places_once_more(recorded):
     taken = list(DeviceFeeder(_batches(4), trainer.topology).batches())
     assert [fb.seq for fb in taken] == [0, 1, 2, 3]
     for fb in taken:
+        # no batch of the first ring recycles a buffer: the wait is 0 and
+        # the identity is PR 24's
+        assert fb.buffer_wait_ms == 0.0
         assert fb.host_ms + fb.place_ms == pytest.approx(fb.convert_ms,
                                                          abs=1e-6)
         assert fb.place_ms > 0 and fb.host_ms > 0 and fb.read_ms >= 0
@@ -164,6 +173,42 @@ def test_host_plus_place_is_convert_and_the_mesh_places_once_more(recorded):
         assert len(fb.feed["x"].sharding.device_set) == 2
     # shard_batch's hand-over is one feed_place more for each leaf it moves
     assert _places_per_convert(recorded) >= one_device + 1
+
+
+def test_host_plus_place_plus_buffer_wait_is_convert(recorded):
+    """The identity with its new term: `feed_convert`'s children are its
+    placements and its waits for a recycled buffer, `place_ms` is the
+    placements only, and what is left is host assembly."""
+    trainer = _trainer()
+    registry = observe_metrics.MetricsRegistry()
+    steps = RING + 3
+    taken = list(DeviceFeeder(_batches(steps), trainer.topology,
+                              metrics_registry=registry).batches())
+    assert len(taken) == steps
+    events = recorded.events()
+    waits = [e for e in events if e[0] == "feed_buffer_wait"]
+    places = [e for e in events if e[0] == "feed_place"]
+    converts = [e for e in events if e[0] == "feed_convert"]
+    # two columns a batch, each batch after the ring has filled
+    assert len(waits) == 2 * 3 and len(places) == 2 * steps
+    assert all(e[6] == "feed_convert" for e in waits)
+    producer = {e[3] for e in converts}
+    assert len(producer) == 1 and {e[3] for e in waits + places} == producer
+    wait_ms = sum(e[2] for e in waits) * 1e3
+    place_ms = sum(e[2] for e in places) * 1e3
+    for i, fb in enumerate(taken):
+        assert (fb.buffer_wait_ms > 0) == (i >= RING)
+        assert fb.place_ms > 0 and fb.host_ms > 0
+        assert fb.host_ms + fb.place_ms + fb.buffer_wait_ms == pytest.approx(
+            fb.convert_ms, abs=1e-9)
+    assert sum(fb.buffer_wait_ms for fb in taken) == pytest.approx(wait_ms)
+    assert sum(fb.place_ms for fb in taken) == pytest.approx(place_ms)
+    hists = registry.snapshot()["histograms"]
+    assert hists[BUFFER_WAIT]["count"] == 3  # once a batch that recycled
+    assert hists[BUFFER_WAIT]["sum"] == pytest.approx(wait_ms)
+    assert hists["paddle_tpu_data_feed_place_ms"]["sum"] == \
+        pytest.approx(place_ms)
+    assert hists["paddle_tpu_data_feed_host_ms"]["count"] == steps
 
 
 def test_the_resume_cursor_keeps_the_batch_numbers(recorded):
@@ -210,8 +255,8 @@ def test_a_slow_handler_names_the_slowest_step(loop, monkeypatch):
                                            "handler"))
     assert 0.75 * worst["latency_ms"] <= own <= worst["latency_ms"] + 1e-3
     if loop == "pipelined":
-        assert {"read", "host", "place", "backpressure"} <= set(
-            worst["phases"])
+        assert {"read", "host", "place", "buffer_wait",
+                "backpressure"} <= set(worst["phases"])
 
     # the operator's reader: the per-pass dump under PADDLE_TPU_STATS=1
     monkeypatch.setenv("PADDLE_TPU_STATS", "1")
