@@ -88,6 +88,12 @@ def to_compute(x):
     return x
 
 
+def wide(dtype):
+    """The dtype sums and decays are kept in beside operands of ``dtype``:
+    float32, or wider where the operands are."""
+    return jnp.promote_types(dtype, jnp.float32)
+
+
 def upcast_f32(x):
     """Locally lift low-precision values to float32 (cost layers, BN stats)."""
     if hasattr(x, "dtype") and x.dtype in (jnp.bfloat16, jnp.float16):

@@ -32,7 +32,11 @@ _FEEDER_NAMES = ("DeviceFeeder", "FeedBatch", "feeder")
 
 def __getattr__(name):
     if name in _FEEDER_NAMES:
-        from paddle_tpu.data import feeder
+        import importlib
+
+        # not `from paddle_tpu.data import feeder`: that asks this
+        # function for the attribute first, and never returns
+        feeder = importlib.import_module("paddle_tpu.data.feeder")
 
         globals()["feeder"] = feeder
         globals()["DeviceFeeder"] = feeder.DeviceFeeder

@@ -81,15 +81,18 @@ class FeedBatch:
     ``backpressure_ms`` (the producer blocked on a full
     queue, written once the batch is in the queue), ``stall_ms`` (time
     the consumer blocked waiting for it), and for sequence feeds
-    ``bucket`` (padded length), ``fill_tokens``/``pad_tokens``."""
+    ``bucket`` (padded length), ``fill_tokens``/``pad_tokens`` (summed over
+    the sequence slots) and the step's ``tokens``/``positions`` (of its
+    widest sequence slot: valid tokens, rows x padded length)."""
 
     __slots__ = ("feed", "seq", "examples", "read_ms", "convert_ms",
                  "place_ms", "buffer_wait_ms", "backpressure_ms", "stall_ms",
-                 "bucket", "fill_tokens", "pad_tokens")
+                 "bucket", "fill_tokens", "pad_tokens", "tokens", "positions")
 
     def __init__(self, feed, examples, convert_ms, bucket=None,
                  fill_tokens=None, pad_tokens=None, seq=None, read_ms=0.0,
-                 place_ms=0.0, buffer_wait_ms=0.0):
+                 place_ms=0.0, buffer_wait_ms=0.0, tokens=None,
+                 positions=None):
         self.feed = feed
         self.seq = seq
         self.examples = examples
@@ -102,6 +105,8 @@ class FeedBatch:
         self.bucket = bucket
         self.fill_tokens = fill_tokens
         self.pad_tokens = pad_tokens
+        self.tokens = tokens  # of the step: its widest sequence slot's
+        self.positions = positions
 
     @property
     def host_ms(self):
@@ -146,20 +151,32 @@ def _feed_shape_key(feed):
 
 
 def _seq_stats(feed):
-    """(padded_len, fill_tokens, pad_tokens) over the sequence slots of a
-    converted feed (None when the feed has no sequence slots)."""
+    """(padded_len, fill_tokens, pad_tokens, step_tokens, step_positions)
+    of a converted feed, all None when it has no sequence slot. Fill and
+    pad are summed over the sequence slots; a step's tokens and positions
+    are those of its widest slot (a language model's tokens and targets
+    are one sequence, counted once)."""
     from paddle_tpu.core.sequence import SequenceBatch
 
-    bucket = fill = slots = 0
+    bucket = fill = slots = tokens = positions = 0
     for value in feed.values():
         if isinstance(value, SequenceBatch):
             lens = np.asarray(value.lengths)
+            held = int(lens.shape[0]) * int(value.max_len)
             bucket = max(bucket, int(value.max_len))
             fill += int(lens.sum())
-            slots += int(lens.shape[0]) * int(value.max_len)
+            slots += held
+            if held > positions:
+                tokens, positions = int(lens.sum()), held
     if slots == 0:
-        return None, None, None
-    return bucket, fill, slots - fill
+        return None, None, None, None, None
+    return bucket, fill, slots - fill, tokens, positions
+
+
+def step_tokens(feed):
+    """(valid tokens, batch x padded length) of a step over ``feed``, or
+    (None, None) for a feed without sequence slots."""
+    return _seq_stats(feed)[3:]
 
 
 class _Slot:
@@ -399,7 +416,7 @@ class DeviceFeeder:
                 with span("feed_convert", args={"batch": seq}) as convert:
                     feed = self._convert_batch(data_batch)
                 waited = self._buffers.sent(feed)  # ms, None: recycled none
-                bucket, fill, pad = _seq_stats(feed)
+                bucket, fill, pad, tokens, positions = _seq_stats(feed)
                 # feed_convert's children are its placements and its
                 # waits for a recycled buffer: place_ms is the former only
                 wait_ms = waited or 0.0
@@ -408,7 +425,8 @@ class DeviceFeeder:
                                pad_tokens=pad, seq=seq,
                                read_ms=read.dur * 1e3,
                                place_ms=convert.child_dur * 1e3 - wait_ms,
-                               buffer_wait_ms=wait_ms)
+                               buffer_wait_ms=wait_ms, tokens=tokens,
+                               positions=positions)
                 self._m_read.observe(fb.read_ms)
                 self._m_host.observe(fb.host_ms)
                 self._m_place.observe(fb.place_ms)

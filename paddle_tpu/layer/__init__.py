@@ -14,6 +14,8 @@ paddle_tpu.topology.Topology. Families:
   cost.py      classification_cost, cross_entropy, square_error, rank, ...
   mixed.py     mixed + projections/operators
   extra.py     nce, hsigmoid, crf, crf_decoding, ctc, warp_ctc, detection
+  decoder.py   rms_norm, gated_mlp, mamba2, gqa_attention, lm_head,
+               recompute (their token-level cost, lm_cost, is in cost.py)
 """
 
 from paddle_tpu.graph import LayerNode, LayerOutput, reset_name_counters
@@ -76,6 +78,7 @@ from paddle_tpu.layer.cost import (
     huber_classification_cost,
     huber_regression_cost,
     lambda_cost,
+    lm_cost,
     mse_cost,
     multi_binary_label_cross_entropy,
     rank_cost,
@@ -129,6 +132,14 @@ from paddle_tpu.layer.misc import (
     prelu,
     selective_fc,
     tensor,
+)
+from paddle_tpu.layer.decoder import (
+    gated_mlp,
+    gqa_attention,
+    lm_head,
+    mamba2,
+    recompute,
+    rms_norm,
 )
 from paddle_tpu.layer.step import gru_step, gru_step_naive, lstm_step
 from paddle_tpu.layer.detection import (

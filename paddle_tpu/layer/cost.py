@@ -12,6 +12,7 @@ mean (and so does jax.grad), matching the reference's sum-over-batch /
 batch-size normalization (TrainerInternal cost accounting).
 """
 
+import jax
 import jax.numpy as jnp
 
 from paddle_tpu.activation import Softmax
@@ -250,6 +251,30 @@ layer_registry.register("soft_binary_class_cross_entropy",
                         multi_binary_label_cross_entropy)
 
 
+@register_layer("lm_cost")
+def lm_cost(input, label, name=None, layer_attr=None):
+    """Token cross entropy of logits [B, T, V] against targets [B, T],
+    from float32 logits (``layer.lm_head`` gives them so under any compute
+    dtype): the mean over the batch's valid positions. Gives a per-row
+    vector [B] whose mean is that number (row i holds its tokens' summed
+    cost times B over the batch's valid tokens), as the trainer takes the
+    mean of every cost."""
+
+    def forward(params, values, ctx):
+        logits, y = values
+        enforce(is_seq(y), "lm_cost needs sequence targets")
+        with jax.named_scope("paddle_tpu.lm_cost"):
+            x = upcast_f32(data_of(logits))
+            picked = jnp.take_along_axis(
+                x, data_of(y)[..., None].astype(jnp.int32), axis=-1)[..., 0]
+            mask = y.mask(x.dtype)
+            rows = jnp.sum((jax_logsumexp(x)[..., 0] - picked) * mask, axis=1)
+            return rows * (rows.shape[0] / jnp.maximum(jnp.sum(mask), 1.0))
+
+    return make_node("lm_cost", forward, [input, label], name=name, size=1,
+                     layer_attr=layer_attr)
+
+
 # Layer types whose non-first inputs are supervision targets (labels,
 # scores, weights) — the mixed-precision policy must NOT quantize those
 # feeds to bfloat16 (topology._run_nodes keeps them float32 so the f32
@@ -259,5 +284,5 @@ COST_LAYER_TYPES = frozenset({
     "multi_binary_label_cross_entropy", "cross_entropy_with_selfnorm",
     "rank_cost", "lambda_cost", "huber_regression_cost",
     "huber_classification_cost", "smooth_l1_cost", "sum_cost",
-    "crf", "crf_decoding", "ctc", "warp_ctc",
+    "crf", "crf_decoding", "ctc", "warp_ctc", "lm_cost",
 })

@@ -1,0 +1,302 @@
+"""Layers of today's decoder-only language models: RMSNorm (plain and
+gated), the gated MLP, the Mamba-2 mixer, grouped-query causal attention
+without positions, the head that is the embedding's transpose, and a
+block that is recomputed in backward (the token-level cost over the head's
+logits is ``layer/cost.py lm_cost``).
+
+All take and give ``SequenceBatch`` values [B, T, width]. The two layers
+that mix across time (``mamba2``, ``gqa_attention``) refuse packed rows:
+their state, convolution taps and attention do not yet reset at segment
+starts. Every piece of device work runs under a ``jax.named_scope`` of its
+own (docs/observability.md "Decoder scopes").
+"""
+
+import math
+
+import jax
+import jax.numpy as jnp
+
+from paddle_tpu.core.dtype import upcast_f32
+from paddle_tpu.graph import auto_name
+from paddle_tpu.initializer import Constant, Uniform
+from paddle_tpu.layer.base import (data_of, featurewise, is_seq, like,
+                                   make_node, register_layer, reject_packed,
+                                   to_list, weight_spec)
+from paddle_tpu.ops import attention as attention_ops
+from paddle_tpu.ops import ssm as ssm_ops
+from paddle_tpu.utils.error import enforce
+
+
+def _rms_normalize(x, weight, eps, gate=None):
+    """x * rsqrt(mean(x^2) + eps) * weight over the last axis, in float32
+    at least; with ``gate``, of x * silu(gate)."""
+    with jax.named_scope("paddle_tpu.rmsnorm"):
+        wide = upcast_f32(x)
+        if gate is not None:
+            wide = wide * jax.nn.silu(upcast_f32(gate))
+        scale = jax.lax.rsqrt(jnp.mean(wide * wide, axis=-1, keepdims=True)
+                              + eps)
+        return (wide * scale * upcast_f32(weight)).astype(x.dtype)
+
+
+def _ones_spec(name, shape, param_attr):
+    spec = weight_spec(name, 0, shape, param_attr)
+    if spec.attr.initializer is None and spec.attr.initial_std is None:
+        spec.initializer = Constant(1.0)
+    return spec
+
+
+@register_layer("rms_norm")
+def rms_norm(input, gate=None, eps=1e-5, name=None, param_attr=None,
+             layer_attr=None):
+    """RMSNorm over the feature axis with a learned scale (ones at the
+    start); with ``gate`` the gated form, RMSNorm(x * silu(gate))."""
+    name = name or auto_name("rms_norm")
+    spec = _ones_spec(name, (input.size,), param_attr)
+
+    def forward(params, values, ctx):
+        g = data_of(values[1]) if gate is not None else None
+        return featurewise(
+            lambda d: _rms_normalize(d, params[spec.name], eps, g),
+            values[0])
+
+    return make_node("rms_norm", forward, [input] + to_list(gate), name=name,
+                     size=input.size, param_specs=[spec],
+                     layer_attr=layer_attr)
+
+
+def _gated_mlp(x, w_in, w_out):
+    with jax.named_scope("paddle_tpu.gated_mlp"):
+        a, b = jnp.split(jnp.matmul(x, w_in), 2, axis=-1)
+        return jnp.matmul(jax.nn.silu(a) * b, w_out)
+
+
+@register_layer("gated_mlp")
+def gated_mlp(input, size, name=None, param_attr=None, layer_attr=None):
+    """(silu(a) * b) W_out with [a, b] = x W_in: widths d -> 2 * size -> d,
+    no bias."""
+    name = name or auto_name("gated_mlp")
+    attrs = param_attr if isinstance(param_attr, (list, tuple)) \
+        else [param_attr] * 2
+    w_in = weight_spec(name, 0, (input.size, 2 * size), attrs[0])
+    w_out = weight_spec(name, 1, (size, input.size), attrs[1])
+
+    def forward(params, values, ctx):
+        return featurewise(
+            lambda d: _gated_mlp(d, params[w_in.name], params[w_out.name]),
+            values[0])
+
+    return make_node("gated_mlp", forward, [input], name=name,
+                     size=input.size, param_specs=[w_in, w_out],
+                     layer_attr=layer_attr)
+
+
+def _named_spec(name, suffix, shape, initializer=None, std=None):
+    from paddle_tpu.attr import ParamAttr
+
+    attr = ParamAttr(name="%s.%s" % (name, suffix), initializer=initializer,
+                     initial_std=std)
+    return weight_spec(name, 0, shape, attr)
+
+
+class _InverseSoftplusOfLogUniform:
+    """dt_bias such that softplus(dt_bias) is log-uniform in [low, high]
+    (the Mamba-2 initialisation)."""
+
+    def __init__(self, low=1e-3, high=1e-1):
+        self.low, self.high = low, high
+
+    def __call__(self, rng, shape, dtype):
+        u = jax.random.uniform(rng, shape, dtype)
+        dt = jnp.exp(u * (math.log(self.high) - math.log(self.low))
+                     + math.log(self.low))
+        return dt + jnp.log(-jnp.expm1(-dt))
+
+
+class _LogArange:
+    """A_log = log(1..H)."""
+
+    def __call__(self, rng, shape, dtype):
+        return jnp.log(jnp.arange(1, shape[0] + 1, dtype=dtype))
+
+
+@register_layer("mamba2")
+def mamba2(input, heads, head_dim, state, conv_width=4, groups=1, chunk=256,
+           eps=1e-5, initial_std=0.02, name=None, layer_attr=None):
+    """The Mamba-2 mixer (Dao & Gu 2024):
+        [z, xBC, dt] = u W_in
+        xBC = silu(conv1d_causal(xBC))             depthwise, with bias
+        [x, B, C] = xBC                            heads * head_dim, 2 * groups * state
+        dt = softplus(dt + dt_bias);  A = -exp(A_log)
+        S_t = exp(dt_t A) S_{t-1} + dt_t x_t (x) B_t;  y_t = S_t C_t + D x_t
+        out = RMSNorm(y * silu(z)) W_out
+    the scan in chunks of ``chunk`` (``ops/ssm.py``). Parameters
+    ``<name>.in_proj``, ``.conv_w`` [C, K], ``.conv_b``, ``.A_log``, ``.D``,
+    ``.dt_bias``, ``.norm_w``, ``.out_proj``; no bias on the projections."""
+    name = name or auto_name("mamba2")
+    d = input.size
+    inner = heads * head_dim
+    conv_dim = inner + 2 * groups * state
+    specs = {
+        "in_proj": _named_spec(name, "in_proj",
+                               (d, inner + conv_dim + heads), std=initial_std),
+        # as torch.nn.Conv1d starts a depthwise filter and its bias
+        "conv_w": _named_spec(name, "conv_w", (conv_dim, conv_width),
+                              Uniform(-conv_width ** -0.5,
+                                      conv_width ** -0.5)),
+        "conv_b": _named_spec(name, "conv_b", (conv_dim,),
+                              Uniform(-conv_width ** -0.5,
+                                      conv_width ** -0.5)),
+        "A_log": _named_spec(name, "A_log", (heads,), _LogArange()),
+        "D": _named_spec(name, "D", (heads,), Constant(1.0)),
+        "dt_bias": _named_spec(name, "dt_bias", (heads,),
+                               _InverseSoftplusOfLogUniform()),
+        "norm_w": _named_spec(name, "norm_w", (inner,), Constant(1.0)),
+        "out_proj": _named_spec(name, "out_proj", (inner, d),
+                                std=initial_std),
+    }
+
+    def forward(params, values, ctx):
+        seq = values[0]
+        reject_packed(seq, "mamba2")
+        enforce(is_seq(seq), "mamba2 needs a sequence input")
+        p = {k: params[s.name] for k, s in specs.items()}
+        u = seq.data
+        b, t = u.shape[:2]
+        z, xbc, dt = jnp.split(jnp.matmul(u, p["in_proj"]),
+                               [inner, inner + conv_dim], axis=-1)
+        xbc = jax.nn.silu(ssm_ops.causal_conv1d(
+            xbc, p["conv_w"], p["conv_b"], seq.lengths))
+        x, b_mat, c_mat = jnp.split(
+            xbc, [inner, inner + groups * state], axis=-1)
+        dt = jax.nn.softplus(upcast_f32(dt) + upcast_f32(p["dt_bias"]))
+        y = ssm_ops.ssd_scan(
+            x.reshape(b, t, heads, head_dim), dt,
+            -jnp.exp(upcast_f32(p["A_log"])),
+            b_mat.reshape(b, t, groups, state),
+            c_mat.reshape(b, t, groups, state), p["D"], chunk, seq.lengths)
+        y = _rms_normalize(y.reshape(b, t, inner), p["norm_w"], eps, gate=z)
+        return like(seq, jnp.matmul(y, p["out_proj"]))
+
+    return make_node("mamba2", forward, [input], name=name, size=d,
+                     param_specs=list(specs.values()), layer_attr=layer_attr)
+
+
+@register_layer("gqa_attention")
+def gqa_attention(input, heads, kv_heads, head_dim, scale=None, block=512,
+                  initial_std=0.02, name=None, layer_attr=None):
+    """Causal self-attention with ``heads`` query heads over ``kv_heads``
+    shared key-value heads, no positional encoding and no bias; scores are
+    multiplied by ``scale`` (1 / sqrt(head_dim) by default). Blockwise
+    (``ops/attention.py``): no [T, T] score matrix is held. Parameters
+    ``<name>.q``, ``.k``, ``.v``, ``.o``."""
+    name = name or auto_name("gqa_attention")
+    d = input.size
+    enforce(heads % kv_heads == 0, "kv_heads %d must divide heads %d",
+            kv_heads, heads)
+    scale = scale if scale is not None else head_dim ** -0.5
+    specs = {
+        "q": _named_spec(name, "q", (d, heads * head_dim), std=initial_std),
+        "k": _named_spec(name, "k", (d, kv_heads * head_dim),
+                         std=initial_std),
+        "v": _named_spec(name, "v", (d, kv_heads * head_dim),
+                         std=initial_std),
+        "o": _named_spec(name, "o", (heads * head_dim, d), std=initial_std),
+    }
+
+    def forward(params, values, ctx):
+        seq = values[0]
+        reject_packed(seq, "gqa_attention")
+        enforce(is_seq(seq), "gqa_attention needs a sequence input")
+        u = seq.data
+        b, t = u.shape[:2]
+        with jax.named_scope("paddle_tpu.gqa_attention"):
+            q, k, v = (jnp.matmul(u, params[specs[n].name]).reshape(
+                b, t, h, head_dim)
+                for n, h in (("q", heads), ("k", kv_heads), ("v", kv_heads)))
+            y = attention_ops.blockwise_attention(
+                q, k, v, scale, True, seq.lengths, block)
+            return like(seq, jnp.matmul(y.reshape(b, t, heads * head_dim),
+                                        params[specs["o"].name]))
+
+    return make_node("gqa_attention", forward, [input], name=name, size=d,
+                     param_specs=list(specs.values()), layer_attr=layer_attr)
+
+
+@register_layer("lm_head")
+def lm_head(input, vocab, param_attr, scale=1.0, name=None, layer_attr=None):
+    """Logits ``scale * h E^T`` over a [vocab, width] table, in float32
+    whatever the compute dtype. Name the table as the embedding's
+    (``param_attr=ParamAttr(name=...)``) and the two are one parameter,
+    whose gradient is the sum of its two uses."""
+    name = name or auto_name("lm_head")
+    spec = weight_spec(name, 0, (vocab, input.size), param_attr)
+
+    def forward(params, values, ctx):
+        table = params[spec.name]
+
+        def logits(h):
+            return jnp.einsum("...d,vd->...v", h, table,
+                              preferred_element_type=upcast_f32(h).dtype
+                              ) * scale
+
+        return featurewise(logits, values[0])
+
+    return make_node("lm_head", forward, [input], name=name, size=vocab,
+                     param_specs=[spec], layer_attr=layer_attr)
+
+
+@register_layer("recompute")
+def recompute(output, inputs, enabled=True, name=None):
+    """The sub-graph from ``inputs`` to ``output`` as one node, whose
+    forward runs under ``jax.checkpoint``: backward keeps the block's
+    inputs and computes its inside again, memory for time. The node owns
+    the parameters of the layers inside; ``enabled=False`` runs the same
+    node without the checkpoint."""
+    inputs = to_list(inputs)
+    boundary = {id(n) for n in inputs}
+    inside = _between(output, boundary)
+    specs = {}
+    for node in inside:
+        enforce(node.layer_type != "data",
+                "recompute: data layer %r is inside the block; list it in "
+                "inputs", node.name)
+        for spec in node.param_specs:
+            enforce(not spec.is_state, "recompute: %r keeps running state "
+                    "(%s), which a recomputed block cannot", node.name,
+                    spec.name)
+            specs[spec.name] = spec
+
+    def forward(params, values, ctx):
+        def run(block_params, block_inputs):
+            seen = {id(n): v for n, v in zip(inputs, block_inputs)}
+            for node in inside:
+                seen[id(node)] = node.forward(
+                    block_params, [seen[id(p)] for p in node.inputs], ctx)
+            return seen[id(output)]
+
+        block_params = {k: params[k] for k in specs}
+        with jax.named_scope("paddle_tpu.block"):
+            if enabled:
+                run = jax.checkpoint(run)
+            return run(block_params, list(values))
+
+    return make_node("recompute", forward, inputs,
+                     name=name or auto_name("recompute"), size=output.size,
+                     param_specs=list(specs.values()))
+
+
+def _between(output, boundary):
+    """Nodes from the boundary (left out) to ``output``, inputs first."""
+    order, seen = [], set(boundary)
+
+    def visit(node):
+        if id(node) in seen:
+            return
+        seen.add(id(node))
+        for parent in node.inputs:
+            visit(parent)
+        order.append(node)
+
+    visit(output)
+    return order

@@ -32,29 +32,23 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax import shard_map
 
+from paddle_tpu.ops import attention as attention_ops
 from paddle_tpu.utils.error import enforce
-
-_NEG = -1e30  # finite mask value: keeps exp() and grads NaN-free
-
 
 def full_attention(q, k, v, causal=False, scale=None, lengths=None):
     """Reference (unsharded) scaled-dot-product attention.
 
     q, k, v: [B, L, H, D]; returns [B, L, H, D]. ``lengths`` ([B] int32)
-    masks out padded key positions.
+    masks out padded key positions. One step of the online softmax that
+    the ring takes a hop at a time (``ops/attention.py``).
     """
     b, lq, h, d = q.shape
     scale = scale if scale is not None else 1.0 / (d ** 0.5)
-    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
-    if causal:
-        qp = jnp.arange(lq)
-        kp = jnp.arange(k.shape[1])
-        s = jnp.where((qp[:, None] >= kp[None, :])[None, None], s, _NEG)
-    if lengths is not None:
-        kmask = jnp.arange(k.shape[1])[None, :] < lengths[:, None]
-        s = jnp.where(kmask[:, None, None, :], s, _NEG)
-    p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("bhqk,bkhd->bqhd", p, v)
+    mask = attention_ops.attention_mask(
+        jnp.arange(lq), jnp.arange(k.shape[1]), causal, lengths)
+    carry = attention_ops.online_softmax_init(b, lq, h, d, q.dtype)
+    carry = attention_ops.online_softmax_step(carry, q, k, v, scale, mask)
+    return attention_ops.online_softmax_finish(carry, q.dtype)
 
 
 def _ring_shard(q, k, v, axis_name, axis_size, causal, scale):
@@ -67,33 +61,20 @@ def _ring_shard(q, k, v, axis_name, axis_size, causal, scale):
     b, lc, h, d = q.shape
     idx = jax.lax.axis_index(axis_name)
     q_pos = idx * lc + jnp.arange(lc)
-
-    m = jnp.full((b, h, lc), _NEG, q.dtype)          # running row max
-    l = jnp.zeros((b, h, lc), q.dtype)               # running normalizer
-    o = jnp.zeros((b, lc, h, d), q.dtype)            # unnormalized output
+    carry = attention_ops.online_softmax_init(b, lc, h, d, q.dtype)
     k_blk, v_blk = k, v
     fwd = [(j, (j + 1) % axis_size) for j in range(axis_size)]
 
     for step in range(axis_size):
         src = (idx - step) % axis_size               # owner of current block
-        k_pos = src * lc + jnp.arange(lc)
-        s = jnp.einsum("bqhd,bkhd->bhqk", q, k_blk) * scale
-        if causal:
-            mask = q_pos[:, None] >= k_pos[None, :]
-            s = jnp.where(mask[None, None], s, _NEG)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
-        alpha = jnp.exp(m - m_new)                   # rescale old accumulators
-        p = jnp.exp(s - m_new[..., None])
-        l = l * alpha + jnp.sum(p, axis=-1)
-        o = o * jnp.transpose(alpha, (0, 2, 1))[..., None] + \
-            jnp.einsum("bhqk,bkhd->bqhd", p, v_blk)
-        m = m_new
+        mask = attention_ops.attention_mask(
+            q_pos, src * lc + jnp.arange(lc), causal, None)
+        carry = attention_ops.online_softmax_step(carry, q, k_blk, v_blk,
+                                                  scale, mask)
         if step < axis_size - 1:
             k_blk = jax.lax.ppermute(k_blk, axis_name, fwd)
             v_blk = jax.lax.ppermute(v_blk, axis_name, fwd)
-
-    norm = jnp.transpose(jnp.maximum(l, 1e-30), (0, 2, 1))[..., None]
-    return o / norm
+    return attention_ops.online_softmax_finish(carry, q.dtype)
 
 
 def ring_attention(q, k, v, mesh, seq_axis="seq", causal=False, scale=None,

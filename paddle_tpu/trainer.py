@@ -26,6 +26,7 @@ import jax.numpy as jnp
 from paddle_tpu import event as v2_event
 from paddle_tpu.graph import LayerNode
 from paddle_tpu.parameters import Parameters
+from paddle_tpu.data.feeder import step_tokens
 from paddle_tpu.topology import Topology, convert_feed
 from paddle_tpu.utils import flags
 from paddle_tpu.utils.error import enforce
@@ -495,7 +496,22 @@ class SGD:
                  m.histogram("paddle_tpu_train_handler_ms",
                              help="step-thread time inside the event "
                                   "handler, per finalized step",
+                             labels=labels)),
+                (m.histogram("paddle_tpu_train_step_tokens",
+                             help="valid tokens of a dispatched step's "
+                                  "widest sequence slot", labels=labels),
+                 m.histogram("paddle_tpu_train_step_positions",
+                             help="rows x padded length of a dispatched "
+                                  "step's widest sequence slot",
                              labels=labels)))
+
+    @staticmethod
+    def _observe_tokens(m_tokens, tokens, positions):
+        """One observation a dispatched step whose feed has a sequence
+        slot: what the step computes over, and what of it is not padding."""
+        if positions:
+            m_tokens[0].observe(tokens)
+            m_tokens[1].observe(positions)
 
     def _close_step(self, last_final, m_phases, step, batches=()):
         """A step's (a fused chunk's) wall interval ends here, its loss
@@ -529,7 +545,7 @@ class SGD:
                       slog, last_final, sentinel=None, feed_pipeline=False,
                       start_pass=0, start_cursor=0, ckpt=None):
         (m_steps, m_examples, m_loss, m_examples_per_sec,
-         m_phases) = self._train_metrics()
+         m_phases, m_tokens) = self._train_metrics()
         # per-worker windowed health (observe/trainview.py): the fleet
         # view's live counterpart to the steplog, O(1) memory
         thist = observe_trainview.get_train_history()
@@ -652,6 +668,7 @@ class SGD:
                             self._trainable, self._replica, self._static,
                             self._state, self._opt_state, feed, step_rng)
                     phases["dispatch"] += step.dur * 1e3
+                    self._observe_tokens(m_tokens, *step_tokens(feed))
                     self._step_count += 1
                     self._checkpoint_maybe(ckpt, pass_id, batch_id + 1)
                     if pending is not None:
@@ -680,6 +697,7 @@ class SGD:
                             self._trainable, self._replica, self._static,
                             self._state, self._opt_state, fb.feed, step_rng)
                     phases["dispatch"] += step.dur * 1e3
+                    self._observe_tokens(m_tokens, fb.tokens, fb.positions)
                     self._step_count += 1
                     self._checkpoint_maybe(ckpt, pass_id, batch_id + 1)
                     if slog is not None:
@@ -760,7 +778,7 @@ class SGD:
         from paddle_tpu.data.feeder import DeviceFeeder
 
         (m_steps, m_examples, m_loss, m_examples_per_sec,
-         m_phases) = self._train_metrics()
+         m_phases, m_tokens) = self._train_metrics()
         # per-worker windowed health, chunk-amortized (trainview.py)
         thist = observe_trainview.get_train_history()
         phases = last_final["phases"]
@@ -908,6 +926,8 @@ class SGD:
                             self._state, self._opt_state, chunk.feed,
                             step_rng)
                 phases["dispatch"] += step.dur * 1e3
+                for fb in chunk.batches:
+                    self._observe_tokens(m_tokens, fb.tokens, fb.positions)
                 base_step = self._step_count
                 self._step_count += chunk.steps
                 # chunk boundary == step boundary: the first one at or

@@ -1,0 +1,259 @@
+"""`models/hybrid_lm.py` at a tiny size with both layer kinds: against the
+plain reference in loss and every gradient leaf, with and without block
+recompute, the tied embedding, the vocabulary slice, the padded tail, and
+through `SGD.train` with its two always-on histograms."""
+
+import json
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import paddle_tpu as paddle
+from chipbench import traffic
+from chipbench.models import granite_h_micro as bench_model
+from chipbench.reference import granite_h_micro as ref
+from paddle_tpu import layer as L
+from paddle_tpu.core.sequence import SequenceBatch
+from paddle_tpu.data import feeder as data_feeder
+from paddle_tpu.models import hybrid_lm
+from paddle_tpu.observe import metrics as observe_metrics
+from paddle_tpu.topology import Topology, convert_feed
+from paddle_tpu.utils.error import EnforceError
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+TINY = os.path.join(ROOT, "tests", "chipbench", "tiny")
+CELL = "granite-4.0-h-micro-seq4096-bs2-train"
+
+
+def _load(kind, name):
+    with open(os.path.join(TINY, kind, name + ".json")) as f:
+        return json.load(f)
+
+
+@pytest.fixture(autouse=True)
+def _float32_at_highest():
+    L.reset_name_counters()
+    with jax.default_matmul_precision("highest"):
+        yield
+
+
+@pytest.fixture()
+def cfg():
+    return _load("configs", "granite-4.0-h-micro")
+
+
+def _program(cfg, seed=3, recompute=True):
+    """(cost node, topology, parameters from the reference's weights,
+    reference weights, {reference name: program name})."""
+    L.reset_name_counters()
+    cost = hybrid_lm.from_config(cfg, recompute=recompute)[3]
+    names = bench_model.program_names(cfg)
+    weights, _ = ref.init_weights(seed, cfg)
+    topo = Topology(cost)
+    return cost, topo, {names[k]: v for k, v in weights.items()}, weights, \
+        names
+
+
+def _batch(cfg, seed=3):
+    return traffic.make_pool(cfg["inputs"], _load("workloads", CELL),
+                             seed)[0]
+
+
+def _loss_and_grads(topo, cost, params, feed):
+    return jax.value_and_grad(lambda p: jnp.mean(
+        topo.apply(p, feed, mode="train")[0][cost.name]))(params)
+
+
+def test_the_tiny_preset_keeps_both_layer_kinds(cfg):
+    kinds = cfg["layer_types"][:cfg["num_hidden_layers"]]
+    assert {"mamba", "attention"} == set(kinds)
+    cost, topo, params, weights, names = _program(cfg)
+    specs = topo.param_specs()
+    assert set(specs) == set(names.values())
+    for k, v in weights.items():
+        assert specs[names[k]].shape == v.shape, k
+    blocks = [n for n in topo.nodes if n.layer_type == "recompute"]
+    assert len(blocks) == len(kinds)
+
+
+def test_loss_and_every_gradient_leaf_agree_with_the_reference(cfg):
+    cost, topo, params, weights, names = _program(cfg)
+    batch = _batch(cfg)
+    lengths = sorted(len(row[0]) for row in batch)
+    assert lengths[0] < lengths[1] and lengths[1] % cfg["mamba_chunk_size"]
+    loss, grads = _loss_and_grads(topo, cost, params,
+                                  convert_feed(topo, batch))
+    arrays = tuple(jnp.asarray(a) for a in ref.batch_arrays(batch, cfg))
+    want, want_grads = jax.value_and_grad(
+        lambda w: ref.loss(w, {}, arrays, cfg)[0])(weights)
+    assert float(loss) == pytest.approx(float(want), rel=1e-5)
+    for k, g in want_grads.items():
+        gap = np.linalg.norm(np.asarray(grads[names[k]]) - np.asarray(g)) \
+            / np.linalg.norm(np.asarray(g))
+        assert gap < 1e-4, (k, gap)
+
+
+def test_gradients_are_equal_with_and_without_block_recompute(cfg):
+    batch = _batch(cfg)
+    out = []
+    for recompute in (True, False):
+        cost, topo, params, _, _ = _program(cfg, recompute=recompute)
+        out.append(_loss_and_grads(topo, cost, params,
+                                   convert_feed(topo, batch)))
+    (loss_a, grads_a), (loss_b, grads_b) = out
+    assert float(loss_a) == pytest.approx(float(loss_b), rel=1e-6)
+    assert set(grads_a) == set(grads_b)
+    for k in grads_a:
+        np.testing.assert_allclose(
+            grads_a[k], grads_b[k], rtol=1e-4,
+            atol=1e-5 * float(jnp.abs(grads_b[k]).max()))
+
+
+def test_the_tied_embedding_is_one_parameter_with_both_uses_gradients(cfg):
+    cost, topo, params, _, _ = _program(cfg)
+    feed = convert_feed(topo, _batch(cfg))
+    tables = [s for s in topo.param_specs() if s.endswith(".emb")]
+    assert tables == ["lm.emb"]
+    _, tied = _loss_and_grads(topo, cost, params, feed)
+
+    # the same model with a head of its own: two tables of equal values
+    from paddle_tpu.attr import ParamAttr
+
+    L.reset_name_counters()
+    tokens, targets, logits, _ = hybrid_lm.from_config(cfg)
+    head = L.lm_head(input=logits.inputs[0], vocab=cfg["vocab_size"],
+                     param_attr=ParamAttr(name="lm.own_head"),
+                     scale=1.0 / cfg["logits_scaling"])
+    untied_cost = L.lm_cost(input=head, label=targets)
+    untied = Topology(untied_cost)
+    both = {**params, "lm.own_head": params["lm.emb"]}
+    _, split = _loss_and_grads(untied, untied_cost, both, feed)
+    np.testing.assert_allclose(tied["lm.emb"],
+                               split["lm.emb"] + split["lm.own_head"],
+                               rtol=1e-4, atol=1e-7)
+    assert float(jnp.abs(split["lm.emb"]).max()) > 0
+    assert float(jnp.abs(split["lm.own_head"]).max()) > 0
+
+
+def test_logits_over_a_vocabulary_slice_are_the_whole_ones_columns(cfg):
+    """A sliced vocabulary is the first rows of the table: what the chip's
+    share computes is the whole model's logits at those columns."""
+    whole, _ = ref.init_weights(5, cfg)
+    kept = cfg["vocab_size"] // 2
+    sliced_cfg = dict(cfg, vocab_size=kept)
+    batch = [(row[0] % kept, row[1] % kept) for row in _batch(cfg)]
+    L.reset_name_counters()
+    logits = hybrid_lm.from_config(sliced_cfg)[2]
+    names = bench_model.program_names(sliced_cfg)
+    topo = Topology(logits)
+    params = {names[k]: (v[:kept] if k == "emb" else v)
+              for k, v in whole.items()}
+    got = topo.apply(params, convert_feed(topo, batch),
+                     mode="test")[0][logits.name]
+    tokens, _, lengths = ref.batch_arrays(batch, cfg)
+    want = ref.logits_of(whole, jnp.asarray(tokens), cfg)
+    valid = (np.arange(tokens.shape[1])[None, :] < lengths[:, None])[..., None]
+    np.testing.assert_allclose(
+        np.where(valid, got.data[:, :tokens.shape[1]], 0),
+        np.where(valid, want[..., :kept], 0), atol=2e-5)
+
+
+def test_nothing_leaks_out_of_the_padded_tail(cfg):
+    cost, topo, params, _, _ = _program(cfg)
+    feed = convert_feed(topo, _batch(cfg))
+    loss, grads = _loss_and_grads(topo, cost, params, feed)
+    short = int(jnp.argmin(feed["tokens"].lengths))
+    tail = int(feed["tokens"].lengths[short])
+    assert tail < feed["tokens"].data.shape[1]
+    moved = {k: SequenceBatch(v.data.at[short, tail:].set(7), v.lengths)
+             for k, v in feed.items()}
+    loss2, grads2 = _loss_and_grads(topo, cost, params, moved)
+    assert float(loss2) == float(loss)
+    for k in grads:
+        np.testing.assert_array_equal(grads2[k], grads[k])
+
+
+def test_nothing_leaks_back_in_time_through_the_whole_model(cfg):
+    L.reset_name_counters()
+    logits = hybrid_lm.from_config(cfg)[2]
+    names = bench_model.program_names(cfg)
+    topo = Topology(logits)
+    params = {names[k]: v for k, v in ref.init_weights(6, cfg)[0].items()}
+    feed = convert_feed(topo, _batch(cfg))
+    at = 17
+    out = topo.apply(params, feed, mode="test")[0][logits.name].data
+    tokens = feed["tokens"]
+    moved = {**feed, "tokens": SequenceBatch(
+        tokens.data.at[:, at].set((tokens.data[:, at] + 1)
+                                  % cfg["vocab_size"]), tokens.lengths)}
+    out2 = topo.apply(params, moved, mode="test")[0][logits.name].data
+    np.testing.assert_array_equal(out2[:, :at], out[:, :at])
+    assert float(jnp.abs(out2[:, at:] - out[:, at:]).max()) > 1e-6
+
+
+def test_experts_are_refused():
+    with pytest.raises(EnforceError, match="expert"):
+        hybrid_lm.from_config({"num_local_experts": 8})
+
+
+def _histograms():
+    return observe_metrics.get_registry().snapshot()["histograms"]
+
+
+def _count(hist, name):
+    return hist.get(name, {"count": 0, "sum": 0.0})
+
+
+@pytest.mark.parametrize("feed_pipeline", [True, False])
+def test_it_trains_through_sgd_train_and_counts_its_tokens(cfg,
+                                                           feed_pipeline):
+    paddle.init(use_tpu=False, seed=7)
+    L.reset_name_counters()
+    cost = hybrid_lm.from_config(cfg)[3]
+    params = paddle.parameters.create(cost)
+    before = {k: np.array(params.get(k)) for k in params.names()}
+    trainer = paddle.trainer.SGD(
+        cost, params, paddle.optimizer.Momentum(learning_rate=0.01,
+                                                momentum=0.9))
+    pool = traffic.make_pool(cfg["inputs"], _load("workloads", CELL), 7)
+    costs = []
+
+    def handler(event):
+        if isinstance(event, paddle.event.EndIteration):
+            costs.append(event.cost)
+
+    names = ("paddle_tpu_train_step_tokens",
+             "paddle_tpu_train_step_positions")
+    start = [_count(_histograms(), n) for n in names]
+    trainer.train(lambda: iter(pool), event_handler=handler,
+                  feed_pipeline=feed_pipeline)
+    end = [_count(_histograms(), n) for n in names]
+    assert len(costs) == 3 and all(np.isfinite(costs))
+    # a uniform guess over the vocabulary costs log(vocab)
+    assert costs[0] == pytest.approx(np.log(cfg["vocab_size"]), rel=0.05)
+    moved = [k for k in before
+             if not np.array_equal(before[k], np.asarray(params.get(k)))]
+    # every matrix moves; a scale whose update is under float32's step at
+    # 1.0 may stand still for three steps
+    assert {k for k in before if before[k].ndim == 2} <= set(moved)
+    assert len(moved) >= 0.8 * len(before)
+    rows = [len(r[0]) for r in pool[0]]
+    padded = convert_feed(trainer.topology, pool[0])["tokens"].data.shape[1]
+    tokens, positions = (e["sum"] - s["sum"] for s, e in zip(start, end))
+    assert [e["count"] - s["count"] for s, e in zip(start, end)] == [3, 3]
+    assert tokens == 3 * sum(rows)
+    assert positions == 3 * len(rows) * padded
+
+
+def test_a_steps_tokens_are_its_widest_sequence_slots():
+    lengths = jnp.asarray([5, 3], jnp.int32)
+    seq = SequenceBatch(jnp.zeros((2, 8), jnp.int32), lengths)
+    assert data_feeder.step_tokens({"tokens": seq, "targets": seq}) == (8, 16)
+    narrow = SequenceBatch(jnp.zeros((2, 4), jnp.int32),
+                           jnp.asarray([4, 1], jnp.int32))
+    assert data_feeder.step_tokens({"a": narrow, "b": seq}) == (8, 16)
+    assert data_feeder.step_tokens({"image": jnp.zeros((2, 3))}) == (None,
+                                                                     None)
