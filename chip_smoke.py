@@ -707,7 +707,7 @@ def phase_feed_readback(cfg, parallelism=None):
 
     from paddle_tpu import data_type as dt, layer as L
     from paddle_tpu.data.feeder import DeviceFeeder
-    from paddle_tpu.observe.metrics import MetricsRegistry
+    from paddle_tpu.observe.metrics import MetricsRegistry, get_registry
     from paddle_tpu.topology import Topology
 
     sizes = cfg["feed"]
@@ -727,9 +727,12 @@ def phase_feed_readback(cfg, parallelism=None):
                for images, labels in made]
     registry = MetricsRegistry()
     depth = 2
+    resharded = get_registry().counter("paddle_tpu_data_feed_resharded_total")
+    moved = resharded.value
     feeder = DeviceFeeder(lambda: iter(batches), topology, depth=depth,
                           parallelism=parallelism, metrics_registry=registry)
     taken = list(feeder.batches())
+    moved = resharded.value - moved
     require(len(taken) == n, "the feeder yielded %d of %d batches",
             len(taken), n)
     for i, (fb, (images, labels)) in enumerate(zip(taken, made)):
@@ -752,8 +755,18 @@ def phase_feed_readback(cfg, parallelism=None):
     require(counted == {"reused": 2 * (n - ring), "allocated": 2 * ring},
             "%d batches of two columns over a ring of %d: %r", n, ring,
             counted)
+    # over a mesh every column went from its host buffer straight to its
+    # shards, and none over one device (shard_batch counts in the
+    # process's registry)
+    straight = snap["counters"].get(
+        "paddle_tpu_data_feed_placed_sharded_total", 0)
+    require((straight, moved) == (2 * n if devices > 1 else 0, 0),
+            "%d batches of two columns over %d device(s): %d placed "
+            "straight onto the mesh, %d moved there from a device", n,
+            devices, straight, moved)
     waits = snap["histograms"]["paddle_tpu_data_feed_buffer_wait_ms"]
     return dict(counted, batches=n, rows=rows, devices=devices,
+                placed_sharded=straight, resharded=moved,
                 megabytes_a_batch=round(rows * sizes["dim"] * 4 / 1e6, 1),
                 buffer_wait_ms_mean=waits["sum"] / waits["count"],
                 host_ms_mean=sum(fb.host_ms for fb in taken[ring:])

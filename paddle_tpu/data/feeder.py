@@ -11,11 +11,13 @@ thread:
 * a background **producer** thread runs the reader, converts each
   minibatch (``topology.convert_feed``, honoring a BucketBatch's exact
   pad target) and PLACES it on the device — sharding-aware: with a
-  ``parallelism`` (parallel.mesh.DataParallel) the batch is
-  ``jax.device_put`` onto the global-mesh 'data' axis exactly as
-  ``shard_train_step`` would have, so the transfer happens ahead of the
-  step instead of inside it (the layout distributed/worker.py trains
-  with);
+  ``parallelism`` (parallel.mesh.DataParallel) the batch lands on the
+  global-mesh 'data' axis exactly where ``shard_train_step`` would have
+  put it, so the transfer happens ahead of the step instead of inside
+  it (the layout distributed/worker.py trains with). A recycled column
+  goes there in ONE crossing, each device's rows straight from the host
+  buffer; every other leaf arrives on one device and ``shard_batch``
+  moves it, a second crossing that runs as a program on that device;
 * a bounded queue keeps up to ``depth`` batches device-resident ahead
   of the step;
 * the consumer (`batches()`) yields :class:`FeedBatch` records carrying
@@ -211,16 +213,22 @@ class HostBuffers:
 
     The owner calls :meth:`assemble` for a column of each batch (through
     ``topology.convert_feed`` inside ``topology.recycling_into``) and
-    :meth:`sent` once the batch is in its final place. The rule that makes recycling safe: a slot
-    remembers the **final** device leaf made from it (on a mesh, the
-    sharded one: ready means host to device 0 and device 0 to mesh are
-    both done), and before the slot is written again the producer waits
-    for that leaf (``block_until_ready``) and lets go of it. A transfer
-    reads the host array for tens of milliseconds after the placing call
-    has returned, and nothing is written under it. The placement itself
-    is a copy the slot does not share (``topology._place``). With as many
-    slots as batches can be alive at once the wait is zero whenever the
-    feed is ahead.
+    :meth:`sent` once the batch is in its final place. The rule that makes
+    recycling safe: a slot remembers the device leaf made from it, and
+    before the slot is written again the producer waits for that leaf
+    (``block_until_ready``) and lets go of it. A transfer reads the host
+    array for tens of milliseconds after the placing call has returned,
+    and nothing is written under it. The placement itself is a copy the
+    slot does not share (``topology._place``). With as many slots as
+    batches can be alive at once the wait is zero whenever the feed is
+    ahead.
+
+    On a mesh (``sharding_of``, the owner's rule for where a batch leaf of
+    a shape goes: ``DataParallel.batch_leaf_sharding``) the one crossing
+    is :meth:`place_sharded`: every device receives its own rows out of
+    the slot's host array, the leaf is the sharded array, and ready means
+    all of those transfers are done. No array of the whole batch exists on
+    any one device.
 
     What it does not take, by what it sees: rows that do not form one
     rectangular array (``assemble`` returns None and the caller's own
@@ -231,10 +239,13 @@ class HostBuffers:
     uses it at a time.
     """
 
-    def __init__(self, slots, reused, allocated):
+    def __init__(self, slots, reused, allocated, sharding_of=None,
+                 placed_sharded=None):
         self.slots = int(slots)  # of each column's ring
         self._reused = reused        # counters of the owner's registry
         self._allocated = allocated
+        self._sharding_of = sharding_of  # shape -> sharding on the mesh
+        self._placed_sharded = placed_sharded  # counter, with the above
         self._rings = {}    # column name -> _Ring
         self._filled = {}   # column name -> the slot this batch was put in
         self._waited = None  # seconds this batch waited; None: reused none
@@ -271,6 +282,22 @@ class HostBuffers:
         self._filled[name] = slot
         return slot.host
 
+    def place_sharded(self, host):
+        """``host`` (an array of :meth:`assemble`) on the owner's mesh,
+        each device's rows straight from the host array, or None where
+        the owner has no mesh or its rule does not split these rows: the
+        caller then places on one device as ever, and the owner's
+        ``shard_batch`` copies from there."""
+        if self._sharding_of is None:
+            return None
+        want = self._sharding_of(host.shape)
+        if want.is_fully_replicated:
+            return None
+        import jax
+
+        self._placed_sharded.inc()
+        return jax.device_put(host, want)
+
     def _wait_for(self, slot):
         with observe_spans.span("feed_buffer_wait") as wait:
             if slot.leaf is not None:
@@ -304,7 +331,9 @@ class DeviceFeeder:
     passes of one ``train`` call and dies with it. Fixed-shape dense and
     index columns are copied into a ring of ``depth + 2`` recycled arrays
     (the queue, the batch being assembled and the one in the step), and
-    a reader's rows are copied, never kept. Sequence, nested and sparse
+    a reader's rows are copied, never kept; with a ``parallelism`` that
+    has the rule (``batch_leaf_sharding``) they go from there onto the
+    mesh in one crossing. Sequence, nested and sparse
     slots, a short last batch and a custom ``convert=`` get fresh arrays
     as before; so does every caller of ``convert_feed`` that is not a
     feeder, since nobody there can say when a batch is dead.
@@ -355,7 +384,13 @@ class DeviceFeeder:
                 help="columns assembled into a recycled host buffer"),
             allocated=m.counter(
                 "paddle_tpu_data_feed_buffers_allocated_total",
-                help="host buffers allocated for the feeder's rings"))
+                help="host buffers allocated for the feeder's rings"),
+            # a plan without the rule gets its shard_batch alone
+            sharding_of=getattr(parallelism, "batch_leaf_sharding", None),
+            placed_sharded=m.counter(
+                "paddle_tpu_data_feed_placed_sharded_total",
+                help="columns placed from a host buffer straight onto the "
+                     "mesh, each device its own rows"))
         self._m_batches = m.counter(
             "paddle_tpu_data_batches_total",
             help="batches assembled by the feed pipeline")
@@ -380,8 +415,11 @@ class DeviceFeeder:
         if self.parallelism is not None:
             # the DataParallel global-mesh placement shard_train_step
             # would apply — done HERE so the transfer overlaps compute.
-            # The device-0 arrays die with this rebinding: the slots hold
-            # the mesh's leaves
+            # The recycled columns are at their target already and pass
+            # through by identity; what came on one device (sequence and
+            # sparse slots, a short last batch, a convert= of the
+            # caller's) is moved from there, and that array dies with
+            # this rebinding
             feed = self.parallelism.shard_batch(feed)
         return feed
 

@@ -20,9 +20,18 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 # here because this is where users look for it
 from paddle_tpu.core.mesh_scope import current_mesh, use_mesh  # noqa: F401
 from paddle_tpu.core.sequence import NestedSequenceBatch, SequenceBatch
+from paddle_tpu.observe import metrics as observe_metrics
 from paddle_tpu.observe import spans as observe_spans
 from paddle_tpu.utils.error import enforce
 from paddle_tpu.utils.logger import logger
+
+
+def _resharded():
+    # of the process's registry, not a feeder's: the step thread and
+    # evaluation come through shard_batch too
+    return observe_metrics.get_registry().counter(
+        "paddle_tpu_data_feed_resharded_total",
+        help="batch leaves shard_batch moved from a device onto the mesh")
 
 
 def local_device_count():
@@ -74,23 +83,33 @@ class DataParallel:
     def replicated(self):
         return NamedSharding(self.mesh, P())
 
+    def batch_leaf_sharding(self, shape):
+        """Where a batch leaf of ``shape`` goes: axis 0 over the data axis
+        when the mesh divides its rows, else a copy on every device. The
+        one rule: :meth:`shard_batch` asks it of every leaf, and the
+        DeviceFeeder of the host arrays it places itself."""
+        if len(shape) >= 1 and shape[0] % self.mesh.shape[self.axis] == 0:
+            return NamedSharding(
+                self.mesh, P(*([self.axis] + [None] * (len(shape) - 1))))
+        return self.replicated()
+
     def shard_batch(self, tree):
-        """Place a host batch onto the mesh, sharded on axis 0.
+        """Place a batch onto the mesh, sharded on axis 0.
         Idempotent: leaves already carrying their target sharding pass
-        through untouched, so a feed the DeviceFeeder pre-placed
-        (paddle_tpu.data.feeder) costs the step thread nothing here.
-        Each leaf that does move is a ``feed_place`` span."""
-        repl = self.replicated()
+        through untouched, so a feed the DeviceFeeder placed
+        (paddle_tpu.data.feeder; its recycled columns straight from the
+        host, the rest through here on its producer) costs the step thread
+        nothing. Each leaf that does move is a ``feed_place`` span, and one
+        that moves from a device (a second crossing, as a program on that
+        device) counts in ``paddle_tpu_data_feed_resharded_total``."""
 
         def place(x):
-            if hasattr(x, "ndim") and x.ndim >= 1 and x.shape[0] % self.mesh.shape[self.axis] == 0:
-                want = NamedSharding(
-                    self.mesh, P(*([self.axis] + [None] * (x.ndim - 1))))
-            else:
-                want = repl
+            want = self.batch_leaf_sharding(getattr(x, "shape", ()))
             if getattr(x, "sharding", None) == want:
                 return x
             with observe_spans.span("feed_place"):
+                if isinstance(x, jax.Array):
+                    _resharded().inc()
                 return jax.device_put(x, want)
 
         return jax.tree_util.tree_map(place, tree)

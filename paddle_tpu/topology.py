@@ -265,8 +265,10 @@ _owner = threading.local()
 def recycling_into(buffers):
     """Inside this scope, on this thread, :func:`convert_feed` assembles
     fixed-shape dense and index columns into ``buffers`` (a
-    ``data.feeder.HostBuffers``). Only an owner that knows when a batch's
-    bytes have left the host may enter it: the DeviceFeeder's producer. A
+    ``data.feeder.HostBuffers``), and places them where ``buffers`` says:
+    straight onto its owner's mesh, if it has one. Only an owner that
+    knows when a batch's bytes have left the host may enter it: the
+    DeviceFeeder's producer. A
     scope and not an argument of ``convert_feed``, so that the pool
     reaches the real ``convert_feed`` through whatever a caller has put in
     its place (a wrapper of its four arguments, as the benchmark's
@@ -366,27 +368,34 @@ def convert_column(col, itype, max_len=None, assemble=None):
 def _place(host, recycled=False):
     """Hand an assembled host array to the device, as a ``feed_place``
     span: what is left of the enclosing ``feed_convert`` is host
-    assembly. A ``recycled`` array will be written again, so what is
-    placed from it must be a copy: the CPU platform wraps a suitably
-    aligned numpy array instead of copying it (alignment decides, and
-    ``device_put(may_alias=False)`` does not reach numpy inputs on jax
-    0.9), and there the placed array is copied on the device."""
+    assembly. Onto one device, but for a ``recycled`` array whose owner
+    (the ``HostBuffers`` of :func:`recycling_into`) knows a mesh: it
+    places each device's rows straight from the host array, so no batch
+    crosses one device on its way to four. A ``recycled`` array will be
+    written again, so what is placed from it must be a copy: the CPU
+    platform wraps a suitably aligned numpy array instead of copying it
+    (alignment decides, and ``device_put(may_alias=False)`` does not
+    reach numpy inputs on jax 0.9), and there the placed array is copied
+    on the device(s)."""
     with observe_spans.span("feed_place"):
-        placed = jnp.asarray(host)
+        owner = getattr(_owner, "buffers", None) if recycled else None
+        placed = None if owner is None else owner.place_sharded(host)
+        if placed is None:
+            placed = jnp.asarray(host)
         if recycled and _lives_in(placed, host):
             placed = jnp.copy(placed)
         return placed
 
 
 def _lives_in(placed, host):
-    """Whether a placed array's buffer lies inside ``host``'s own bytes.
-    Only a device whose memory is the host's can; a TPU's transfer is
-    never asked (its pointer would wait for the bytes to land)."""
-    device, = placed.devices()  # jnp.asarray places on one
-    if device.platform != "cpu":
-        return False
+    """Whether any shard of a placed array lies inside ``host``'s own
+    bytes. Only a device whose memory is the host's can; a TPU's transfer
+    is never asked (its pointer would wait for the bytes to land)."""
     start = host.ctypes.data
-    return start <= placed.unsafe_buffer_pointer() < start + host.nbytes
+    return any(
+        shard.device.platform == "cpu"
+        and start <= shard.data.unsafe_buffer_pointer() < start + host.nbytes
+        for shard in placed.addressable_shards)
 
 
 def _densify(rows, itype):

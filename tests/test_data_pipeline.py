@@ -1022,15 +1022,16 @@ def test_an_open_chunk_holds_k_distinct_buffers_bytes():
 
 def test_on_a_mesh_a_slot_holds_the_mesh_leaf_not_the_device0_array():
     """(g) under a DataParallel the slot waits for the sharded leaf (ready
-    means both crossings are done) and keeps nothing of device 0's copy."""
-    from paddle_tpu.parallel.mesh import DataParallel, build_mesh
-
+    means the one crossing, every device's transfer out of the slot's host
+    array, is done) and no device-0 copy of the batch was ever made."""
     n = 4
-    dp = DataParallel(build_mesh({"data": n}, devices=jax.devices()[:n]))
+    dp = _mesh_of(n)
     topo = Topology(_labelled_model())
     batches = _labelled_batches(3)
+    reg = observe_metrics.MetricsRegistry()
+    moved = _resharded()
     feeder = DeviceFeeder(lambda: iter(batches), topo, parallelism=dp,
-                          metrics_registry=observe_metrics.MetricsRegistry())
+                          metrics_registry=reg)
     got = list(feeder.batches())
     for name in ("x", "y"):
         slots = feeder._buffers._rings[name].slots
@@ -1039,6 +1040,260 @@ def test_on_a_mesh_a_slot_holds_the_mesh_leaf_not_the_device0_array():
             assert slot.leaf is fb.feed[name]
             assert len(slot.leaf.sharding.device_set) == n
             assert not slot.leaf.sharding.is_fully_replicated
+    assert reg.snapshot()["counters"][PLACED_SHARDED] == 2 * 3
+    assert _resharded() == moved
+
+
+# ---- on a mesh a recycled column goes straight to its shards ---------------
+
+PLACED_SHARDED = "paddle_tpu_data_feed_placed_sharded_total"
+RESHARDED = "paddle_tpu_data_feed_resharded_total"
+
+
+def _mesh_of(n):
+    from paddle_tpu.parallel.mesh import DataParallel, build_mesh
+
+    return DataParallel(build_mesh({"data": n}, devices=jax.devices()[:n]))
+
+
+def _resharded():
+    """`shard_batch` counts in the process's registry: it serves the step
+    thread and evaluation too, whatever registry a feeder was given."""
+    return observe_metrics.get_registry().counter(RESHARDED).value
+
+
+def _placed(reg):
+    return reg.snapshot()["counters"].get(PLACED_SHARDED, 0)
+
+
+class _PlacementSpy:
+    """Every hand-over of a host array to a device that the feed's two
+    placing calls make: (call, shape, devices the target spans)."""
+
+    def __init__(self, monkeypatch):
+        self.seen = []
+        put, asarray = jax.device_put, jnp.asarray
+
+        def device_put(x, device=None, **kw):
+            if isinstance(x, np.ndarray):
+                spans = len(device.device_set) \
+                    if isinstance(device, jax.sharding.Sharding) else 1
+                self.seen.append(("device_put", x.shape, spans))
+            return put(x, device, **kw)
+
+        def spied_asarray(a, *args, **kw):
+            if isinstance(a, np.ndarray):
+                self.seen.append(("asarray", a.shape, 1))
+            return asarray(a, *args, **kw)
+
+        monkeypatch.setattr(jax, "device_put", device_put)
+        monkeypatch.setattr(jnp, "asarray", spied_asarray)
+
+
+def _assert_shards_hold_their_rows(leaf, rows, n):
+    assert len(leaf.addressable_shards) == n
+    assert len({shard.device for shard in leaf.addressable_shards}) == n
+    each = rows.shape[0] // n
+    for shard in leaf.addressable_shards:
+        assert shard.data.shape[0] == each
+        np.testing.assert_array_equal(np.asarray(shard.data),
+                                      rows[shard.index])
+
+
+@pytest.mark.parametrize("n", [2, 4])
+def test_on_a_mesh_recycled_columns_go_straight_to_their_shards(
+        n, monkeypatch, aligned_slots):
+    """(i) shard by shard the rows, bit for bit; (ii) the very sharding
+    `shard_batch` would choose, so it passes them through by identity;
+    (iii) never an array on one device: counted as placed, none resharded,
+    and no single-device hand-over of a column's shape was made."""
+    dp = _mesh_of(n)
+    topo = Topology(_labelled_model())
+    batches = _labelled_batches(RING + 3, seed=6)
+    reg = observe_metrics.MetricsRegistry()
+    moved = _resharded()
+    spy = _PlacementSpy(monkeypatch)
+    got = list(DeviceFeeder(lambda: iter(batches), topo, parallelism=dp,
+                            metrics_registry=reg).batches())
+    assert len(got) == RING + 3 and _counters(reg) == (2 * 3, 2 * RING)
+    for fb, batch in zip(got, batches):
+        rows = {"x": np.asarray([r[0] for r in batch], dtype=np.float32),
+                "y": np.asarray([r[1] for r in batch], dtype=np.int32)}
+        one_device = convert_feed(topo, batch)
+        want = dp.shard_batch(one_device)
+        again = dp.shard_batch(fb.feed)
+        for name in ("x", "y"):
+            leaf = fb.feed[name]
+            assert leaf.dtype == one_device[name].dtype
+            _assert_shards_hold_their_rows(leaf, rows[name], n)
+            assert leaf.sharding == want[name].sharding \
+                == dp.batch_leaf_sharding(rows[name].shape)
+            assert again[name] is leaf
+    assert _placed(reg) == 2 * (RING + 3)
+    # the comparison's own `shard_batch(convert_feed(...))` moved two a batch
+    assert _resharded() - moved == 2 * (RING + 3)
+    feeders = [s for s in spy.seen if s[0] == "device_put"]
+    assert feeders == [("device_put", shape, n)
+                       for _ in batches for shape in ((8, 6), (8,))]
+
+
+@pytest.mark.parametrize("n", [2, 4])
+@pytest.mark.parametrize("case", ["rows_not_divided", "short_last_batch"])
+def test_on_a_mesh_rows_the_direct_path_declines_take_todays(case, n):
+    """(iv) a row count the mesh does not divide, and a short last batch
+    (which no ring takes): one device first, then `shard_batch`, with the
+    result `shard_batch(convert_feed(...))` gives."""
+    dp = _mesh_of(n)
+    topo = Topology(_labelled_model())
+    if case == "rows_not_divided":
+        stream = _labelled_batches(RING + 2, rows=7, seed=8)
+        odd = list(range(len(stream)))
+    else:
+        stream = _labelled_batches(RING + 1, seed=8) \
+            + _labelled_batches(1, rows=4, seed=9)
+        odd = [RING + 1]
+    reg = observe_metrics.MetricsRegistry()
+    moved = _resharded()
+    got = list(DeviceFeeder(lambda: iter(stream), topo, parallelism=dp,
+                            metrics_registry=reg).batches())
+    assert _resharded() - moved == 2 * len(odd)
+    assert _placed(reg) == 2 * (len(stream) - len(odd))
+    for i, (fb, batch) in enumerate(zip(got, stream)):
+        want = dp.shard_batch(convert_feed(topo, batch))
+        for name in ("x", "y"):
+            assert fb.feed[name].sharding == want[name].sharding
+            assert fb.feed[name].dtype == want[name].dtype
+            np.testing.assert_array_equal(np.asarray(fb.feed[name]),
+                                          np.asarray(want[name]))
+        replicated = fb.feed["x"].sharding.is_fully_replicated
+        assert replicated == (case == "rows_not_divided")
+        assert (i in odd) or not replicated
+
+
+def _sparse_model(dim=5000, classes=4):
+    reset_name_counters()
+    ids = L.data(name="ids", type=dt.sparse_binary_vector(dim))
+    y = L.data(name="y", type=dt.integer_value(classes))
+    return L.classification_cost(input=L.fc(input=ids, size=classes),
+                                 label=y)
+
+
+@pytest.mark.parametrize("slot", ["sequence", "sparse", "convert"])
+def test_on_a_mesh_other_slots_are_moved_by_shard_batch_as_before(slot):
+    """(v) what is made on one device (inside `SequenceBatch.from_sequences`
+    and `SparseRows.from_rows`, or by a `convert=` the feeder cannot see
+    into) still crosses twice, lands where `shard_batch` puts it, and is
+    counted as resharded."""
+    dp = _mesh_of(4)
+    convert = None
+    if slot == "sequence":
+        topo, reader = Topology(_tagging_model()), _tagging_batches()
+    elif slot == "sparse":
+        topo = Topology(_sparse_model())
+        rng = np.random.RandomState(2)
+        made = [[(sorted(rng.choice(5000, 3, replace=False).tolist()),
+                  int(rng.randint(4))) for _ in range(8)] for _ in range(3)]
+        reader = lambda: iter(made)  # noqa: E731
+    else:
+        topo = Topology(_labelled_model())
+        made = _labelled_batches(3)
+        reader = lambda: iter(made)  # noqa: E731
+
+        def convert(topology, data_batch, feeding, max_len):
+            return convert_feed(topology, data_batch, feeding,
+                                max_len=max_len)
+
+    reg = observe_metrics.MetricsRegistry()
+    moved = _resharded()
+    got = list(DeviceFeeder(reader, topo, parallelism=dp, convert=convert,
+                            metrics_registry=reg).batches())
+    by_shard_batch = _resharded() - moved
+    leaves = 0
+    for fb, batch in zip(got, reader()):
+        want = dp.shard_batch(convert_feed(
+            topo, batch, max_len=getattr(batch, "bucket", None)))
+        assert jax.tree_util.tree_structure(fb.feed) \
+            == jax.tree_util.tree_structure(want)
+        for a, b in zip(jax.tree_util.tree_leaves(fb.feed),
+                        jax.tree_util.tree_leaves(want)):
+            assert a.sharding == b.sharding and a.dtype == b.dtype
+            assert len(a.sharding.device_set) == 4
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+            leaves += 1
+    # the recycled label column of the sparse model went straight there
+    direct = len(got) if slot == "sparse" else 0
+    assert _placed(reg) == direct
+    assert by_shard_batch == leaves - direct and by_shard_batch > 0
+
+
+@pytest.mark.parametrize("n", [2, 4])
+def test_on_a_mesh_chunks_are_stacks_of_leaves_placed_once(n):
+    """(vi) `chunks(3)`: every member of a stack lies on the mesh, holds
+    its rows, and no leaf was moved from a device to get there."""
+    dp = _mesh_of(n)
+    topo = Topology(_labelled_model())
+    batches = _labelled_batches(9, seed=12)
+    reg = observe_metrics.MetricsRegistry()
+    moved = _resharded()
+    chunks = list(DeviceFeeder(lambda: iter(batches), topo, depth=2,
+                               parallelism=dp,
+                               metrics_registry=reg).chunks(3))
+    assert [c.steps for c in chunks] == [3, 3, 3]
+    assert all(c.stacked for c in chunks)
+    members = [feed for c in chunks for feed in c.feed]
+    for feed, batch in zip(members, batches):
+        rows = np.asarray([r[0] for r in batch], dtype=np.float32)
+        _assert_shards_hold_their_rows(feed["x"], rows, n)
+        assert dp.shard_batch(feed)["x"] is feed["x"]
+        assert dp.shard_batch(feed)["y"] is feed["y"]
+    assert _resharded() == moved and _placed(reg) == 2 * 9
+
+
+def test_a_plan_without_the_rule_gets_its_shard_batch_alone():
+    """A `parallelism` that has a `shard_batch` and no
+    `batch_leaf_sharding`: the feeder places on one device and hands the
+    feed to that `shard_batch`, as before this rule existed."""
+    dp = _mesh_of(2)
+
+    class OldPlan:
+        def shard_batch(self, tree):
+            return dp.shard_batch(tree)
+
+    topo = Topology(_labelled_model())
+    batches = _labelled_batches(RING + 2)
+    reg = observe_metrics.MetricsRegistry()
+    moved = _resharded()
+    got = list(DeviceFeeder(lambda: iter(batches), topo,
+                            parallelism=OldPlan(),
+                            metrics_registry=reg).batches())
+    assert _placed(reg) == 0 and _resharded() - moved == 2 * (RING + 2)
+    assert _counters(reg) == (2 * 2, 2 * RING)  # it recycles all the same
+    for fb, batch in zip(got, batches):
+        assert len(fb.feed["x"].sharding.device_set) == 2
+        np.testing.assert_array_equal(
+            np.asarray(fb.feed["x"]),
+            np.asarray([r[0] for r in batch], dtype=np.float32))
+
+
+@pytest.mark.parametrize("n", [1, 2, 4])
+def test_a_placed_array_is_looked_for_in_every_shard(n):
+    """`_lives_in` on a mesh: a CPU device may wrap its own aligned slice
+    of the host array, so each addressable shard is asked; and the copy
+    `_place` makes of such an array keeps its sharding."""
+    from paddle_tpu.topology import _lives_in
+
+    host = _aligned_like(np.empty((64, 16), np.float32))  # 4 KiB a row block
+    host[...] = np.arange(64, dtype=np.float32)[:, None]
+    other = _aligned_like(host)
+    dp = _mesh_of(n)
+    want = dp.batch_leaf_sharding(host.shape)
+    placed = jax.device_put(host, want)
+    assert _lives_in(placed, host) and not _lives_in(placed, other)
+    copied = jnp.copy(placed)
+    assert copied.sharding == want and not _lives_in(copied, host)
+    host[...] = -1.0
+    np.testing.assert_array_equal(
+        np.asarray(copied)[:, 0], np.arange(64, dtype=np.float32))
 
 
 def test_a_new_full_shape_starts_the_columns_ring_afresh():

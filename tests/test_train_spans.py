@@ -152,6 +152,9 @@ def _places_per_convert(tracer):
 
 
 def test_host_plus_place_is_convert_and_the_mesh_places_once_more(recorded):
+    """... once more than nothing: a recycled column is one `feed_place`
+    on a mesh as on one device, since it goes straight to its shards
+    (two before PR 27: device 0, then `shard_batch`'s copy from there)."""
     trainer = _trainer()
     taken = list(DeviceFeeder(_batches(4), trainer.topology).batches())
     assert [fb.seq for fb in taken] == [0, 1, 2, 3]
@@ -162,7 +165,7 @@ def test_host_plus_place_is_convert_and_the_mesh_places_once_more(recorded):
         assert fb.host_ms + fb.place_ms == pytest.approx(fb.convert_ms,
                                                          abs=1e-6)
         assert fb.place_ms > 0 and fb.host_ms > 0 and fb.read_ms >= 0
-    one_device = _places_per_convert(recorded)
+    assert _places_per_convert(recorded) == 2  # the two columns
     recorded.reset()
     mesh = DataParallel(build_mesh({"data": 2}, devices=jax.devices()[:2]))
     sharded = list(DeviceFeeder(_batches(4), trainer.topology,
@@ -171,8 +174,82 @@ def test_host_plus_place_is_convert_and_the_mesh_places_once_more(recorded):
         assert fb.host_ms + fb.place_ms == pytest.approx(fb.convert_ms,
                                                          abs=1e-6)
         assert len(fb.feed["x"].sharding.device_set) == 2
-    # shard_batch's hand-over is one feed_place more for each leaf it moves
-    assert _places_per_convert(recorded) >= one_device + 1
+    assert _places_per_convert(recorded) == 2
+    # a batch the ring does not take still crosses twice: one device, then
+    # shard_batch's hand-over, a feed_place more for each leaf it moves
+    recorded.reset()
+    stream = list(_batches(RING)()) + list(_batches(1, rows=4)())
+    list(DeviceFeeder(lambda: iter(stream), trainer.topology,
+                      parallelism=mesh).batches())
+    places = [e for e in recorded.events() if e[0] == "feed_place"]
+    assert len(places) == 2 * RING + 4
+
+
+@pytest.mark.parametrize("n", [2, 4])
+def test_on_a_mesh_a_recycled_column_is_one_feed_place(n, recorded):
+    """Each recycled column has exactly one `feed_place` child under its
+    batch's `feed_convert`, on the producer's line, and the identity
+    `host = convert - place - buffer_wait` closes with the ring wrapped;
+    so do the histograms' sums, which the two sums of PERF.md section 3
+    are made of."""
+    mesh = DataParallel(build_mesh({"data": n}, devices=jax.devices()[:n]))
+    trainer = _trainer()
+    registry = observe_metrics.MetricsRegistry()
+    steps = RING + 3
+    taken = list(DeviceFeeder(_batches(steps), trainer.topology,
+                              parallelism=mesh,
+                              metrics_registry=registry).batches())
+    events = recorded.events()
+    converts = [e for e in events if e[0] == "feed_convert"]
+    places = [e for e in events if e[0] == "feed_place"]
+    waits = [e for e in events if e[0] == "feed_buffer_wait"]
+    assert len(converts) == steps and len(places) == 2 * steps
+    assert len(waits) == 2 * 3
+    assert all(e[6] == "feed_convert" for e in places + waits)
+    producer = {e[3] for e in converts}
+    assert len(producer) == 1 and {e[3] for e in places + waits} == producer
+    for convert in converts:  # two inside each batch's own interval
+        inside = [e for e in places if convert[1] <= e[1]
+                  and e[1] + e[2] <= convert[1] + convert[2]]
+        assert len(inside) == 2
+    for i, fb in enumerate(taken):
+        assert (fb.buffer_wait_ms > 0) == (i >= RING)
+        assert fb.place_ms > 0 and fb.host_ms > 0
+        assert fb.host_ms + fb.place_ms + fb.buffer_wait_ms == pytest.approx(
+            fb.convert_ms, abs=1e-9)
+    hists = registry.snapshot()["histograms"]
+    place_ms = sum(e[2] for e in places) * 1e3
+    wait_ms = sum(e[2] for e in waits) * 1e3
+    convert_ms = sum(e[2] for e in converts) * 1e3
+    assert hists["paddle_tpu_data_feed_place_ms"]["sum"] == \
+        pytest.approx(place_ms)
+    assert hists[BUFFER_WAIT]["sum"] == pytest.approx(wait_ms)
+    # the producer's sum: host + place + buffer_wait = convert ...
+    assert hists["paddle_tpu_data_feed_host_ms"]["sum"] + place_ms \
+        + wait_ms == pytest.approx(convert_ms)
+    # ... and the consumer's histogram of convert is the same batches'
+    assert hists["paddle_tpu_data_feed_convert_ms"]["sum"] == \
+        pytest.approx(convert_ms)
+    counters = registry.snapshot()["counters"]
+    assert counters["paddle_tpu_data_feed_placed_sharded_total"] == 2 * steps
+
+
+def test_lives_in_looks_at_both_devices_of_a_two_device_array():
+    """It unpacked exactly one device before PR 27 and raised here."""
+    from paddle_tpu.topology import _lives_in, _place
+
+    mesh = DataParallel(build_mesh({"data": 2}, devices=jax.devices()[:2]))
+    raw = np.empty(8 * 16 * 4 + 64, np.uint8)
+    start = (-raw.ctypes.data) % 64
+    host = raw[start:start + 8 * 16 * 4].view(np.float32).reshape(8, 16)
+    host[...] = 3.0
+    placed = jax.device_put(host, mesh.batch_leaf_sharding(host.shape))
+    assert len(placed.devices()) == 2
+    assert _lives_in(placed, host)  # each device wraps its aligned rows
+    assert not _lives_in(placed, np.empty_like(host))
+    assert not _lives_in(jax.numpy.copy(placed), host)
+    # one device, as ever: a fresh array may be shared, a recycled one not
+    assert not _lives_in(_place(host, recycled=True), host)
 
 
 def test_host_plus_place_plus_buffer_wait_is_convert(recorded):
