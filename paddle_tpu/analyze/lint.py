@@ -109,7 +109,7 @@ CHECKERS = {
 # (e.g. the trainer's per-pass ``finalize``) are scanned as part of
 # their enclosing hot function.
 HOT_PATHS = {
-    "trainer.py": {"_train_passes", "_train_passes_fused", "test"},
+    "trainer.py": {"_train_passes", "test"},
     "serve/engine.py": {"submit", "_take_batch", "_loop", "_run_batch"},
     "serve/bundle.py": {"run", "infer", "warmup", "decode_step"},
     "serve/scheduler.py": {"submit", "_loop", "_run_iteration",
@@ -162,7 +162,9 @@ HOT_PATHS = {
     # program (serve/export.py), so a stray host sync in it would land
     # on every serving dispatch of every quantized bundle
     "serve/quantize.py": {"dequant_for_trace", "dequantize"},
-    "data/feeder.py": {"_produce", "batches", "chunks"},
+    # materialize: the unpipelined train loop's conversion, on the step
+    # thread between BeginIteration and the dispatch
+    "data/feeder.py": {"_produce", "batches", "chunks", "materialize"},
     # the async checkpoint writer: submit runs ON the step thread every
     # cadence hit, and the writer loop shares state with it — a stray
     # host sync or an unlocked access here stalls or tears every

@@ -116,10 +116,12 @@ class FeedBatch:
 
 
 class ChunkBatch:
-    """K consecutive pipelined batches grouped for one fused dispatch
-    (``trainer.SGD.train steps_per_call=``, docs/data.md).
+    """The train loop's dispatch unit: n >= 1 consecutive batches handed
+    to the device in one call (``trainer.SGD._train_passes``). Under
+    ``steps_per_call=K`` up to K pipelined batches grouped for one fused
+    dispatch (docs/data.md); otherwise one batch.
 
-    ``feed`` is what the trainer hands to the fused step: for
+    ``feed`` is what the trainer hands to the step: for
     ``steps > 1`` a length-K TUPLE of the member device trees
     (``stacked=True``) — the fused program stacks them into the
     ``lax.scan`` xs layout inside the jit, so chunk assembly costs the
@@ -141,6 +143,12 @@ class ChunkBatch:
         self.examples = sum(fb.examples for fb in self.batches)
         self.stall_ms = sum(fb.stall_ms or 0.0 for fb in self.batches)
         self.convert_ms = sum(fb.convert_ms or 0.0 for fb in self.batches)
+
+    def materialize(self):
+        """Called by the step thread once the unit's steps are announced
+        (``BeginIteration``), before it reads ``feed``. Nothing to do for
+        a feeder's unit: its producer made the members before it was
+        yielded. An :func:`inline_units` unit converts here."""
 
 
 def _feed_shape_key(feed):
@@ -179,6 +187,53 @@ def step_tokens(feed):
     """(valid tokens, batch x padded length) of a step over ``feed``, or
     (None, None) for a feed without sequence slots."""
     return _seq_stats(feed)[3:]
+
+
+class _InlineUnit(ChunkBatch):
+    """A one-batch unit of :func:`inline_units`: its rows stay on the
+    host, and it has no ``feed`` or ``batches``, until ``materialize()``."""
+
+    __slots__ = ("_convert_args",)
+
+    def __init__(self, topo, rows, feeding, seq):
+        self._convert_args = (topo, rows, feeding, seq)
+        self.steps = 1
+        self.stacked = False
+
+    def materialize(self):
+        from paddle_tpu import topology
+
+        topo, rows, feeding, seq = self._convert_args
+        self._convert_args = None  # the rows die with this call
+        with observe_spans.span("feed", args={"batch": seq}) as scope:
+            # through the module attribute, as the producer's call: a
+            # stand-in for convert_feed takes here too
+            feed = topology.convert_feed(
+                topo, rows, feeding, max_len=getattr(rows, "bucket", None))
+        tokens, positions = step_tokens(feed)
+        fb = FeedBatch(feed, len(rows), scope.dur * 1e3, seq=seq,
+                       tokens=tokens, positions=positions)
+        fb.stall_ms = fb.convert_ms  # all of it on the step thread
+        ChunkBatch.__init__(self, feed, [fb], stacked=False)
+
+
+def inline_units(reader, topo, feeding=None, skip=0):
+    """The batch source without a feeder (``SGD.train``'s default,
+    ``feed_pipeline=False``): one-batch :class:`ChunkBatch` units, each
+    converted and placed on the caller's own thread when it calls
+    ``materialize()``, so that the trainer announces batch b
+    (``BeginIteration``) before it pays for b's conversion — the
+    historical synchronous order. The conversion is the ``feed`` span and
+    the unit's ``stall_ms``. ``skip=N`` drops the reader's first N
+    batches unconverted (the resume cursor). Every batch gets fresh host
+    arrays: nobody here knows when a batch's bytes have left the host
+    (:class:`HostBuffers`)."""
+    batch_iter = iter(reader())
+    for _ in range(skip):  # deterministic resume skip
+        if next(batch_iter, None) is None:
+            break
+    for seq, data_batch in enumerate(batch_iter, skip):
+        yield _InlineUnit(topo, data_batch, feeding, seq)
 
 
 class _Slot:
@@ -547,6 +602,12 @@ class DeviceFeeder:
                 "chunk size %d: deepening to %d so a chunk never starves "
                 "the dispatch", self.depth, k, k)
             self.depth = k
+        if k == 1:
+            # one-batch units (the trainer's loop without steps_per_call):
+            # nothing to group, no shape to compare
+            for fb in self.batches(skip=skip):
+                yield self._stack_chunk([fb])
+            return
         group, key = [], None
         sizes, split = [], 0
 

@@ -26,7 +26,7 @@ import jax.numpy as jnp
 from paddle_tpu import event as v2_event
 from paddle_tpu.graph import LayerNode
 from paddle_tpu.parameters import Parameters
-from paddle_tpu.data.feeder import step_tokens
+from paddle_tpu.data.feeder import DeviceFeeder, inline_units
 from paddle_tpu.topology import Topology, convert_feed
 from paddle_tpu.utils import flags
 from paddle_tpu.utils.error import enforce
@@ -288,11 +288,11 @@ class SGD:
         behind, at chunk granularity (sentinel latency, checkpoint
         boundaries and per-step wall timing all coarsen to the chunk; the
         chunk itself is the additive ``train_chunk`` steplog record).
-        ``K=1`` runs the byte-identical per-step program through the
-        chunked loop; the default (None/0) is the historical path,
-        untouched. Partial final chunks (K does not divide the pass
-        length, or a bucket boundary splits a chunk) scan at their own
-        length — one extra compile per distinct chunk size.
+        ``K=1`` runs the byte-identical per-step program with a chunk's
+        records; the default (None/0) groups nothing. Partial final
+        chunks (K does not divide the pass length, or a bucket boundary
+        splits a chunk) scan at their own length — one extra compile per
+        distinct chunk size.
 
         ``checkpoint_dir`` + ``checkpoint_every=N`` (docs/distributed.md):
         every N global steps a full training-state snapshot — parameters,
@@ -317,7 +317,7 @@ class SGD:
         """
         user_handler = event_handler or default_event_handler
         # what the step thread did since the last finalized step, in ms:
-        # each span of the loops below adds its duration as it closes
+        # each span of the loop below adds its duration as it closes
         phases = dict.fromkeys(_STEP_PHASES, 0.0)
 
         def event_handler(event):
@@ -351,8 +351,6 @@ class SGD:
             from paddle_tpu.analyze.topology_check import pretrain_check
 
             pretrain_check(self, steps_per_call=k or None)
-        log_period = flags.get_flag("log_period")
-        test_period = flags.get_flag("test_period")
 
         # observability: host spans around every phase (feed / device step
         # / evaluator read-back — they feed the global StatSet, dumped per
@@ -404,23 +402,10 @@ class SGD:
         completed = False
         last_final = {"t": time.perf_counter(), "phases": phases}
         try:
-            if k:
-                self._train_passes_fused(
-                    reader, num_passes, event_handler, feeding,
-                    sync_params, test_reader, log_period, test_period,
-                    slog, last_final, sentinel, k,
-                    feed_depth=self._feed_depth(feed_pipeline),
-                    start_pass=start_pass, start_cursor=start_cursor,
-                    ckpt=ckpt_ctx)
-            else:
-                self._train_passes(reader, num_passes, event_handler,
-                                   feeding, sync_params, test_reader,
-                                   log_period, test_period, slog,
-                                   last_final, sentinel,
-                                   feed_pipeline=feed_pipeline,
-                                   start_pass=start_pass,
-                                   start_cursor=start_cursor,
-                                   ckpt=ckpt_ctx)
+            self._train_passes(reader, num_passes, event_handler, feeding,
+                               sync_params, test_reader, slog, last_final,
+                               sentinel, k, feed_pipeline, start_pass,
+                               start_cursor, ckpt_ctx)
             completed = True
         except BaseException as exc:
             # any escape from the training loop dumps the black box
@@ -541,177 +526,239 @@ class SGD:
             phases[name] = 0.0
 
     def _train_passes(self, reader, num_passes, event_handler, feeding,
-                      sync_params, test_reader, log_period, test_period,
-                      slog, last_final, sentinel=None, feed_pipeline=False,
-                      start_pass=0, start_cursor=0, ckpt=None):
+                      sync_params, test_reader, slog, last_final, sentinel,
+                      k, feed_pipeline, start_pass, start_cursor, ckpt):
+        """The train loop, over dispatch units (data/feeder.py
+        ChunkBatch): n >= 1 consecutive batches handed to the device in
+        one call. Where a unit comes from is its source's business: the
+        feeder's producer thread (``feed_pipeline``; ``steps_per_call=K``
+        implies it and groups up to K batches a unit) or, by default,
+        this thread (``inline_units``). A stacked unit is ONE
+        ``lax.scan`` dispatch (``_train_chunk``); a one-batch unit (no
+        ``steps_per_call``, K=1, a remainder, a bucket boundary) is the
+        ordinary per-step program — byte-identical math, no scan-of-1
+        compile.
+
+        One-deep pipeline (PyDataProvider2 pool-thread parity,
+        TPU-shaped): unit u+1 is converted and DISPATCHED before unit u's
+        loss/stats are fetched from the device, so host-side data
+        conversion and event handling overlap the accelerator — the loop
+        never blocks on a per-batch device_get before launching the next
+        step. Events, steplog ``step`` records, metrics and sentinel
+        checks still fire once per real step, in order with exact values,
+        one dispatch behind (a unit's n steps at a time); handlers
+        reading live parameters mid-pass see the in-flight unit.
+
+        What ``steps_per_call`` changes besides the grouping is what the
+        wall interval between two readbacks is called: per-step wall time
+        is unmeasurable inside a fused region, so ``step`` records then
+        carry no wall_ms and the interval lands on the unit's
+        ``train_chunk`` record (span, trainview and sentinel ring
+        likewise); without it the interval is the step's own."""
+        log_period = flags.get_flag("log_period")
+        test_period = flags.get_flag("test_period")
         (m_steps, m_examples, m_loss, m_examples_per_sec,
          m_phases, m_tokens) = self._train_metrics()
         # per-worker windowed health (observe/trainview.py): the fleet
         # view's live counterpart to the steplog, O(1) memory
         thist = observe_trainview.get_train_history()
         phases = last_final["phases"]
-        # ONE feeder across passes (batches() starts a fresh producer
-        # thread per pass) so its cumulative per-bucket fill/waste
-        # gauges span the whole run, like the serve engine's
+        # ONE feeder across passes (each pass's generator starts a fresh
+        # producer thread) so its cumulative per-bucket fill/waste gauges
+        # span the whole run, like the serve engine's. Conversion and
+        # device placement happen on that thread; the "feed" span on this
+        # one measures only the STALL the step thread spent waiting for
+        # data (also a paddle_tpu_data_feed_stall_ms histogram sample, and
+        # each batch writes a ``feed`` steplog record), so feed_ms on the
+        # step record is the host time actually charged to the step thread
         feeder = None
+        if k or feed_pipeline:
+            feeder = DeviceFeeder(
+                reader, self.topology, feeding=feeding,
+                depth=max(self._feed_depth(feed_pipeline), k),
+                parallelism=self.parallelism)
         for pass_id in range(start_pass, num_passes):
             # resumed pass: the first ``start_cursor`` batches were
             # already trained before the checkpoint — skip them on the
             # stream so batch numbering (and every event/record keyed on
-            # it) continues exactly where the snapshot left off
+            # it) continues exactly where the snapshot left off. The
+            # cursor counts BATCHES, so a resume lands exactly even when
+            # chunk regrouping differs — the fused math is K-invariant
             cursor0 = start_cursor if pass_id == start_pass else 0
-            if not feed_pipeline:
-                batch_iter = iter(reader())
-                for _ in range(cursor0):  # deterministic resume skip
-                    if next(batch_iter, None) is None:
-                        break
+            if feeder is not None:
+                units = feeder.chunks(k or 1, skip=cursor0)
             else:
-                from paddle_tpu.data.feeder import DeviceFeeder
-
-                if feeder is None:
-                    feeder = DeviceFeeder(
-                        reader, self.topology, feeding=feeding,
-                        depth=self._feed_depth(feed_pipeline),
-                        parallelism=self.parallelism)
-                batch_iter = feeder.batches(skip=cursor0)
+                units = inline_units(reader, self.topology, feeding,
+                                     skip=cursor0)
             if cursor0:
-                batch_iter = self._resume_pass_iter(batch_iter, pass_id)
-                if batch_iter is None:
+                units = self._resume_pass_iter(units, pass_id)
+                if units is None:
                     continue  # pass was complete at the checkpoint
             event_handler(v2_event.BeginPass(pass_id))
             eval_acc = {e.name: None for e in self.evaluators}
             batch_id = cursor0
-            # One-deep input pipeline (PyDataProvider2 pool-thread parity,
-            # TPU-shaped): step k+1's feed is converted and DISPATCHED
-            # before step k's loss/stats are fetched from the device, so
-            # host-side data conversion and event handling overlap the
-            # accelerator — the loop never blocks on a per-batch
-            # device_get before launching the next step. Events still fire
-            # in order with exact values, one dispatch behind; handlers
-            # reading live parameters mid-pass see the in-flight step.
-            pending = None  # (batch_id, loss, stats, feed, feed_ms, n_ex)
-            taken = ()  # the FeedBatch taken since the last finalize
+            pending = None  # (batch_id, base_step, losses, stats, unit)
+            taken = ()  # the producer's FeedBatches since the last finalize
 
             def finalize(item):
-                b_id, loss, stats, feed, feed_ms, n_examples = item
-                metrics = {}
+                b_id, base_step, losses, stats, unit = item
                 with observe_spans.span("eval_readback",
                                         args={"batch": b_id}) as readback:
-                    for e in self.evaluators:
-                        eval_acc[e.name] = e.merge(
-                            eval_acc[e.name], jax.device_get(stats[e.name]))
-                        metrics[e.name] = e.result(eval_acc[e.name])
-                    loss = float(loss)
+                    costs = np.atleast_1d(
+                        np.asarray(jax.device_get(losses), dtype=np.float64))
+                    host_stats = (jax.device_get(stats)
+                                  if self.evaluators else {})
                 phases["readback"] += readback.dur * 1e3
                 wall_ms = self._close_step(last_final, m_phases,
-                                           self._pending_step_of(b_id), taken)
-                if slog is not None:
-                    slog.log_step(
-                        step=self._pending_step_of(b_id), pass_id=pass_id,
-                        batch_id=b_id, wall_ms=wall_ms, feed_ms=feed_ms,
-                        cost=loss, examples=n_examples, metrics=metrics)
-                m_steps.inc()
-                m_examples.inc(n_examples)
-                m_loss.set(loss)
+                                           base_step + 1, taken)
                 if wall_ms > 0:
-                    m_examples_per_sec.set(n_examples / wall_ms * 1000.0)
-                thist.record_step(wall_ms, examples=n_examples,
-                                  feed_stall_ms=feed_ms)
-                if sentinel is not None:
-                    # halt mode raises TrainingAnomaly here (black box
-                    # already dumped by the sentinel itself)
-                    sentinel.step(self._pending_step_of(b_id), cost=loss,
-                                  pass_id=pass_id, batch_id=b_id,
-                                  wall_ms=round(wall_ms, 4))
-                # reference per-batch sequence: forwardBackward done →
-                # EndForwardBackward → stats/periodic-test → EndIteration
-                # (TrainerInternal.cpp:66-140). With the one-deep pipeline
-                # both fire at finalize time, one dispatch behind.
-                event_handler(v2_event.EndForwardBackward(
-                    pass_id, b_id, gm=self))
-                if log_period and b_id % log_period == 0:
-                    logger.info("pass %d batch %d cost=%.6f %s", pass_id,
-                                b_id, loss, _fmt_metrics(metrics))
-                    if flags.get_flag("show_layer_stat"):
-                        self._log_layer_stats(feed)
-                psp = flags.get_flag("show_parameter_stats_period")
-                if psp and (self._pending_step_of(b_id)) % max(psp, 1) == 0:
-                    self._log_param_stats()
-                if (test_reader is not None and test_period
-                        and self._pending_step_of(b_id) % test_period == 0):
-                    result = self.test(test_reader, feeding=feeding,
-                                       pass_id=pass_id)
-                    logger.info("periodic test: cost=%.6f %s", result.cost,
-                                _fmt_metrics(result.metrics))
-                    event_handler(result)
-                    # the eval pass must not be charged to the next step's
-                    # wall_ms interval
-                    self._reanchor(last_final)
-                event_handler(v2_event.EndIteration(
-                    pass_id, b_id, loss, metrics))
-
-            self._pass_step_base = self._step_count - cursor0
-            if not feed_pipeline:
-                for data_batch in batch_iter:
-                    event_handler(v2_event.BeginIteration(pass_id, batch_id))
-                    with observe_spans.span(
-                            "feed", args={"batch": batch_id}) as feed_scope:
-                        feed = convert_feed(
-                            self.topology, data_batch, feeding,
-                            max_len=getattr(data_batch, "bucket", None))
-                    phases["wait"] += feed_scope.dur * 1e3
-                    self._rng, step_rng = jax.random.split(self._rng)
-                    with observe_spans.span(
-                            "train_step", args={"batch": batch_id}) as step:
-                        (loss, self._trainable, self._replica, self._state,
-                         self._opt_state, stats) = self._train_step(
-                            self._trainable, self._replica, self._static,
-                            self._state, self._opt_state, feed, step_rng)
-                    phases["dispatch"] += step.dur * 1e3
-                    self._observe_tokens(m_tokens, *step_tokens(feed))
-                    self._step_count += 1
-                    self._checkpoint_maybe(ckpt, pass_id, batch_id + 1)
-                    if pending is not None:
-                        finalize(pending)
-                    pending = (batch_id, loss, stats, feed,
-                               feed_scope.dur * 1000.0, len(data_batch))
-                    batch_id += 1
-            else:
-                # pipelined feed (paddle_tpu.data.feeder): conversion +
-                # device placement happen on the feeder's producer thread;
-                # the "feed" span here measures only the STALL the step
-                # thread spent waiting for data (that stall is also a
-                # paddle_tpu_data_feed_stall_ms histogram sample, and each
-                # batch writes a ``feed`` steplog record). feed_ms on the
-                # step record = the stall, the host time actually charged
-                # to the step thread.
-                for fb in batch_iter:
-                    taken = (fb,)
-                    phases["wait"] += fb.stall_ms
-                    event_handler(v2_event.BeginIteration(pass_id, batch_id))
-                    self._rng, step_rng = jax.random.split(self._rng)
-                    with observe_spans.span(
-                            "train_step", args={"batch": fb.seq}) as step:
-                        (loss, self._trainable, self._replica, self._state,
-                         self._opt_state, stats) = self._train_step(
-                            self._trainable, self._replica, self._static,
-                            self._state, self._opt_state, fb.feed, step_rng)
-                    phases["dispatch"] += step.dur * 1e3
-                    self._observe_tokens(m_tokens, fb.tokens, fb.positions)
-                    self._step_count += 1
-                    self._checkpoint_maybe(ckpt, pass_id, batch_id + 1)
+                    m_examples_per_sec.set(unit.examples / wall_ms * 1000.0)
+                step_wall = step_feed = None
+                if k:
                     if slog is not None:
+                        slog.log_train_chunk(
+                            step=base_step + 1, steps=unit.steps,
+                            pass_id=pass_id, batch_id=b_id, wall_ms=wall_ms,
+                            feed_ms=unit.stall_ms,
+                            cost_first=float(costs[0]),
+                            cost_last=float(costs[-1]),
+                            examples=unit.examples)
+                    thist.record_chunk(unit.steps, wall_ms,
+                                       examples=unit.examples,
+                                       feed_stall_ms=unit.stall_ms)
+                    if sentinel is not None:
+                        # chunk granularity: ONE ring record per chunk;
+                        # the per-loss checks run in the per-step tail
+                        # below, where the unfused sentinel.step does
+                        sentinel.record_chunk(base_step + 1, costs,
+                                              pass_id=pass_id, batch_id=b_id,
+                                              wall_ms=round(wall_ms, 4))
+                else:
+                    step_wall, step_feed = wall_ms, unit.stall_ms
+                    thist.record_step(wall_ms, examples=unit.examples,
+                                      feed_stall_ms=unit.stall_ms)
+                # the per-step tail: a unit of n steps runs it n times
+                for i, fb in enumerate(unit.batches):
+                    step, b = base_step + i + 1, b_id + i
+                    metrics = {}
+                    for e in self.evaluators:
+                        per = host_stats[e.name]
+                        # evaluator stats may be arbitrary pytrees; a
+                        # stacked unit carries step i at leading index i
+                        eval_acc[e.name] = e.merge(
+                            eval_acc[e.name],
+                            jax.tree.map(lambda a: a[i], per)
+                            if unit.stacked else per)
+                        metrics[e.name] = e.result(eval_acc[e.name])
+                    cost = float(costs[i])
+                    if slog is not None:
+                        slog.log_step(
+                            step=step, pass_id=pass_id, batch_id=b,
+                            wall_ms=step_wall, feed_ms=step_feed, cost=cost,
+                            examples=fb.examples, metrics=metrics)
+                    m_steps.inc()
+                    m_examples.inc(fb.examples)
+                    m_loss.set(cost)
+                    if sentinel is not None:
+                        # halt mode raises TrainingAnomaly here (black
+                        # box already dumped by the sentinel itself): the
+                        # anomalous step's record/metrics have landed, its
+                        # events do not fire, and a halt-mode trip must
+                        # not swallow the records/events of the unit's
+                        # pre-anomaly steps
+                        if k:
+                            sentinel.check(step, cost, pass_id=pass_id,
+                                           chunk_index=i)
+                        else:
+                            sentinel.step(step, cost=cost, pass_id=pass_id,
+                                          batch_id=b,
+                                          wall_ms=round(wall_ms, 4))
+                    # reference per-batch sequence: forwardBackward done →
+                    # EndForwardBackward → stats/periodic-test →
+                    # EndIteration (TrainerInternal.cpp:66-140). With the
+                    # one-deep pipeline both fire at finalize time, one
+                    # dispatch behind.
+                    event_handler(v2_event.EndForwardBackward(
+                        pass_id, b, gm=self))
+                    if log_period and b % log_period == 0:
+                        logger.info("pass %d batch %d cost=%.6f %s", pass_id,
+                                    b, cost, _fmt_metrics(metrics))
+                        if flags.get_flag("show_layer_stat"):
+                            self._log_layer_stats(fb.feed)
+                    psp = flags.get_flag("show_parameter_stats_period")
+                    if psp and step % max(psp, 1) == 0:
+                        self._log_param_stats()
+                    if (test_reader is not None and test_period
+                            and step % test_period == 0):
+                        result = self.test(test_reader, feeding=feeding,
+                                           pass_id=pass_id)
+                        logger.info("periodic test: cost=%.6f %s",
+                                    result.cost,
+                                    _fmt_metrics(result.metrics))
+                        event_handler(result)
+                        # the eval pass must not be charged to the next
+                        # wall interval
+                        self._reanchor(last_final)
+                    event_handler(v2_event.EndIteration(
+                        pass_id, b, cost, metrics))
+
+            for unit in units:
+                # every real step of the unit announces itself before the
+                # dispatch, so the reference ordering BeginIteration(b) <
+                # EndForwardBackward(b) < EndIteration(b) holds for any K;
+                # and before an inline unit is converted, after a
+                # feeder's was taken: each source's historical order
+                for i in range(unit.steps):
+                    event_handler(v2_event.BeginIteration(
+                        pass_id, batch_id + i))
+                unit.materialize()
+                if feeder is not None:
+                    taken = unit.batches
+                phases["wait"] += unit.stall_ms
+                if not unit.stacked:
+                    self._rng, step_rng = jax.random.split(self._rng)
+                with observe_spans.span(
+                        "train_chunk" if k else "train_step",
+                        args={"steps": unit.steps, "batch": batch_id} if k
+                        else {"batch": batch_id}) as dispatch:
+                    if unit.stacked:
+                        # the rng carry advances INSIDE the fused program
+                        # through the same sequential split stream as the
+                        # per-step dispatch — fixed-seed trajectories are
+                        # K-invariant
+                        (losses, self._trainable, self._replica,
+                         self._state, self._opt_state, stats,
+                         self._rng) = self._train_chunk(
+                            self._trainable, self._replica, self._static,
+                            self._state, self._opt_state, unit.feed,
+                            self._rng)
+                    else:
+                        (losses, self._trainable, self._replica,
+                         self._state, self._opt_state,
+                         stats) = self._train_step(
+                            self._trainable, self._replica, self._static,
+                            self._state, self._opt_state, unit.feed,
+                            step_rng)
+                phases["dispatch"] += dispatch.dur * 1e3
+                for fb in unit.batches:
+                    self._observe_tokens(m_tokens, fb.tokens, fb.positions)
+                base_step = self._step_count
+                self._step_count += unit.steps
+                # unit boundary == step boundary: the first one at or
+                # past the cadence commits the snapshot
+                self._checkpoint_maybe(ckpt, pass_id, batch_id + unit.steps)
+                if slog is not None:
+                    for i, fb in enumerate(taken):
                         slog.log_feed(
-                            step=self._step_count, stall_ms=fb.stall_ms,
-                            convert_ms=fb.convert_ms, examples=fb.examples,
-                            depth=feeder.depth, bucket=fb.bucket,
-                            fill_tokens=fb.fill_tokens,
+                            step=base_step + i + 1, stall_ms=fb.stall_ms,
+                            convert_ms=fb.convert_ms,
+                            examples=fb.examples, depth=feeder.depth,
+                            bucket=fb.bucket, fill_tokens=fb.fill_tokens,
                             pad_tokens=fb.pad_tokens)
-                    if pending is not None:
-                        finalize(pending)
-                    pending = (batch_id, loss, stats, fb.feed,
-                               fb.stall_ms, fb.examples)
-                    batch_id += 1
+                if pending is not None:
+                    finalize(pending)
+                pending = (batch_id, base_step, losses, stats, unit)
+                batch_id += unit.steps
             taken = ()
             if pending is not None:
                 finalize(pending)
@@ -724,9 +771,8 @@ class SGD:
     def _finish_pass(self, pass_id, eval_acc, event_handler, feeding,
                      sync_params, test_reader, test_period, slog,
                      last_final):
-        """Pass-boundary sequence shared by the per-step and fused loops
-        (per-pass test, sync-back, pass metrics/record, stats dump,
-        EndPass) — ONE ordering for every loop shape."""
+        """Pass-boundary sequence (per-pass test, sync-back, pass
+        metrics/record, stats dump, EndPass)."""
         if test_reader is not None and not test_period:
             # flag default 0 = one test pass per training pass
             result = self.test(test_reader, feeding=feeding,
@@ -761,216 +807,17 @@ class SGD:
         # to the next pass's first step wall_ms
         self._reanchor(last_final)
 
-    def _train_passes_fused(self, reader, num_passes, event_handler,
-                            feeding, sync_params, test_reader, log_period,
-                            test_period, slog, last_final, sentinel, k,
-                            feed_depth=2, start_pass=0, start_cursor=0,
-                            ckpt=None):
-        """The steps_per_call=K loop: chunks of K device-resident feeds
-        (DeviceFeeder.chunks) through ONE scan dispatch, one-deep
-        pipelined like the per-step loop — chunk c+1 is dispatched before
-        chunk c's length-K loss/stat stacks are read back. Per-step
-        events, steplog ``step`` records, metrics and sentinel checks all
-        still fire once per real step at finalize, K at a time; per-step
-        wall time is unmeasurable inside a fused region, so ``step``
-        records carry no wall_ms and the chunk's interval lands on the
-        ``train_chunk`` record instead."""
-        from paddle_tpu.data.feeder import DeviceFeeder
-
-        (m_steps, m_examples, m_loss, m_examples_per_sec,
-         m_phases, m_tokens) = self._train_metrics()
-        # per-worker windowed health, chunk-amortized (trainview.py)
-        thist = observe_trainview.get_train_history()
-        phases = last_final["phases"]
-        # ONE feeder across passes, like the per-step pipelined loop
-        feeder = DeviceFeeder(reader, self.topology, feeding=feeding,
-                              depth=max(int(feed_depth), k),
-                              parallelism=self.parallelism)
-        for pass_id in range(start_pass, num_passes):
-            # resumed pass: skip the already-trained batch prefix (the
-            # checkpoint cursor counts BATCHES, so a resume lands exactly
-            # even when chunk regrouping differs — the fused math is
-            # K-invariant)
-            cursor0 = start_cursor if pass_id == start_pass else 0
-            chunk_iter = feeder.chunks(k, skip=cursor0)
-            if cursor0:
-                chunk_iter = self._resume_pass_iter(chunk_iter, pass_id)
-                if chunk_iter is None:
-                    continue  # pass was complete at the checkpoint
-            event_handler(v2_event.BeginPass(pass_id))
-            eval_acc = {e.name: None for e in self.evaluators}
-            batch_id = cursor0
-            pending = None  # (batch_id, base_step, losses, stats, chunk)
-            taken = ()  # the FeedBatches taken since the last finalize
-
-            def finalize(item):
-                b_id, base_step, losses, stats, chunk = item
-                with observe_spans.span("eval_readback",
-                                        args={"batch": b_id}) as readback:
-                    costs = np.atleast_1d(
-                        np.asarray(jax.device_get(losses), dtype=np.float64))
-                    host_stats = (jax.device_get(stats)
-                                  if self.evaluators else {})
-                phases["readback"] += readback.dur * 1e3
-                wall_ms = self._close_step(last_final, m_phases,
-                                           base_step + 1, taken)
-                n = len(costs)
-                if slog is not None:
-                    slog.log_train_chunk(
-                        step=base_step + 1, steps=n, pass_id=pass_id,
-                        batch_id=b_id, wall_ms=wall_ms,
-                        feed_ms=chunk.stall_ms,
-                        cost_first=float(costs[0]),
-                        cost_last=float(costs[-1]),
-                        examples=chunk.examples)
-                if wall_ms > 0:
-                    m_examples_per_sec.set(
-                        chunk.examples / wall_ms * 1000.0)
-                thist.record_chunk(n, wall_ms, examples=chunk.examples,
-                                   feed_stall_ms=chunk.stall_ms)
-                if sentinel is not None:
-                    # chunk granularity: ONE ring record per chunk; the
-                    # per-loss checks run inside the per-step loop below,
-                    # at the same point of the finalize sequence as the
-                    # legacy path (a halt-mode trip must not swallow the
-                    # records/events of the chunk's pre-anomaly steps)
-                    sentinel.record_chunk(base_step + 1, costs,
-                                          pass_id=pass_id, batch_id=b_id,
-                                          wall_ms=round(wall_ms, 4))
-                for i in range(n):
-                    gstep = base_step + i + 1
-                    metrics = {}
-                    for e in self.evaluators:
-                        per = host_stats[e.name]
-                        # evaluator stats may be arbitrary pytrees; a
-                        # stacked chunk carries step i at leading index i
-                        eval_acc[e.name] = e.merge(
-                            eval_acc[e.name],
-                            jax.tree.map(lambda a: a[i], per)
-                            if chunk.stacked else per)
-                        metrics[e.name] = e.result(eval_acc[e.name])
-                    cost_i = float(costs[i])
-                    if slog is not None:
-                        slog.log_step(
-                            step=gstep, pass_id=pass_id, batch_id=b_id + i,
-                            cost=cost_i,
-                            examples=chunk.batches[i].examples,
-                            metrics=metrics)
-                    m_steps.inc()
-                    m_examples.inc(chunk.batches[i].examples)
-                    m_loss.set(cost_i)
-                    if sentinel is not None:
-                        # same position as the legacy finalize: the
-                        # anomalous step's record/metrics land, halt
-                        # raises before its events fire
-                        sentinel.check(gstep, cost_i, pass_id=pass_id,
-                                       chunk_index=i)
-                    event_handler(v2_event.EndForwardBackward(
-                        pass_id, b_id + i, gm=self))
-                    if log_period and (b_id + i) % log_period == 0:
-                        logger.info("pass %d batch %d cost=%.6f %s",
-                                    pass_id, b_id + i, cost_i,
-                                    _fmt_metrics(metrics))
-                        if flags.get_flag("show_layer_stat"):
-                            self._log_layer_stats(chunk.batches[i].feed)
-                    psp = flags.get_flag("show_parameter_stats_period")
-                    if psp and gstep % max(psp, 1) == 0:
-                        self._log_param_stats()
-                    if (test_reader is not None and test_period
-                            and gstep % test_period == 0):
-                        result = self.test(test_reader, feeding=feeding,
-                                           pass_id=pass_id)
-                        logger.info("periodic test: cost=%.6f %s",
-                                    result.cost,
-                                    _fmt_metrics(result.metrics))
-                        event_handler(result)
-                        # the eval pass must not be charged to the next
-                        # chunk's wall interval
-                        self._reanchor(last_final)
-                    event_handler(v2_event.EndIteration(
-                        pass_id, b_id + i, cost_i, metrics))
-
-            for chunk in chunk_iter:
-                taken = chunk.batches
-                phases["wait"] += chunk.stall_ms
-                # every real step of the chunk announces itself before
-                # the fused dispatch, so the reference ordering
-                # BeginIteration(b) < EndForwardBackward(b) <
-                # EndIteration(b) holds for any K
-                for i in range(chunk.steps):
-                    event_handler(v2_event.BeginIteration(
-                        pass_id, batch_id + i))
-                with observe_spans.span(
-                        "train_chunk", args={"steps": chunk.steps,
-                                             "batch": batch_id}) as step:
-                    if chunk.stacked:
-                        # the rng carry advances INSIDE the fused program
-                        # through the same sequential split stream as the
-                        # per-step loop — fixed-seed trajectories are
-                        # K-invariant
-                        (losses, self._trainable, self._replica,
-                         self._state, self._opt_state, stats,
-                         self._rng) = self._train_chunk(
-                            self._trainable, self._replica, self._static,
-                            self._state, self._opt_state, chunk.feed,
-                            self._rng)
-                    else:
-                        # single-step chunk (K=1, or a remainder/bucket
-                        # boundary): the ordinary per-step program —
-                        # byte-identical math, no scan-of-1 compile
-                        self._rng, step_rng = jax.random.split(self._rng)
-                        (losses, self._trainable, self._replica,
-                         self._state, self._opt_state,
-                         stats) = self._train_step(
-                            self._trainable, self._replica, self._static,
-                            self._state, self._opt_state, chunk.feed,
-                            step_rng)
-                phases["dispatch"] += step.dur * 1e3
-                for fb in chunk.batches:
-                    self._observe_tokens(m_tokens, fb.tokens, fb.positions)
-                base_step = self._step_count
-                self._step_count += chunk.steps
-                # chunk boundary == step boundary: the first one at or
-                # past the cadence commits the snapshot
-                self._checkpoint_maybe(ckpt, pass_id,
-                                       batch_id + chunk.steps)
-                if slog is not None:
-                    for i, fb in enumerate(chunk.batches):
-                        slog.log_feed(
-                            step=base_step + i + 1, stall_ms=fb.stall_ms,
-                            convert_ms=fb.convert_ms,
-                            examples=fb.examples, depth=feeder.depth,
-                            bucket=fb.bucket, fill_tokens=fb.fill_tokens,
-                            pad_tokens=fb.pad_tokens)
-                if pending is not None:
-                    finalize(pending)
-                pending = (batch_id, base_step, losses, stats, chunk)
-                batch_id += chunk.steps
-            taken = ()
-            if pending is not None:
-                finalize(pending)
-            self._finish_pass(pass_id, eval_acc, event_handler, feeding,
-                              sync_params, test_reader, test_period, slog,
-                              last_final)
-        if sync_params:
-            self._sync_back()
-
     @staticmethod
     def _feed_depth(feed_pipeline):
         """Queue depth encoded in train()'s ``feed_pipeline`` argument —
-        ONE interpretation shared by the per-step and fused loops: an
-        explicit int is the depth; ``True`` (and off, for the fused
-        loop's implied pipeline) means the default 2. Booleans checked
+        ONE interpretation with and without ``steps_per_call``: an
+        explicit int is the depth; ``True`` (and off, for the pipeline
+        that ``steps_per_call`` implies) means the default 2. Booleans checked
         first: ``1 == True`` in Python, so a membership/equality test
         would misread depth 1 as the bool."""
         if isinstance(feed_pipeline, bool) or not feed_pipeline:
             return 2
         return max(int(feed_pipeline), 1)
-
-    def _pending_step_of(self, batch_id):
-        """Global step number of a pipelined batch being finalized (the
-        periodic-stats/test triggers keep their pre-pipelining schedule)."""
-        return self._pass_step_base + batch_id + 1
 
     @staticmethod
     def _resume_pass_iter(batch_iter, pass_id):

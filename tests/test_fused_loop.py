@@ -10,6 +10,7 @@ at chunk granularity (the anomaly names the real offending global step),
 the additive ``train_chunk`` telemetry record, the off-path stream
 golden, and the regression-gate wiring for ``exp_fused_loop`` rows."""
 
+import functools
 import json
 import os
 
@@ -542,3 +543,198 @@ def test_steps_per_call_rejects_plan_without_chunk_wrapper():
     with pytest.raises(Exception, match="shard_train_chunk"):
         trainer.train(lambda: iter(_dense_batches(2)), num_passes=1,
                       steps_per_call=2)
+
+
+# ---- one loop, four ways to feed it ----------------------------------------
+# SGD.train has one loop over dispatch units; what differs between these
+# is where a batch comes from and how many steps one dispatch holds. What
+# the user sees must not: trajectory, parameters, events, step count, and
+# a checkpoint cursor any of them can continue from.
+
+MODES = {"default": {}, "pipelined": {"feed_pipeline": True},
+         "k1": {"steps_per_call": 1}, "k4": {"steps_per_call": 4}}
+# a stacked unit's scan reassociates nothing, but XLA fuses it differently
+EXACT = ("default", "pipelined", "k1")
+N_BATCHES, N_PASSES, TEST_PERIOD = 7, 2, 3
+
+
+class _Abort(Exception):
+    pass
+
+
+def _mode_model(model):
+    """(cost, evaluators, optimizer, reader, test_reader): the dense mlp
+    with an error evaluator, or the recurrent tagger."""
+    if model == "mlp":
+        reset_name_counters()
+        img = L.data(name="img", type=dt.dense_vector(784))
+        lab = L.data(name="lab", type=dt.integer_value(10))
+        out = L.fc(input=L.fc(input=img, size=64), size=10)
+        cost = L.classification_cost(input=out, label=lab)
+        err = evaluator.classification_error(input=out, label=lab)
+        batches = _mnist_batches(N_BATCHES, seed=1)
+        held_out = _mnist_batches(2, seed=2)
+        return (cost, [err], opt.Momentum(learning_rate=1e-2, momentum=0.9),
+                lambda: iter(batches), lambda: iter(held_out))
+    cost = _tagging_model()
+    samples = _seq_samples(4 * N_BATCHES, seed=3)
+    held_out = _seq_samples(8, seed=4)
+    return (cost, [], opt.Adam(learning_rate=1e-2),
+            minibatch.batch(lambda: iter(samples), 4),
+            minibatch.batch(lambda: iter(held_out), 4))
+
+
+def _mode_run(model, mode, abort_after=None, reader=None, **train_kw):
+    """One fixed-seed run under ``mode`` with a periodic test every
+    TEST_PERIOD steps. Returns the events, the trainer and what escaped."""
+    from paddle_tpu.utils import flags as fl
+
+    cost, evaluators, optimizer, train_reader, test_reader = \
+        _mode_model(model)
+    trainer = paddle.trainer.SGD(cost, Parameters.create(cost), optimizer,
+                                 extra_layers=evaluators)
+    events, raised = [], None
+
+    def handler(e):
+        events.append(e)
+        ended = sum(isinstance(x, paddle.event.EndIteration) for x in events)
+        if abort_after is not None and ended >= abort_after:
+            raise _Abort()
+
+    fl.set_flag("test_period", TEST_PERIOD)
+    try:
+        trainer.train(reader or train_reader, num_passes=N_PASSES,
+                      event_handler=handler, test_reader=test_reader,
+                      **MODES[mode], **train_kw)
+    except (_Abort, ZeroDivisionError) as exc:
+        raised = exc
+    finally:
+        fl.set_flag("test_period", 0)
+    return events, trainer, raised
+
+
+@functools.lru_cache(maxsize=None)
+def _reference(model):
+    """The default mode's uninterrupted run, made once a model."""
+    events, trainer, _ = _mode_run(model, "default")
+    return (events, trainer._step_count,
+            {n: np.array(trainer.parameters.get(n))
+             for n in trainer.parameters.names()})
+
+
+def _shape(events, keep_begin=True):
+    """The stream's structure: (event type, pass, batch) in order."""
+    return [(type(e).__name__, e.pass_id, getattr(e, "batch_id", None))
+            for e in events
+            if keep_begin or not isinstance(e, paddle.event.BeginIteration)]
+
+
+def _losses(events):
+    return {(e.pass_id, e.batch_id): e.cost for e in events
+            if isinstance(e, paddle.event.EndIteration)}
+
+
+@pytest.mark.parametrize("mode", list(MODES))
+@pytest.mark.parametrize("model", ["mlp", "tagger"])
+def test_every_mode_is_the_same_run(model, mode):
+    """Loss trajectory, final parameters, the ordered event stream with
+    its periodic tests, and the step count do not depend on where the
+    batches come from or on how many steps a dispatch holds."""
+    want_events, want_steps, want_params = _reference(model)
+    events, trainer, raised = _mode_run(model, mode)
+    assert raised is None
+    assert trainer._step_count == want_steps == N_BATCHES * N_PASSES
+    tol = 0 if mode in EXACT else 1e-6
+
+    # trajectory and parameters
+    want, got = _losses(want_events), _losses(events)
+    assert list(got) == list(want)
+    np.testing.assert_allclose(list(got.values()), list(want.values()),
+                               rtol=0, atol=tol)
+    for name, value in want_params.items():
+        np.testing.assert_allclose(np.array(trainer.parameters.get(name)),
+                                   value, rtol=0, atol=10 * tol, err_msg=name)
+
+    # events: everything that fires at finalize is one stream in every
+    # mode, with the periodic test between the step's EndForwardBackward
+    # and its EndIteration, at the same steps
+    assert _shape(events, keep_begin=False) == \
+        _shape(want_events, keep_begin=False)
+    shape = _shape(events)
+    tests = [i for i, item in enumerate(shape) if item[0] == "TestResult"]
+    assert len(tests) == N_BATCHES * N_PASSES // TEST_PERIOD
+    for i in tests:
+        assert shape[i - 1][0] == "EndForwardBackward"
+        assert shape[i + 1] == ("EndIteration",) + shape[i - 1][1:]
+    # a batch announces itself before its own EndForwardBackward, in
+    # order; one-batch units announce batch b+1 before batch b finalizes
+    # (the one-deep pipeline), whatever thread converted it
+    begins = [item for item in shape if item[0] == "BeginIteration"]
+    assert begins == [("BeginIteration", p, b) for p in range(N_PASSES)
+                      for b in range(N_BATCHES)]
+    for p in range(N_PASSES):
+        for b in range(N_BATCHES):
+            assert shape.index(("BeginIteration", p, b)) < \
+                shape.index(("EndForwardBackward", p, b))
+    if mode in EXACT:
+        assert shape == _shape(want_events)
+        # evaluator metrics and the periodic tests' own results, exactly
+        for mine, theirs in zip(events, want_events):
+            if isinstance(mine, (paddle.event.EndIteration,
+                                 paddle.event.TestResult)):
+                assert (mine.cost, mine.metrics) == \
+                    (theirs.cost, theirs.metrics)
+
+
+@pytest.mark.parametrize("resumed", list(MODES))
+@pytest.mark.parametrize("written", list(MODES))
+def test_any_mode_resumes_from_any_modes_cursor(written, resumed, tmp_path):
+    """A checkpoint's cursor counts batches, so a run under any mode
+    continues the trajectory from a cursor any other mode wrote."""
+    want = _losses(_reference("mlp")[0])
+    d = str(tmp_path)
+    part, _, raised = _mode_run("mlp", written, abort_after=5,
+                                checkpoint_dir=d, checkpoint_every=2,
+                                checkpoint_sync=True)
+    assert isinstance(raised, _Abort)
+    rest, trainer, raised = _mode_run("mlp", resumed, checkpoint_dir=d,
+                                      checkpoint_every=2, resume=True,
+                                      checkpoint_sync=True)
+    assert raised is None
+    assert trainer._step_count == N_BATCHES * N_PASSES
+    part, rest = _losses(part), _losses(rest)
+    first = min(rest)
+    assert min(want) < first  # from the cursor, not from scratch
+    for key, cost in {**part, **rest}.items():
+        assert abs(cost - want[key]) <= 1e-6, (key, cost, want[key])
+    # the resumed stream runs from its cursor to the end without a hole,
+    # and what neither run reported was dispatched and checkpointed by the
+    # first one, behind its one-deep pipeline
+    assert list(rest) == [key for key in want if key >= first]
+    assert all(max(part) < key < first
+               for key in set(want) - set(part) - set(rest))
+
+
+@pytest.mark.parametrize("mode,finalized", [
+    ("default", [0, 1]), ("pipelined", [0, 1]), ("k1", [0, 1]),
+    # the reader fails before the first unit of four is whole
+    ("k4", [])])
+def test_a_failing_reader_surfaces_the_same_way(mode, finalized):
+    """The reader raises when asked for batch 3: its exception reaches the
+    caller as itself from every source, the units finalized before it are
+    whole, and the one in flight is dropped, not half reported."""
+    batches = _mnist_batches(3, seed=1)
+
+    def reader():
+        yield from batches
+        1 / 0
+
+    events, trainer, raised = _mode_run("mlp", mode, reader=reader)
+    assert isinstance(raised, ZeroDivisionError)
+    shape = _shape(events)
+    assert [b for kind, _, b in shape if kind == "EndIteration"] == finalized
+    assert [b for kind, _, b in shape
+            if kind == "EndForwardBackward"] == finalized
+    assert not any(kind == "EndPass" for kind, _, _ in shape)
+    # every batch the source handed over was dispatched and counted
+    assert trainer._step_count == (0 if mode == "k4" else 3)
