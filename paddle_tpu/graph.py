@@ -123,6 +123,10 @@ class Context:
         self._rng_counter = itertools.count()
         self.state_updates = {}
         self.aux = {}
+        # layer/decoder.py recompute: the names the block being traced
+        # keeps for backward, and the bytes this trace's blocks keep
+        self.recompute_keeping = frozenset()
+        self.recompute_kept_bytes = 0
         # streaming-decode carry threading (serve/export.py decode step):
         # when ``decode_state`` is a dict, recurrent layers read their
         # initial carry from it (decode_state[layer_name] = [leaf, ...];

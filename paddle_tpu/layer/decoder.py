@@ -15,6 +15,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from paddle_tpu.core.dtype import upcast_f32
 from paddle_tpu.graph import auto_name
@@ -65,16 +66,35 @@ def rms_norm(input, gate=None, eps=1e-5, name=None, param_attr=None,
                      layer_attr=layer_attr)
 
 
-def _gated_mlp(x, w_in, w_out):
+# What a recomputed block may keep for backward (``recompute(keep=...)``):
+# GATED_MLP_PRODUCT names x W_in inside ``gated_mlp``, before the split;
+# _KEPT_OUTPUT the outputs of the inner nodes a block lists.
+GATED_MLP_PRODUCT = "paddle_tpu.gated_mlp.product"
+_KEPT_OUTPUT = "paddle_tpu.block.kept"
+
+
+def _kept(x, name, ctx):
+    """``x`` under ``name`` for the checkpoint policy of the block around
+    it (``jax.ad_checkpoint.checkpoint_name``: the identity outside a
+    checkpoint and under a policy that does not list the name), its bytes
+    added to the trace's count where the block keeps the name."""
+    if name in ctx.recompute_keeping:
+        ctx.recompute_kept_bytes += x.size * x.dtype.itemsize
+    return checkpoint_name(x, name)
+
+
+def _gated_mlp(x, w_in, w_out, ctx):
     with jax.named_scope("paddle_tpu.gated_mlp"):
-        a, b = jnp.split(jnp.matmul(x, w_in), 2, axis=-1)
+        product = _kept(jnp.matmul(x, w_in), GATED_MLP_PRODUCT, ctx)
+        a, b = jnp.split(product, 2, axis=-1)
         return jnp.matmul(jax.nn.silu(a) * b, w_out)
 
 
 @register_layer("gated_mlp")
 def gated_mlp(input, size, name=None, param_attr=None, layer_attr=None):
     """(silu(a) * b) W_out with [a, b] = x W_in: widths d -> 2 * size -> d,
-    no bias."""
+    no bias. The first product carries the name ``GATED_MLP_PRODUCT``, which
+    a ``recompute`` block around the layer may keep."""
     name = name or auto_name("gated_mlp")
     attrs = param_attr if isinstance(param_attr, (list, tuple)) \
         else [param_attr] * 2
@@ -83,7 +103,8 @@ def gated_mlp(input, size, name=None, param_attr=None, layer_attr=None):
 
     def forward(params, values, ctx):
         return featurewise(
-            lambda d: _gated_mlp(d, params[w_in.name], params[w_out.name]),
+            lambda d: _gated_mlp(d, params[w_in.name], params[w_out.name],
+                                 ctx),
             values[0])
 
     return make_node("gated_mlp", forward, [input], name=name,
@@ -247,15 +268,32 @@ def lm_head(input, vocab, param_attr, scale=1.0, name=None, layer_attr=None):
 
 
 @register_layer("recompute")
-def recompute(output, inputs, enabled=True, name=None):
+def recompute(output, inputs, enabled=True, name=None, keep=()):
     """The sub-graph from ``inputs`` to ``output`` as one node, whose
     forward runs under ``jax.checkpoint``: backward keeps the block's
     inputs and computes its inside again, memory for time. The node owns
     the parameters of the layers inside; ``enabled=False`` runs the same
-    node without the checkpoint."""
+    node without the checkpoint.
+
+    ``keep`` lists what backward keeps besides the inputs, so that the
+    second forward need not make it again: a node inside the block (its
+    output) or a name that a layer inside gives one of its values
+    (``GATED_MLP_PRODUCT``). Worth keeping is a value that is dear to make
+    and small to hold, a large product's output; whatever only fed a kept
+    value is then dead in the second forward (the product before a kept
+    sum). With nothing listed the checkpoint has no policy. The bytes kept
+    are added to ``ctx.recompute_kept_bytes``, which ``Topology.apply``
+    sets the gauge ``paddle_tpu_recompute_kept_bytes`` from."""
     inputs = to_list(inputs)
     boundary = {id(n) for n in inputs}
     inside = _between(output, boundary)
+    kept_nodes = {id(k) for k in keep if not isinstance(k, str)}
+    enforce(kept_nodes <= {id(n) for n in inside},
+            "recompute: keep lists a node that is not inside the block")
+    names = frozenset(k for k in keep if isinstance(k, str)) \
+        | ({_KEPT_OUTPUT} if kept_nodes else frozenset())
+    policy = jax.checkpoint_policies.save_only_these_names(*sorted(names)) \
+        if names else None
     specs = {}
     for node in inside:
         enforce(node.layer_type != "data",
@@ -271,15 +309,24 @@ def recompute(output, inputs, enabled=True, name=None):
         def run(block_params, block_inputs):
             seen = {id(n): v for n, v in zip(inputs, block_inputs)}
             for node in inside:
-                seen[id(node)] = node.forward(
+                value = node.forward(
                     block_params, [seen[id(p)] for p in node.inputs], ctx)
+                if id(node) in kept_nodes:
+                    value = featurewise(
+                        lambda d: _kept(d, _KEPT_OUTPUT, ctx), value)
+                seen[id(node)] = value
             return seen[id(output)]
 
         block_params = {k: params[k] for k in specs}
         with jax.named_scope("paddle_tpu.block"):
-            if enabled:
-                run = jax.checkpoint(run)
-            return run(block_params, list(values))
+            if not enabled:
+                return run(block_params, list(values))
+            outer, ctx.recompute_keeping = ctx.recompute_keeping, names
+            try:
+                return jax.checkpoint(run, policy=policy)(
+                    block_params, list(values))
+            finally:
+                ctx.recompute_keeping = outer
 
     return make_node("recompute", forward, inputs,
                      name=name or auto_name("recompute"), size=output.size,

@@ -21,6 +21,7 @@ from paddle_tpu.core import dtype as dtype_mod
 from paddle_tpu.core.sequence import NestedSequenceBatch, SequenceBatch
 from paddle_tpu.data_type import DENSE, INDEX, SEQ_NESTED, SEQ_NONE, SEQ_SINGLE, SPARSE_BINARY, SPARSE_FLOAT
 from paddle_tpu.graph import Context, LayerNode, topo_sort
+from paddle_tpu.observe import metrics as observe_metrics
 from paddle_tpu.observe import spans as observe_spans
 from paddle_tpu.utils import flags
 from paddle_tpu.utils.error import enforce
@@ -158,6 +159,14 @@ class Topology:
         """
         ctx = Context(mode=mode, rng=rng)
         values = self._run_nodes(params, feed, ctx)
+        if mode == "train":
+            # set as the step program is traced: what its recomputed
+            # blocks keep for backward (layer/decoder.py recompute)
+            observe_metrics.get_registry().gauge(
+                "paddle_tpu_recompute_kept_bytes",
+                help="bytes a train step keeps inside recomputed blocks "
+                     "besides their inputs, of the program traced last"
+            ).set(ctx.recompute_kept_bytes)
         wanted = outputs or [o.name for o in self.outputs]
         return {name: _external(values[name]) for name in wanted}, \
             ctx.state_updates

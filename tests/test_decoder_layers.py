@@ -1,7 +1,10 @@
 """`layer/decoder.py` and `ops/attention.py`: each new layer against its
 equation written out, blockwise attention against full scores, causality,
 the padded tail, packed rows refused, `checkgrad` on each layer, and a
-recomputed block against the same block kept. Float32 at `highest`."""
+recomputed block against the same block kept, whole and with the two
+values a block may keep for backward. Float32 at `highest`."""
+
+import re
 
 import jax
 import jax.numpy as jnp
@@ -14,6 +17,8 @@ from paddle_tpu import layer as L
 from paddle_tpu.attr import ParamAttr
 from paddle_tpu.checkgrad import check_layer_grad
 from paddle_tpu.core.sequence import PackedSequenceBatch, SequenceBatch
+from paddle_tpu.layer import decoder
+from paddle_tpu.observe import metrics as observe_metrics
 from paddle_tpu.ops import attention as attention_ops
 from paddle_tpu.topology import Topology
 from paddle_tpu.utils.error import EnforceError
@@ -329,6 +334,124 @@ def test_a_recomputed_block_owns_its_parameters_and_keeps_its_gradients():
     assert float(values[0]) == float(values[1])
     for name in grads[0]:
         np.testing.assert_allclose(grads[0][name], grads[1][name], atol=1e-6)
+
+
+def _layer_block(how):
+    """A decoder layer's shape at width 8: mixer (here two products, in
+    and out) into the residual stream, then the MLP. ``how``: "none" no
+    checkpoint,
+    "full" all of the inside made again, "keep" but the residual after the
+    mixer and the MLP's first product, "unnamed" ``keep=[]`` spelt out."""
+    L.reset_name_counters()
+    x = _input(8)
+    linear = {"act": paddle.activation.Linear(), "bias_attr": False}
+    mixed = L.fc(input=L.fc(input=L.rms_norm(input=x, name="b.norm1"),
+                            size=10, name="b.in", **linear),
+                 size=8, name="b.out", **linear)
+    mid = L.addto(input=[x, L.slope_intercept(input=mixed, slope=0.22)])
+    mlp = L.gated_mlp(input=L.rms_norm(input=mid, name="b.norm2"), size=12,
+                      name="b.mlp")
+    out = L.addto(input=[mid, L.slope_intercept(input=mlp, slope=0.22)])
+    keep = {"keep": [mid, decoder.GATED_MLP_PRODUCT], "unnamed": []}
+    if how in keep:
+        return L.recompute(out, inputs=[x], keep=keep[how], name="b")
+    return L.recompute(out, inputs=[x], enabled=how != "none", name="b")
+
+
+def _block_loss(how, feed):
+    topo = Topology(_layer_block(how))
+    params = topo.init_params(jax.random.PRNGKey(3))
+    return (lambda p: jnp.sum(jnp.sin(
+        topo.apply(p, feed, mode="train")[0]["b"].data))), params
+
+
+def _dots(jaxpr):
+    """The output shape of every dot_general, sub-programs included."""
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            out.append(tuple(eqn.outvars[0].aval.shape))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            out += _dots(sub)
+    return out
+
+
+def _kept_bytes():
+    return observe_metrics.get_registry().snapshot()["gauges"][
+        "paddle_tpu_recompute_kept_bytes"]
+
+
+@pytest.mark.parametrize("other", ["full", "none"])
+def test_a_block_that_keeps_two_values_keeps_its_loss_and_gradients(other):
+    feed = {"x": _seq(15)}
+    out = []
+    for how in ("keep", other):
+        loss, params = _block_loss(how, feed)
+        out.append(jax.value_and_grad(loss)(params))
+    (loss_a, grads_a), (loss_b, grads_b) = out
+    assert float(loss_a) == float(loss_b)
+    assert sorted(grads_a) == sorted(grads_b) and len(grads_a) == 6
+    for name in grads_a:
+        np.testing.assert_allclose(grads_a[name], grads_b[name], atol=1e-6)
+
+
+def test_a_kept_value_is_not_made_again_in_backward():
+    """Full recompute makes three of the four products twice (the MLP's
+    second is dead in backward); with the residual after the mixer and the
+    MLP's first product kept, the mixer's second product, which only fed
+    the kept sum, and the MLP's first are made once."""
+    feed = {"x": _seq(15)}
+    dots = {}
+    for how in ("none", "keep", "full"):
+        loss, params = _block_loss(how, feed)
+        dots[how] = _dots(jax.make_jaxpr(jax.grad(loss))(params).jaxpr)
+    count = {how: len(d) for how, d in dots.items()}
+    # forward 4 and backward 8, and of the second forward 0, 1 and 3
+    assert count == {"none": 12, "keep": 13, "full": 15}
+    first_product = (2, 12, 24)     # [rows, time, 2 * size] of b.mlp
+    assert [d.count(first_product) for d in dots.values()] == [1, 1, 2]
+
+
+@pytest.mark.parametrize("how", ["none", "full", "unnamed"])
+def test_a_name_that_no_block_keeps_changes_nothing_in_the_program(
+        how, monkeypatch):
+    """``checkpoint_name`` outside a checkpoint, and inside one whose block
+    lists nothing, lowers to what the layers lowered to before they named
+    anything."""
+    feed = {"x": _seq(15)}
+
+    def lowered(how):
+        loss, params = _block_loss(how, feed)
+        text = jax.jit(jax.grad(loss)).lower(params).as_text()
+        # a private function's number counts the lowerings of the process
+        return re.sub(r"@(\w+?)_\d+\b", r"@\1", text)
+
+    named = lowered(how)
+    # what holds a second forward apart from the first in the lowered text
+    assert ("optimization_barrier" in named) is (how != "none")
+    if how == "unnamed":
+        assert lowered("full") == named
+    monkeypatch.setattr(decoder, "checkpoint_name", lambda x, name: x)
+    assert lowered(how) == named
+
+
+def test_a_block_counts_the_bytes_it_keeps():
+    feed = {"x": _seq(15)}
+    rows, time = feed["x"].data.shape[:2]
+    kept = 8 + 2 * 12      # the residual stream and b.mlp's first product
+    for how, values in (("keep", kept), ("full", 0), ("keep", kept),
+                        ("none", 0)):
+        loss, params = _block_loss(how, feed)
+        jax.eval_shape(jax.grad(loss), params)
+        assert _kept_bytes() == rows * time * values * 4, how
+
+
+def test_a_block_keeps_only_what_is_inside_it():
+    x = _input(8)
+    outside = L.rms_norm(input=x, name="outside")
+    inner = L.rms_norm(input=outside, name="inner")
+    with pytest.raises(EnforceError, match="not inside the block"):
+        L.recompute(inner, inputs=[outside], keep=[x])
 
 
 def test_a_block_refuses_a_data_layer_inside_it():

@@ -1,10 +1,12 @@
 """`models/hybrid_lm.py` at a tiny size with both layer kinds: against the
 plain reference in loss and every gradient leaf, with and without block
-recompute, the tied embedding, the vocabulary slice, the padded tail, and
-through `SGD.train` with its two always-on histograms."""
+recompute and with what a block keeps for backward, the tied embedding,
+the vocabulary slice, the padded tail, and through `SGD.train` with its
+two always-on histograms."""
 
 import json
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -110,6 +112,123 @@ def test_gradients_are_equal_with_and_without_block_recompute(cfg):
         np.testing.assert_allclose(
             grads_a[k], grads_b[k], rtol=1e-4,
             atol=1e-5 * float(jnp.abs(grads_b[k]).max()))
+
+
+def _recompute_everything(monkeypatch):
+    """``hybrid_lm``'s blocks as they were before they kept anything."""
+    block = L.recompute
+    monkeypatch.setattr(L, "recompute",
+                        lambda *a, keep=(), **kw: block(*a, **kw))
+
+
+def test_what_a_block_keeps_changes_no_gradient(cfg, monkeypatch):
+    feed = None
+    out = []
+    for everything in (False, True):
+        if everything:
+            _recompute_everything(monkeypatch)
+        cost, topo, params, _, _ = _program(cfg)
+        feed = feed or convert_feed(topo, _batch(cfg))
+        out.append(_loss_and_grads(topo, cost, params, feed))
+    (loss_a, grads_a), (loss_b, grads_b) = out
+    assert float(loss_a) == float(loss_b)
+    for k in grads_a:
+        np.testing.assert_allclose(
+            grads_a[k], grads_b[k], rtol=1e-5,
+            atol=1e-6 * float(jnp.abs(grads_b[k]).max()))
+
+
+def _dots(jaxpr):
+    """The output shape of every dot_general, sub-programs included."""
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            out.append(tuple(eqn.outvars[0].aval.shape))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            out += _dots(sub)
+    return out
+
+
+def test_backward_makes_neither_kept_product_again(cfg, monkeypatch):
+    """By layer: full recompute makes the MLP's first product, the mixer's
+    output projection and the mixer's inside twice; a block that keeps the
+    first product and the residual after the mixer makes only the mixer's
+    inside twice; no recompute makes nothing twice."""
+    batch = _batch(cfg)
+    dots = {}
+    for how in ("none", "keep", "full"):
+        if how == "full":
+            _recompute_everything(monkeypatch)
+        cost, topo, params, _, _ = _program(cfg, recompute=how != "none")
+        feed = convert_feed(topo, batch)
+        dots[how] = _dots(jax.make_jaxpr(jax.grad(lambda p: jnp.mean(
+            topo.apply(p, feed, mode="train")[0][cost.name])))(params).jaxpr)
+    assert len(dots["none"]) < len(dots["keep"]) < len(dots["full"])
+    layers = cfg["num_hidden_layers"]
+    rows, time = feed["tokens"].data.shape
+    first_product = (rows, time, 2 * cfg["shared_intermediate_size"])
+    assert [d.count(first_product) for d in dots.values()] \
+        == [layers, layers, 2 * layers]
+    # the two a layer no longer makes again: the first product and the
+    # mixer's output projection (out_proj, or the attention layer's o)
+    assert len(dots["full"]) - len(dots["keep"]) == 2 * layers
+
+
+def test_without_recompute_the_program_is_what_it_was(cfg, monkeypatch):
+    """Outside a checkpoint a name is the identity: ``recompute=False``
+    lowers to the text it lowered to before the layers named anything."""
+    from paddle_tpu.layer import decoder
+
+    batch = _batch(cfg)
+
+    def lowered():
+        cost, topo, params, _, _ = _program(cfg, recompute=False)
+        feed = convert_feed(topo, batch)
+        text = jax.jit(jax.grad(lambda p: jnp.mean(
+            topo.apply(p, feed, mode="train")[0][cost.name]))
+        ).lower(params).as_text()
+        # a private function's number counts the lowerings of the process
+        return re.sub(r"@(\w+?)_\d+\b", r"@\1", text)
+
+    named = lowered()
+    monkeypatch.setattr(decoder, "checkpoint_name", lambda x, name: x)
+    assert lowered() == named
+
+
+def _kept_bytes():
+    return observe_metrics.get_registry().snapshot()["gauges"][
+        "paddle_tpu_recompute_kept_bytes"]
+
+
+def test_the_gauge_reads_what_the_traced_step_keeps(cfg):
+    """layers x positions x (the MLP's first product + the residual
+    stream) x the bytes of a value; 0 without recompute and for a model
+    with no recomputed block (the ResNet preset)."""
+    from chipbench.models import resnet50 as bench_resnet
+
+    def trace(cost, topo, params, feed):
+        jax.eval_shape(jax.grad(lambda p: jnp.mean(
+            topo.apply(p, feed, mode="train")[0][cost.name])), params)
+
+    program = _program(cfg)[:3]
+    feed = convert_feed(program[1], _batch(cfg))
+    rows, time = feed["tokens"].data.shape
+    kept = cfg["num_hidden_layers"] * rows * time * (
+        2 * cfg["shared_intermediate_size"] + cfg["hidden_size"]) * 4
+    trace(*program, feed)
+    assert _kept_bytes() == kept
+    trace(*_program(cfg, recompute=False)[:3], feed)
+    assert _kept_bytes() == 0
+
+    trace(*program, feed)
+    assert _kept_bytes() == kept
+    resnet = _load("configs", "resnet50")
+    cost = bench_resnet.build(resnet)
+    topo = Topology(cost)
+    trace(cost, topo, topo.init_params(jax.random.PRNGKey(0)),
+          {"image": jnp.zeros((2, 3 * resnet["im_size"] ** 2)),
+           "label": jnp.zeros((2,), jnp.int32)})
+    assert _kept_bytes() == 0
 
 
 def test_the_tied_embedding_is_one_parameter_with_both_uses_gradients(cfg):
@@ -246,6 +365,9 @@ def test_it_trains_through_sgd_train_and_counts_its_tokens(cfg,
     assert [e["count"] - s["count"] for s, e in zip(start, end)] == [3, 3]
     assert tokens == 3 * sum(rows)
     assert positions == 3 * len(rows) * padded
+    # set as the trainer's step was traced: what its ten blocks keep
+    assert _kept_bytes() == cfg["num_hidden_layers"] * len(rows) * padded \
+        * (2 * cfg["shared_intermediate_size"] + cfg["hidden_size"]) * 4
 
 
 def test_a_steps_tokens_are_its_widest_sequence_slots():
