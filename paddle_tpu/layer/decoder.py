@@ -1,14 +1,15 @@
 """Layers of today's decoder-only language models: RMSNorm (plain and
-gated), the gated MLP, the Mamba-2 mixer, grouped-query causal attention
-without positions, the head that is the embedding's transpose, and a
-block that is recomputed in backward (the token-level cost over the head's
-logits is ``layer/cost.py lm_cost``).
+gated), the gated MLP, the Mamba-2 mixer, the Gated DeltaNet
+linear-attention mixer, grouped-query causal attention without positions
+(with or without normalised queries and keys), the head over a
+vocabulary table, and a block that is recomputed in backward (the
+token-level cost over the head's logits is ``layer/cost.py lm_cost``).
 
-All take and give ``SequenceBatch`` values [B, T, width]. The two layers
-that mix across time (``mamba2``, ``gqa_attention``) refuse packed rows:
-their state, convolution taps and attention do not yet reset at segment
-starts. Every piece of device work runs under a ``jax.named_scope`` of its
-own (docs/observability.md "Decoder scopes").
+All take and give ``SequenceBatch`` values [B, T, width]. The three layers
+that mix across time (``mamba2``, ``gated_delta_net``, ``gqa_attention``)
+refuse packed rows: their state, convolution taps and attention do not yet
+reset at segment starts. Every piece of device work runs under a
+``jax.named_scope`` of its own (docs/observability.md "Decoder scopes").
 """
 
 import math
@@ -24,20 +25,36 @@ from paddle_tpu.layer.base import (data_of, featurewise, is_seq, like,
                                    make_node, register_layer, reject_packed,
                                    to_list, weight_spec)
 from paddle_tpu.ops import attention as attention_ops
+from paddle_tpu.ops import delta_rule as delta_ops
 from paddle_tpu.ops import ssm as ssm_ops
 from paddle_tpu.utils.error import enforce
 
 
-def _rms_normalize(x, weight, eps, gate=None):
+def _rms_normalize(x, weight, eps, gate=None, gate_first=True):
     """x * rsqrt(mean(x^2) + eps) * weight over the last axis, in float32
-    at least; with ``gate``, of x * silu(gate)."""
+    at least. With ``gate``: of x * silu(gate) (Mamba-2's order), or with
+    ``gate_first=False`` the norm of x, then times silu(gate) (Gated
+    DeltaNet's)."""
     with jax.named_scope("paddle_tpu.rmsnorm"):
         wide = upcast_f32(x)
-        if gate is not None:
+        if gate is not None and gate_first:
             wide = wide * jax.nn.silu(upcast_f32(gate))
         scale = jax.lax.rsqrt(jnp.mean(wide * wide, axis=-1, keepdims=True)
                               + eps)
-        return (wide * scale * upcast_f32(weight)).astype(x.dtype)
+        out = wide * scale * upcast_f32(weight)
+        if gate is not None and not gate_first:
+            out = out * jax.nn.silu(upcast_f32(gate))
+        return out.astype(x.dtype)
+
+
+def _l2_normalize(x, scale=1.0, eps=1e-6):
+    """scale * x / sqrt(sum(x^2) + eps) over the last axis, in float32 at
+    least (the eps keeps an all-zero padded position finite)."""
+    with jax.named_scope("paddle_tpu.l2norm"):
+        wide = upcast_f32(x)
+        inverse = jax.lax.rsqrt(jnp.sum(wide * wide, axis=-1, keepdims=True)
+                                + eps)
+        return (wide * (inverse * scale)).astype(x.dtype)
 
 
 def _ones_spec(name, shape, param_attr):
@@ -203,14 +220,97 @@ def mamba2(input, heads, head_dim, state, conv_width=4, groups=1, chunk=256,
                      param_specs=list(specs.values()), layer_attr=layer_attr)
 
 
+class _LogUniform:
+    """A_log = log(uniform(0, high)), as the published layer starts it."""
+
+    def __init__(self, high=16.0):
+        self.high = high
+
+    def __call__(self, rng, shape, dtype):
+        return jnp.log(self.high * jax.random.uniform(rng, shape, dtype))
+
+
+@register_layer("gated_delta_net")
+def gated_delta_net(input, heads, key_dim, value_dim, conv_width=4,
+                    neg_eigval=True, chunk=64, eps=1e-6, initial_std=0.02,
+                    name=None, layer_attr=None):
+    """The Gated DeltaNet mixer (Yang, Kautz & Hatamizadeh,
+    arXiv:2412.06464), ``heads`` heads of ``key_dim`` / ``value_dim``:
+        q~, k~, v = silu(conv1d_causal([u W_q, u W_k, u W_v]))   depthwise, no bias
+        q_t = q~_t / |q~_t| * key_dim^-1/2;  k_t = k~_t / |k~_t|   per head
+        beta_t = sigmoid(u_t W_b), doubled with ``neg_eigval``
+        alpha_t = exp(-exp(A_log) * softplus(u_t W_a + dt_bias))
+        S_t = alpha_t (I - beta_t k_t k_t^T) S_{t-1} + beta_t k_t v_t^T;  o_t = S_t^T q_t
+        out = (RMSNorm(o_t) * silu(u_t W_g)) W_o               norm and gate per head
+    the rule in chunks of ``chunk`` (``ops/delta_rule.py``). Parameters
+    ``<name>.q``, ``.k``, ``.v``, ``.g``, ``.a``, ``.b``, ``.conv_w``
+    [C, K] over q, k, v side by side, ``.A_log``, ``.dt_bias``, ``.norm_w``
+    [value_dim], ``.o``; no bias anywhere."""
+    name = name or auto_name("gated_delta_net")
+    d = input.size
+    key_inner, value_inner = heads * key_dim, heads * value_dim
+    specs = {
+        "q": _named_spec(name, "q", (d, key_inner), std=initial_std),
+        "k": _named_spec(name, "k", (d, key_inner), std=initial_std),
+        "v": _named_spec(name, "v", (d, value_inner), std=initial_std),
+        "g": _named_spec(name, "g", (d, value_inner), std=initial_std),
+        "a": _named_spec(name, "a", (d, heads), std=initial_std),
+        "b": _named_spec(name, "b", (d, heads), std=initial_std),
+        # as torch.nn.Conv1d starts a depthwise filter
+        "conv_w": _named_spec(name, "conv_w",
+                              (2 * key_inner + value_inner, conv_width),
+                              Uniform(-conv_width ** -0.5,
+                                      conv_width ** -0.5)),
+        "A_log": _named_spec(name, "A_log", (heads,), _LogUniform()),
+        "dt_bias": _named_spec(name, "dt_bias", (heads,),
+                               _InverseSoftplusOfLogUniform()),
+        "norm_w": _named_spec(name, "norm_w", (value_dim,), Constant(1.0)),
+        "o": _named_spec(name, "o", (value_inner, d), std=initial_std),
+    }
+
+    def forward(params, values, ctx):
+        seq = values[0]
+        reject_packed(seq, "gated_delta_net")
+        enforce(is_seq(seq), "gated_delta_net needs a sequence input")
+        p = {k: params[s.name] for k, s in specs.items()}
+        u = seq.data
+        b, t = u.shape[:2]
+        with jax.named_scope("paddle_tpu.gated_delta_net"):
+            qkv = jnp.concatenate(
+                [jnp.matmul(u, p[n]) for n in ("q", "k", "v")], axis=-1)
+            qkv = jax.nn.silu(ssm_ops.causal_conv1d(
+                qkv, p["conv_w"], None, seq.lengths))
+            q, k, v = (x.reshape(b, t, heads, -1) for x in jnp.split(
+                qkv, [key_inner, 2 * key_inner], axis=-1))
+            log_alpha = -jnp.exp(upcast_f32(p["A_log"])) * jax.nn.softplus(
+                upcast_f32(jnp.matmul(u, p["a"])) + upcast_f32(p["dt_bias"]))
+            beta = jax.nn.sigmoid(upcast_f32(jnp.matmul(u, p["b"]))) \
+                * (2.0 if neg_eigval else 1.0)
+            o, _ = delta_ops.gated_delta_rule(
+                _l2_normalize(q, key_dim ** -0.5), _l2_normalize(k), v,
+                log_alpha, beta, chunk, seq.lengths)
+            y = _rms_normalize(
+                o, p["norm_w"], eps, gate_first=False,
+                gate=jnp.matmul(u, p["g"]).reshape(b, t, heads, value_dim))
+            return like(seq, jnp.matmul(y.reshape(b, t, value_inner),
+                                        p["o"]))
+
+    return make_node("gated_delta_net", forward, [input], name=name, size=d,
+                     param_specs=list(specs.values()), layer_attr=layer_attr)
+
+
 @register_layer("gqa_attention")
 def gqa_attention(input, heads, kv_heads, head_dim, scale=None, block=512,
-                  initial_std=0.02, name=None, layer_attr=None):
+                  initial_std=0.02, name=None, layer_attr=None,
+                  qk_norm=False, eps=1e-5):
     """Causal self-attention with ``heads`` query heads over ``kv_heads``
     shared key-value heads, no positional encoding and no bias; scores are
     multiplied by ``scale`` (1 / sqrt(head_dim) by default). Blockwise
-    (``ops/attention.py``): no [T, T] score matrix is held. Parameters
-    ``<name>.q``, ``.k``, ``.v``, ``.o``."""
+    (``ops/attention.py``): no [T, T] score matrix is held. With
+    ``qk_norm`` queries and keys are RMS-normalised with a learned scale,
+    each over its whole projection, before the split into heads (the
+    OLMo 2 layout). Parameters ``<name>.q``, ``.k``, ``.v``, ``.o``, and
+    ``.q_norm``, ``.k_norm`` with ``qk_norm``."""
     name = name or auto_name("gqa_attention")
     d = input.size
     enforce(heads % kv_heads == 0, "kv_heads %d must divide heads %d",
@@ -224,6 +324,11 @@ def gqa_attention(input, heads, kv_heads, head_dim, scale=None, block=512,
                          std=initial_std),
         "o": _named_spec(name, "o", (heads * head_dim, d), std=initial_std),
     }
+    if qk_norm:
+        specs["q_norm"] = _named_spec(name, "q_norm", (heads * head_dim,),
+                                      Constant(1.0))
+        specs["k_norm"] = _named_spec(name, "k_norm", (kv_heads * head_dim,),
+                                      Constant(1.0))
 
     def forward(params, values, ctx):
         seq = values[0]
@@ -231,10 +336,18 @@ def gqa_attention(input, heads, kv_heads, head_dim, scale=None, block=512,
         enforce(is_seq(seq), "gqa_attention needs a sequence input")
         u = seq.data
         b, t = u.shape[:2]
+
+        def projected(n, h):
+            x = jnp.matmul(u, params[specs[n].name])
+            if qk_norm and n != "v":
+                with jax.named_scope("paddle_tpu.qk_norm"):
+                    x = _rms_normalize(x, params[specs[n + "_norm"].name],
+                                       eps)
+            return x.reshape(b, t, h, head_dim)
+
         with jax.named_scope("paddle_tpu.gqa_attention"):
-            q, k, v = (jnp.matmul(u, params[specs[n].name]).reshape(
-                b, t, h, head_dim)
-                for n, h in (("q", heads), ("k", kv_heads), ("v", kv_heads)))
+            q, k, v = (projected(n, h) for n, h in (
+                ("q", heads), ("k", kv_heads), ("v", kv_heads)))
             y = attention_ops.blockwise_attention(
                 q, k, v, scale, True, seq.lengths, block)
             return like(seq, jnp.matmul(y.reshape(b, t, heads * head_dim),
@@ -249,7 +362,8 @@ def lm_head(input, vocab, param_attr, scale=1.0, name=None, layer_attr=None):
     """Logits ``scale * h E^T`` over a [vocab, width] table, in float32
     whatever the compute dtype. Name the table as the embedding's
     (``param_attr=ParamAttr(name=...)``) and the two are one parameter,
-    whose gradient is the sum of its two uses."""
+    whose gradient is the sum of its two uses; under another name, or
+    none, the head has a table of its own."""
     name = name or auto_name("lm_head")
     spec = weight_spec(name, 0, (vocab, input.size), param_attr)
 

@@ -231,13 +231,18 @@ class SGD:
                                                self._param_meta)
         # update hooks prune the initial values too (reference:
         # StaticPruningHook masks at init, not just per update)
+        hooked = False
         for n, attr in self._param_meta.items():
             for hook in getattr(attr, "update_hooks", None) or ():
                 if n in self._trainable:
                     self._trainable[n] = hook.apply(n, self._trainable[n])
-        if self._replica is not None:
+                    hooked = True
+        if self._replica is not None and hooked:
             # hooks mutated the masters above; the replica must mirror the
-            # POST-hook weights or step 1 trains on unpruned values
+            # POST-hook weights or step 1 trains on unpruned values. The
+            # old one goes first: two alive were 2 bytes a parameter of
+            # peak_bytes_in_use that no step needs
+            self._replica = None
             self._replica = _make_replica(self._trainable)
         self._rng = jax.random.PRNGKey(flags.get_flag("seed") or 0)
         self._step_count = 0

@@ -1,5 +1,6 @@
 """`layer/decoder.py` and `ops/attention.py`: each new layer against its
-equation written out, blockwise attention against full scores, causality,
+equation written out (the Gated DeltaNet mixer and normalised queries
+and keys among them), blockwise attention against full scores, causality,
 the padded tail, packed rows refused, `checkgrad` on each layer, and a
 recomputed block against the same block kept, whole and with the two
 values a block may keep for backward. Float32 at `highest`."""
@@ -158,6 +159,11 @@ def _mixers():
         "gqa_attention": lambda x: L.gqa_attention(
             input=x, heads=4, kv_heads=2, head_dim=4, scale=0.25, block=4,
             name="mix"),
+        "gqa_attention_qk_norm": lambda x: L.gqa_attention(
+            input=x, heads=4, kv_heads=2, head_dim=4, block=4, qk_norm=True,
+            name="mix"),
+        "gated_delta_net": lambda x: L.gated_delta_net(
+            input=x, heads=2, key_dim=4, value_dim=6, chunk=4, name="mix"),
     }
 
 
@@ -225,6 +231,86 @@ def test_the_mamba2_mixer_is_its_equations_token_by_token():
                                atol=2e-5)
 
 
+def test_the_gated_delta_net_mixer_is_its_equations_token_by_token():
+    heads, dk, dv, taps = 2, 4, 6, 4
+    x = _seq(12, t=11, lengths=(11, 8))
+    node = L.gated_delta_net(input=_input(8), heads=heads, key_dim=dk,
+                             value_dim=dv, chunk=4, eps=1e-6,
+                             initial_std=0.5, name="mix")
+    out, p = _apply(node, {"x": x})
+    p = {k.split(".", 1)[1]: np.asarray(v, np.float64) for k, v in p.items()}
+    assert sorted(p) == ["A_log", "a", "b", "conv_w", "dt_bias", "g", "k",
+                         "norm_w", "o", "q", "v"]
+    p["norm_w"] = np.linspace(0.5, 1.5, dv)
+    out = Topology(node).apply(
+        {"mix." + k: jnp.asarray(v, jnp.float32) for k, v in p.items()},
+        {"x": x}, mode="test")[0]["mix"]
+    want = np.zeros((2, 11, 8))
+    for row, length in enumerate((11, 8)):
+        u = np.asarray(x.data[row, :length], np.float64)
+        qkv = np.concatenate([u @ p["q"], u @ p["k"], u @ p["v"]], axis=-1)
+        padded = np.concatenate([np.zeros((taps - 1, qkv.shape[1])), qkv])
+        qkv = silu(sum(padded[k:k + length] * p["conv_w"][:, k]
+                       for k in range(taps)))
+        q, k, v = np.split(qkv, [heads * dk, 2 * heads * dk], axis=-1)
+        beta = 2.0 / (1.0 + np.exp(-(u @ p["b"])))
+        alpha = np.exp(-np.exp(p["A_log"])
+                       * np.log1p(np.exp(u @ p["a"] + p["dt_bias"])))
+        gate = silu(u @ p["g"])
+        y = np.zeros((length, heads, dv))
+        for h in range(heads):
+            state = np.zeros((dk, dv))
+            for t in range(length):
+                q_t, k_t = (z[t, h * dk:(h + 1) * dk] for z in (q, k))
+                q_t = q_t / np.sqrt(q_t @ q_t + 1e-6) * dk ** -0.5
+                k_t = k_t / np.sqrt(k_t @ k_t + 1e-6)
+                v_t = v[t, h * dv:(h + 1) * dv]
+                state = alpha[t, h] * (
+                    state - beta[t, h] * np.outer(k_t, k_t @ state)) \
+                    + beta[t, h] * np.outer(k_t, v_t)
+                o_t = state.T @ q_t
+                y[t, h] = o_t / np.sqrt((o_t * o_t).mean() + 1e-6) \
+                    * p["norm_w"] * gate[t, h * dv:(h + 1) * dv]
+        want[row, :length] = y.reshape(length, heads * dv) @ p["o"]
+    valid = np.arange(11)[None, :] < np.asarray([11, 8])[:, None]
+    np.testing.assert_allclose(np.where(valid[..., None], out.data, 0), want,
+                               atol=2e-5)
+
+
+def test_normalised_queries_and_keys_are_normalised_before_the_split():
+    """q and k are RMS-normalised over all heads at once, each with its
+    own learned scale; with scales of one and a single head that is
+    attention over unit-RMS queries and keys."""
+    x = _seq(13, t=10, lengths=(10, 7))
+    node = L.gqa_attention(input=_input(8), heads=2, kv_heads=1, head_dim=4,
+                           block=4, qk_norm=True, eps=1e-6, initial_std=0.5,
+                           name="mix")
+    out, p = _apply(node, {"x": x})
+    assert p["mix.q_norm"].shape == (8,) and p["mix.k_norm"].shape == (4,)
+    p["mix.q_norm"] = jnp.linspace(0.5, 1.5, 8)
+    p["mix.k_norm"] = jnp.linspace(1.5, 0.5, 4)
+    out = Topology(node).apply(p, {"x": x}, mode="test")[0]["mix"]
+
+    def rms(z, w):
+        return z / jnp.sqrt(jnp.mean(z * z, -1, keepdims=True) + 1e-6) * w
+
+    q = rms(x.data @ p["mix.q"], p["mix.q_norm"]).reshape(2, 10, 2, 4)
+    k = rms(x.data @ p["mix.k"], p["mix.k_norm"]).reshape(2, 10, 1, 4)
+    v = (x.data @ p["mix.v"]).reshape(2, 10, 1, 4)
+    want = full_scores(q, k, v, 0.5, True, x.lengths).reshape(2, 10, 8) \
+        @ p["mix.o"]
+    valid = (jnp.arange(10)[None, :] < x.lengths[:, None])[..., None]
+    np.testing.assert_allclose(jnp.where(valid, out.data, 0),
+                               jnp.where(valid, want, 0), atol=2e-5)
+
+
+def test_attention_without_the_norm_has_no_norm_parameters():
+    node = L.gqa_attention(input=_input(8), heads=2, kv_heads=1, head_dim=4,
+                           name="mix")
+    assert sorted(s.name for s in node.param_specs) == [
+        "mix.k", "mix.o", "mix.q", "mix.v"]
+
+
 def _checkgrad_nodes():
     x = lambda: _input(6)
     return {
@@ -236,6 +322,12 @@ def _checkgrad_nodes():
                                    chunk=4, initial_std=0.5),
         "gqa_attention": lambda: L.gqa_attention(
             input=x(), heads=4, kv_heads=2, head_dim=3, block=4,
+            initial_std=0.5),
+        "gqa_attention_qk_norm": lambda: L.gqa_attention(
+            input=x(), heads=4, kv_heads=2, head_dim=3, block=4,
+            qk_norm=True, initial_std=0.5),
+        "gated_delta_net": lambda: L.gated_delta_net(
+            input=x(), heads=2, key_dim=3, value_dim=4, chunk=4,
             initial_std=0.5),
         "lm_head": lambda: L.lm_head(
             input=x(), vocab=7, scale=0.5,
@@ -467,6 +559,7 @@ def test_the_analyzer_finds_the_time_mixing_layers_guarded():
     scan_layer_modules.cache_clear()
     coverage = verify_reject_packed_coverage()
     assert coverage["missing"] == [] and coverage["extra"] == []
-    assert {"mamba2", "gqa_attention"} <= set(coverage["expected"])
+    assert {"mamba2", "gqa_attention", "gated_delta_net"} \
+        <= set(coverage["expected"])
     assert not {"rms_norm", "gated_mlp", "lm_head", "lm_cost",
                 "recompute"} & set(coverage["expected"])

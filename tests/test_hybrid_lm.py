@@ -2,7 +2,9 @@
 plain reference in loss and every gradient leaf, with and without block
 recompute and with what a block keeps for backward, the tied embedding,
 the vocabulary slice, the padded tail, and through `SGD.train` with its
-two always-on histograms."""
+two always-on histograms. Then the `olmo_hybrid` layout at a tiny size:
+its layer kinds, norms after the branches, the untied head, in how many
+layers a block keeps its two values, and what `from_config` refuses."""
 
 import json
 import os
@@ -315,7 +317,11 @@ def test_nothing_leaks_back_in_time_through_the_whole_model(cfg):
 
 def test_experts_are_refused():
     with pytest.raises(EnforceError, match="expert"):
-        hybrid_lm.from_config({"num_local_experts": 8})
+        hybrid_lm.from_config({"model_type": "granitemoehybrid",
+                               "num_local_experts": 8, "vocab_size": 8,
+                               "hidden_size": 8, "layer_types": [],
+                               "num_hidden_layers": 0,
+                               "rms_norm_eps": 1e-5})
 
 
 def _histograms():
@@ -379,3 +385,142 @@ def test_a_steps_tokens_are_its_widest_sequence_slots():
     assert data_feeder.step_tokens({"a": narrow, "b": seq}) == (8, 16)
     assert data_feeder.step_tokens({"image": jnp.zeros((2, 3))}) == (None,
                                                                      None)
+
+
+# -- the olmo_hybrid layout ---------------------------------------------------
+
+OLMO_CELL = "olmo-hybrid-7b-seq4096-bs2-train"
+
+
+@pytest.fixture()
+def olmo():
+    return _load("configs", "olmo-hybrid-7b")
+
+
+def _olmo_program(cfg, seed=3, **kw):
+    from chipbench.models import olmo_hybrid as olmo_model
+    from chipbench.reference import olmo_hybrid as olmo_ref
+
+    L.reset_name_counters()
+    cost = hybrid_lm.from_config(cfg, **kw)[3]
+    names = olmo_model.program_names(cfg)
+    weights, _ = olmo_ref.init_weights(seed, cfg)
+    return cost, Topology(cost), {names[k]: v for k, v in weights.items()}
+
+
+def _olmo_feed(cfg, topo, seed=3):
+    return convert_feed(topo, traffic.make_pool(
+        cfg["inputs"], _load("workloads", OLMO_CELL), seed)[0])
+
+
+def test_the_olmo_preset_is_built_from_the_table_of_mixers(olmo):
+    kinds = olmo["layer_types"][:olmo["num_hidden_layers"]]
+    assert kinds == ["linear_attention"] * 3 + ["full_attention"]
+    assert set(hybrid_lm.MIXERS) == {"mamba", "attention",
+                                     "linear_attention", "full_attention"}
+    cost, topo, params = _olmo_program(olmo)
+    assert set(topo.param_specs()) == set(params)
+    types = [n.layer_type for n in topo.nodes]
+    assert types.count("recompute") == len(kinds)
+    # an untied head: a table of its own beside the embedding's
+    assert {"lm.emb", "lm.head.w0"} <= set(params)
+    assert params["lm.l3.mixer.q_norm"].shape == (olmo["hidden_size"],)
+    assert "lm.l0.mixer.A_log" in params and "lm.l3.mixer.A_log" not in params
+    # no multiplier is 1's product: the stream is not scaled anywhere
+    assert "slope_intercept" not in types
+
+
+def test_the_olmo_norms_sit_after_the_branches(olmo):
+    """With every norm's scale at 0 each branch adds nothing, whatever
+    the mixers and MLPs give: the logits are those of the embedding
+    alone."""
+    L.reset_name_counters()
+    logits = hybrid_lm.from_config(olmo)[2]
+    topo = Topology(logits)
+    _, _, params = _olmo_program(olmo)
+    feed = _olmo_feed(olmo, topo)
+    muted = {k: jnp.zeros_like(v) if ".norm1." in k or ".norm2." in k else v
+             for k, v in params.items()}
+    out = topo.apply(muted, feed, mode="test")[0][logits.name].data
+    h = params["lm.emb"][feed["tokens"].data]
+    h = h * jax.lax.rsqrt(jnp.mean(h * h, -1, keepdims=True)
+                          + olmo["rms_norm_eps"])
+    np.testing.assert_allclose(out, h @ params["lm.head.w0"].T, atol=1e-5)
+    assert float(jnp.abs(
+        topo.apply(params, feed, mode="test")[0][logits.name].data
+        - out).max()) > 1e-3
+
+
+@pytest.mark.parametrize("keep_layers", [None, 0, 3])
+def test_in_how_many_layers_a_block_keeps_changes_no_gradient(olmo,
+                                                              keep_layers):
+    cost, topo, params = _olmo_program(olmo, recompute=False)
+    feed = _olmo_feed(olmo, topo)
+    want_loss, want = _loss_and_grads(topo, cost, params, feed)
+    cost, topo, params = _olmo_program(olmo, keep_layers=keep_layers)
+    loss, grads = _loss_and_grads(topo, cost, params, feed)
+    assert float(loss) == pytest.approx(float(want_loss), rel=1e-6)
+    for k in want:
+        np.testing.assert_allclose(
+            grads[k], want[k], rtol=1e-4,
+            atol=1e-5 * float(jnp.abs(want[k]).max()))
+    # the last keep_layers blocks keep the MLP's first product and the
+    # residual after the mixer; the gauge counts their bytes
+    rows, time = feed["tokens"].data.shape
+    layers = olmo["num_hidden_layers"]
+    kept = (layers if keep_layers is None else keep_layers) * rows * time \
+        * (2 * olmo["intermediate_size"] + olmo["hidden_size"]) * 4
+    assert _kept_bytes() == kept
+    blocks = [n for n in topo.nodes if n.layer_type == "recompute"]
+    assert [n.name for n in blocks] == ["lm.l%d.block" % i
+                                        for i in range(layers)]
+
+
+@pytest.mark.parametrize("change,match", [
+    ({"model_type": "llama"}, "model_type"),
+    ({"rope_parameters": {"rope_theta": 500000.0}}, "rotary"),
+    ({"linear_num_key_heads": 1}, "key heads"),
+    ({"layer_types": ["sliding_attention"] * 4}, "sliding_attention"),
+])
+def test_what_the_olmo_config_may_not_say(olmo, change, match):
+    with pytest.raises(EnforceError, match=match):
+        hybrid_lm.from_config({**olmo, **change})
+
+
+def test_a_kind_without_its_options_is_refused():
+    with pytest.raises(EnforceError, match="no mamba options"):
+        hybrid_lm.hybrid_lm(vocab=8, hidden=8, layer_types=["mamba"],
+                            mlp_size=8)
+
+
+def test_the_olmo_model_trains_through_sgd_train(olmo):
+    paddle.init(use_tpu=False, seed=7)
+    L.reset_name_counters()
+    cost = hybrid_lm.from_config(olmo, keep_layers=3)[3]
+    params = paddle.parameters.create(cost)
+    before = {k: np.array(params.get(k)) for k in params.names()}
+    trainer = paddle.trainer.SGD(
+        cost, params, paddle.optimizer.Momentum(learning_rate=0.01,
+                                                momentum=0.9))
+    pool = traffic.make_pool(olmo["inputs"], _load("workloads", OLMO_CELL),
+                             7)
+    costs = []
+    names = ("paddle_tpu_train_step_tokens",
+             "paddle_tpu_train_step_positions")
+    start = [_count(_histograms(), n) for n in names]
+    trainer.train(lambda: iter(pool), feed_pipeline=True,
+                  event_handler=lambda e: costs.append(e.cost) if isinstance(
+                      e, paddle.event.EndIteration) else None)
+    end = [_count(_histograms(), n) for n in names]
+    assert len(costs) == 3 and all(np.isfinite(costs))
+    assert costs[0] == pytest.approx(np.log(olmo["vocab_size"]), rel=0.05)
+    moved = [k for k in before
+             if not np.array_equal(before[k], np.asarray(params.get(k)))]
+    assert {k for k in before if before[k].ndim == 2} <= set(moved)
+    rows = [len(r[0]) for r in pool[0]]
+    padded = convert_feed(trainer.topology, pool[0])["tokens"].data.shape[1]
+    tokens, positions = (e["sum"] - s["sum"] for s, e in zip(start, end))
+    assert tokens == 3 * sum(rows)
+    assert positions == 3 * len(rows) * padded
+    assert _kept_bytes() == 3 * len(rows) * padded * (
+        2 * olmo["intermediate_size"] + olmo["hidden_size"]) * 4

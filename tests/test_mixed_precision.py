@@ -149,3 +149,41 @@ def test_bf16_replica_activation_guard():
         tr32.train(lambda: iter([batch]), num_passes=1)
     finally:
         flags.set_flag("compute_dtype", old or "")
+
+
+@pytest.mark.parametrize("hooked", [False, True])
+def test_set_up_makes_the_replica_once_unless_a_hook_moved_the_masters(
+        hooked, monkeypatch):
+    """Without an update hook the replica made beside the masters is the
+    one the first step reads (a second one, made while the first was
+    alive, was 2 bytes a parameter of peak memory); with a pruning hook it
+    is made again from the pruned masters."""
+    import paddle_tpu as paddle
+    from paddle_tpu import trainer as trainer_mod
+    from paddle_tpu.attr import ParamAttr
+    from paddle_tpu.parameters import Parameters
+
+    made = []
+    make = trainer_mod._make_replica
+    monkeypatch.setattr(trainer_mod, "_make_replica",
+                        lambda t: made.append(1) or make(t))
+    flags.set_flag("compute_dtype", "bfloat16")
+    reset_name_counters()
+    x = paddle.layer.data(name="x", type=paddle.data_type.dense_vector(8))
+    hook = opt.StaticPruningHook(0.5)
+    out = paddle.layer.fc(
+        input=x, size=4, act=paddle.activation.Softmax(), name="fc",
+        param_attr=ParamAttr(update_hooks=[hook]) if hooked else None)
+    lbl = paddle.layer.data(name="label",
+                            type=paddle.data_type.integer_value(4))
+    cost = paddle.layer.classification_cost(input=out, label=lbl)
+    tr = paddle.trainer.SGD(
+        cost, Parameters.create(Topology(cost)),
+        paddle.optimizer.Momentum(learning_rate=0.1, momentum=0.9))
+    assert len(made) == (2 if hooked else 1)
+    for name, master in tr._trainable.items():
+        np.testing.assert_array_equal(
+            np.asarray(tr._replica[name], np.float32),
+            np.asarray(master.astype(jnp.bfloat16), np.float32))
+    if hooked:
+        assert float(jnp.mean(tr._replica["fc.w0"] == 0)) == 0.5
