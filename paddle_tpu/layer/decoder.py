@@ -85,8 +85,10 @@ def rms_norm(input, gate=None, eps=1e-5, name=None, param_attr=None,
 
 # What a recomputed block may keep for backward (``recompute(keep=...)``):
 # GATED_MLP_PRODUCT names x W_in inside ``gated_mlp``, before the split;
+# MAMBA_IN_PRODUCT u W_in inside ``mamba2``, before the split;
 # _KEPT_OUTPUT the outputs of the inner nodes a block lists.
 GATED_MLP_PRODUCT = "paddle_tpu.gated_mlp.product"
+MAMBA_IN_PRODUCT = "paddle_tpu.mamba2.in_product"
 _KEPT_OUTPUT = "paddle_tpu.block.kept"
 
 
@@ -170,7 +172,10 @@ def mamba2(input, heads, head_dim, state, conv_width=4, groups=1, chunk=256,
         out = RMSNorm(y * silu(z)) W_out
     the scan in chunks of ``chunk`` (``ops/ssm.py``). Parameters
     ``<name>.in_proj``, ``.conv_w`` [C, K], ``.conv_b``, ``.A_log``, ``.D``,
-    ``.dt_bias``, ``.norm_w``, ``.out_proj``; no bias on the projections."""
+    ``.dt_bias``, ``.norm_w``, ``.out_proj``; no bias on the projections.
+    The first product, before the split, carries the name
+    ``MAMBA_IN_PRODUCT``, which a ``recompute`` block around the layer may
+    keep."""
     name = name or auto_name("mamba2")
     d = input.size
     inner = heads * head_dim
@@ -201,8 +206,8 @@ def mamba2(input, heads, head_dim, state, conv_width=4, groups=1, chunk=256,
         p = {k: params[s.name] for k, s in specs.items()}
         u = seq.data
         b, t = u.shape[:2]
-        z, xbc, dt = jnp.split(jnp.matmul(u, p["in_proj"]),
-                               [inner, inner + conv_dim], axis=-1)
+        product = _kept(jnp.matmul(u, p["in_proj"]), MAMBA_IN_PRODUCT, ctx)
+        z, xbc, dt = jnp.split(product, [inner, inner + conv_dim], axis=-1)
         xbc = jax.nn.silu(ssm_ops.causal_conv1d(
             xbc, p["conv_w"], p["conv_b"], seq.lengths))
         x, b_mat, c_mat = jnp.split(
@@ -392,12 +397,13 @@ def recompute(output, inputs, enabled=True, name=None, keep=()):
     ``keep`` lists what backward keeps besides the inputs, so that the
     second forward need not make it again: a node inside the block (its
     output) or a name that a layer inside gives one of its values
-    (``GATED_MLP_PRODUCT``). Worth keeping is a value that is dear to make
-    and small to hold, a large product's output; whatever only fed a kept
-    value is then dead in the second forward (the product before a kept
-    sum). With nothing listed the checkpoint has no policy. The bytes kept
-    are added to ``ctx.recompute_kept_bytes``, which ``Topology.apply``
-    sets the gauge ``paddle_tpu_recompute_kept_bytes`` from."""
+    (``GATED_MLP_PRODUCT``, ``MAMBA_IN_PRODUCT``). Worth keeping is a value
+    that is dear to make and small to hold, a large product's output;
+    whatever only fed a kept value is then dead in the second forward (the
+    product before a kept sum). With nothing listed the checkpoint has no
+    policy. The bytes kept are added to ``ctx.recompute_kept_bytes``, which
+    ``Topology.apply`` sets the gauge ``paddle_tpu_recompute_kept_bytes``
+    from."""
     inputs = to_list(inputs)
     boundary = {id(n) for n in inputs}
     inside = _between(output, boundary)

@@ -14,36 +14,42 @@ queries and keys (``olmo_hybrid``: https://huggingface.co/allenai/Olmo-Hybrid-7B
     logits = RMSNorm(h) W^T / logits_scaling          W = E (tied) or the head's own table
 
 Each layer is one ``layer.recompute`` block: backward keeps the layer's
-input and computes its inside again, but for two values that are dear to
+input and computes its inside again, but for the values that are dear to
 make and small to hold. The gated MLP's first product, [B, T, 2 * mlp]:
 the largest product of a layer, 2.05 GFLOP saved per MB kept at Granite
 4.0-H Micro's widths. The residual stream after the mixer,
 [B, T, hidden]: with it kept the mixer's output projection is dead in the
 second forward, 4.1 GFLOP per MB. Together layers x positions x
-(2 * mlp + hidden) values a step. The mixer's input projection is as dear
-per byte as the MLP's product, and is made again for want of room (half
-as many bytes again); so are the scan, the norms and the gates, which
-cost little to make. ``keep_layers`` says in how many of the layers, the
-last ones, a block keeps the two: a choice by what the chip's memory
-leaves, model by model. The last ones, because a step's memory peaks in
-the backward of the first layers, when nearly every gradient is alive and
-what the later layers kept has been used and freed.
+(2 * mlp + hidden) values a step. And what the layer's kind of mixer
+offers, the third field of ``MIXERS``: a Mamba-2 mixer's first product
+(``MAMBA_IN_PRODUCT``, [B, T, 2 * inner + 2 * groups * state + heads],
+before the split into z, xBC and dt), as dear per byte as the MLP's
+product (2.05 GFLOP per MB; at Granite 4.0-H Micro's widths 8,512 values
+a position, 139 MB and 0.286 TFLOP a layer at 8,192 positions); the
+attention and Gated DeltaNet mixers offer nothing. The scan, the
+convolution, the norms and the gates are made again: they cost little to
+make. ``keep_layers`` says in how many of the layers, the last ones, a
+block keeps anything: a choice by what the chip's memory leaves, model by
+model. The last ones, because a step's memory peaks in the backward of the
+first layers, when nearly every gradient is alive and what the later
+layers kept has been used and freed.
 """
 
 from paddle_tpu import data_type
 from paddle_tpu import layer as L
 from paddle_tpu.attr import ParamAttr
-from paddle_tpu.layer.decoder import GATED_MLP_PRODUCT
+from paddle_tpu.layer.decoder import GATED_MLP_PRODUCT, MAMBA_IN_PRODUCT
 from paddle_tpu.utils.error import enforce
 
 # layer kind: (the mixer's layer, which of hybrid_lm's groups of options
-# it takes). "attention" and "full_attention" are one layer under the two
-# model types' names for it.
+# it takes, the names the mixer gives values that a block round it keeps
+# where it keeps at all). "attention" and "full_attention" are one layer
+# under the two model types' names for it.
 MIXERS = {
-    "mamba": (L.mamba2, "mamba"),
-    "attention": (L.gqa_attention, "attention"),
-    "full_attention": (L.gqa_attention, "attention"),
-    "linear_attention": (L.gated_delta_net, "linear_attention"),
+    "mamba": (L.mamba2, "mamba", (MAMBA_IN_PRODUCT,)),
+    "attention": (L.gqa_attention, "attention", ()),
+    "full_attention": (L.gqa_attention, "attention", ()),
+    "linear_attention": (L.gated_delta_net, "linear_attention", ()),
 }
 
 
@@ -66,8 +72,9 @@ def hybrid_lm(vocab, hidden, layer_types, mlp_size, attention=None,
     ``tie_head=False`` gives the head a table of its own, ``<prefix>.head.w0``.
     ``recompute=False`` keeps every layer's inside for backward; otherwise
     a layer keeps its input and, in the last ``keep_layers`` layers (all
-    of them by default), its MLP's first product and the residual stream
-    after its mixer. Returns (tokens, targets, logits, cost)."""
+    of them by default), its MLP's first product, the residual stream
+    after its mixer and what ``MIXERS`` says its kind of mixer offers.
+    Returns (tokens, targets, logits, cost)."""
     enforce(norm in ("before", "after"), "norm is %r, not before or after",
             norm)
     options = {"attention": attention, "mamba": mamba,
@@ -95,7 +102,7 @@ def hybrid_lm(vocab, hidden, layer_types, mlp_size, attention=None,
     for i, kind in enumerate(layer_types):
         enforce(kind in MIXERS, "layer_types[%d] is %r, not one of %s", i,
                 kind, ", ".join(sorted(MIXERS)))
-        mixer, group = MIXERS[kind]
+        mixer, group, mixer_keeps = MIXERS[kind]
         enforce(options[group] is not None,
                 "layer_types[%d] is %r and no %s options are given", i, kind,
                 group)
@@ -109,7 +116,7 @@ def hybrid_lm(vocab, hidden, layer_types, mlp_size, attention=None,
             name + ".norm2")
         h = L.recompute(
             h, inputs=[entry], enabled=recompute, name=name + ".block",
-            keep=[after_mixer, GATED_MLP_PRODUCT]
+            keep=[after_mixer, GATED_MLP_PRODUCT, *mixer_keeps]
             if i >= len(layer_types) - keep_layers else [])
     h = L.rms_norm(input=h, eps=eps, name=prefix + ".final_norm")
     logits = L.lm_head(input=h, vocab=vocab,

@@ -2,8 +2,9 @@
 equation written out (the Gated DeltaNet mixer and normalised queries
 and keys among them), blockwise attention against full scores, causality,
 the padded tail, packed rows refused, `checkgrad` on each layer, and a
-recomputed block against the same block kept, whole and with the two
-values a block may keep for backward. Float32 at `highest`."""
+recomputed block against the same block kept, whole and with the values
+a block may keep for backward (the MLP's first product and the residual
+after the mixer; a Mamba-2 mixer's first product). Float32 at `highest`."""
 
 import re
 
@@ -450,11 +451,18 @@ def _layer_block(how):
     return L.recompute(out, inputs=[x], enabled=how != "none", name="b")
 
 
-def _block_loss(how, feed):
-    topo = Topology(_layer_block(how))
+def _block_loss(how, feed, block=_layer_block):
+    topo = Topology(block(how))
     params = topo.init_params(jax.random.PRNGKey(3))
     return (lambda p: jnp.sum(jnp.sin(
         topo.apply(p, feed, mode="train")[0]["b"].data))), params
+
+
+def _lowered_grad(how, feed, block=_layer_block):
+    loss, params = _block_loss(how, feed, block)
+    text = jax.jit(jax.grad(loss)).lower(params).as_text()
+    # a private function's number counts the lowerings of the process
+    return re.sub(r"@(\w+?)_\d+\b", r"@\1", text)
 
 
 def _dots(jaxpr):
@@ -511,20 +519,13 @@ def test_a_name_that_no_block_keeps_changes_nothing_in_the_program(
     lists nothing, lowers to what the layers lowered to before they named
     anything."""
     feed = {"x": _seq(15)}
-
-    def lowered(how):
-        loss, params = _block_loss(how, feed)
-        text = jax.jit(jax.grad(loss)).lower(params).as_text()
-        # a private function's number counts the lowerings of the process
-        return re.sub(r"@(\w+?)_\d+\b", r"@\1", text)
-
-    named = lowered(how)
+    named = _lowered_grad(how, feed)
     # what holds a second forward apart from the first in the lowered text
     assert ("optimization_barrier" in named) is (how != "none")
     if how == "unnamed":
-        assert lowered("full") == named
+        assert _lowered_grad("full", feed) == named
     monkeypatch.setattr(decoder, "checkpoint_name", lambda x, name: x)
-    assert lowered(how) == named
+    assert _lowered_grad(how, feed) == named
 
 
 def test_a_block_counts_the_bytes_it_keeps():
@@ -534,6 +535,83 @@ def test_a_block_counts_the_bytes_it_keeps():
     for how, values in (("keep", kept), ("full", 0), ("keep", kept),
                         ("none", 0)):
         loss, params = _block_loss(how, feed)
+        jax.eval_shape(jax.grad(loss), params)
+        assert _kept_bytes() == rows * time * values * 4, how
+
+
+def _mamba_block(how):
+    """A Mamba-2 layer's first half at width 8: norm, mixer, residual.
+    ``how``: "bare" no block at all, "none" a block without the
+    checkpoint, "full" all of the inside made again, "keep" but the
+    mixer's first product, "unnamed" ``keep=[]`` spelt out."""
+    L.reset_name_counters()
+    x = _input(8)
+    mixed = L.mamba2(input=L.rms_norm(input=x, name="b.norm"), heads=2,
+                     head_dim=4, state=4, chunk=4, name="b.mixer")
+    out = L.addto(input=[x, L.slope_intercept(input=mixed, slope=0.22)],
+                  name="b")
+    keep = {"keep": [decoder.MAMBA_IN_PRODUCT], "unnamed": []}
+    if how == "bare":
+        return out
+    if how in keep:
+        return L.recompute(out, inputs=[x], keep=keep[how], name="b")
+    return L.recompute(out, inputs=[x], enabled=how != "none", name="b")
+
+
+# [rows, time, z + xBC + dt] of b.mixer: 8 + (8 + 2 * 4) + 2
+_MAMBA_IN_PRODUCT_SHAPE = (2, 12, 26)
+
+
+@pytest.mark.parametrize("other", ["full", "none", "bare"])
+def test_a_mamba_block_that_keeps_its_input_product_keeps_its_gradients(
+        other):
+    feed = {"x": _seq(15)}
+    out = []
+    for how in ("keep", other):
+        loss, params = _block_loss(how, feed, _mamba_block)
+        out.append(jax.value_and_grad(loss)(params))
+    (loss_a, grads_a), (loss_b, grads_b) = out
+    assert float(loss_a) == float(loss_b)
+    assert sorted(grads_a) == sorted(grads_b) and len(grads_a) == 9
+    for name in grads_a:
+        np.testing.assert_allclose(grads_a[name], grads_b[name], atol=1e-6)
+
+
+def test_a_kept_input_product_is_not_made_again_in_backward():
+    """One ``dot_general`` of ``in_proj``'s shape fewer than full
+    recompute, and no other product changes its count."""
+    feed = {"x": _seq(15)}
+    dots = {}
+    for how in ("none", "keep", "full"):
+        loss, params = _block_loss(how, feed, _mamba_block)
+        dots[how] = _dots(jax.make_jaxpr(jax.grad(loss))(params).jaxpr)
+    assert [d.count(_MAMBA_IN_PRODUCT_SHAPE) for d in dots.values()] \
+        == [1, 1, 2]
+    assert len(dots["full"]) - len(dots["keep"]) == 1
+    assert len(dots["none"]) < len(dots["keep"])
+
+
+@pytest.mark.parametrize("how", ["bare", "none", "full", "unnamed"])
+def test_the_mixers_name_changes_nothing_where_no_block_keeps_it(
+        how, monkeypatch):
+    """A bare ``mamba2``, a block without the checkpoint and one that
+    lists nothing lower to what they lowered to before the mixer named
+    its product."""
+    feed = {"x": _seq(15)}
+    named = _lowered_grad(how, feed, _mamba_block)
+    assert ("optimization_barrier" in named) is (how in ("full", "unnamed"))
+    if how == "unnamed":
+        assert _lowered_grad("full", feed, _mamba_block) == named
+    monkeypatch.setattr(decoder, "checkpoint_name", lambda x, name: x)
+    assert _lowered_grad(how, feed, _mamba_block) == named
+
+
+def test_a_mamba_block_counts_the_product_it_keeps():
+    feed = {"x": _seq(15)}
+    rows, time, width = _MAMBA_IN_PRODUCT_SHAPE
+    for how, values in (("keep", width), ("full", 0), ("bare", 0),
+                        ("keep", width), ("none", 0)):
+        loss, params = _block_loss(how, feed, _mamba_block)
         jax.eval_shape(jax.grad(loss), params)
         assert _kept_bytes() == rows * time * values * 4, how
 

@@ -4,7 +4,9 @@ recompute and with what a block keeps for backward, the tied embedding,
 the vocabulary slice, the padded tail, and through `SGD.train` with its
 two always-on histograms. Then the `olmo_hybrid` layout at a tiny size:
 its layer kinds, norms after the branches, the untied head, in how many
-layers a block keeps its two values, and what `from_config` refuses."""
+layers a block keeps its two values, and what `from_config` refuses.
+Last, what a kind of mixer offers the block round it: a Mamba-2 layer's
+first product, and nothing that moves a model without such a layer."""
 
 import json
 import os
@@ -22,6 +24,7 @@ from chipbench.reference import granite_h_micro as ref
 from paddle_tpu import layer as L
 from paddle_tpu.core.sequence import SequenceBatch
 from paddle_tpu.data import feeder as data_feeder
+from paddle_tpu.layer import decoder
 from paddle_tpu.models import hybrid_lm
 from paddle_tpu.observe import metrics as observe_metrics
 from paddle_tpu.topology import Topology, convert_feed
@@ -64,6 +67,12 @@ def _program(cfg, seed=3, recompute=True):
 def _batch(cfg, seed=3):
     return traffic.make_pool(cfg["inputs"], _load("workloads", CELL),
                              seed)[0]
+
+
+def _numbers_off(text):
+    """Lowered text without the numbers of its private functions, which
+    count the lowerings of the process."""
+    return re.sub(r"@(\w+?)_\d+\b", r"@\1", text)
 
 
 def _loss_and_grads(topo, cost, params, feed):
@@ -151,11 +160,33 @@ def _dots(jaxpr):
     return out
 
 
+def _mamba_layers(cfg):
+    return cfg["layer_types"][:cfg["num_hidden_layers"]].count("mamba")
+
+
+def _in_proj_width(cfg):
+    """z, xBC and dt side by side: what ``mamba2``'s first product gives
+    a position."""
+    inner = cfg["mamba_n_heads"] * cfg["mamba_d_head"]
+    return 2 * inner + 2 * cfg["mamba_n_groups"] * cfg["mamba_d_state"] \
+        + cfg["mamba_n_heads"]
+
+
+def _kept_values(cfg):
+    """What the preset's blocks keep a position: in every layer the MLP's
+    first product and the residual stream, in a Mamba-2 layer the mixer's
+    first product too."""
+    return cfg["num_hidden_layers"] * (
+        2 * cfg["shared_intermediate_size"] + cfg["hidden_size"]) \
+        + _mamba_layers(cfg) * _in_proj_width(cfg)
+
+
 def test_backward_makes_neither_kept_product_again(cfg, monkeypatch):
     """By layer: full recompute makes the MLP's first product, the mixer's
     output projection and the mixer's inside twice; a block that keeps the
     first product and the residual after the mixer makes only the mixer's
-    inside twice; no recompute makes nothing twice."""
+    inside twice, and of a Mamba-2 mixer's inside not its first product,
+    which the block keeps too; no recompute makes nothing twice."""
     batch = _batch(cfg)
     dots = {}
     for how in ("none", "keep", "full"):
@@ -171,26 +202,27 @@ def test_backward_makes_neither_kept_product_again(cfg, monkeypatch):
     first_product = (rows, time, 2 * cfg["shared_intermediate_size"])
     assert [d.count(first_product) for d in dots.values()] \
         == [layers, layers, 2 * layers]
-    # the two a layer no longer makes again: the first product and the
-    # mixer's output projection (out_proj, or the attention layer's o)
-    assert len(dots["full"]) - len(dots["keep"]) == 2 * layers
+    mamba = _mamba_layers(cfg)
+    in_product = (rows, time, _in_proj_width(cfg))
+    assert [d.count(in_product) for d in dots.values()] \
+        == [mamba, mamba, 2 * mamba]
+    # what a layer no longer makes again: the MLP's first product and the
+    # mixer's output projection (out_proj, or the attention layer's o),
+    # and in a Mamba-2 layer the mixer's first product
+    assert len(dots["full"]) - len(dots["keep"]) == 2 * layers + mamba
 
 
 def test_without_recompute_the_program_is_what_it_was(cfg, monkeypatch):
     """Outside a checkpoint a name is the identity: ``recompute=False``
     lowers to the text it lowered to before the layers named anything."""
-    from paddle_tpu.layer import decoder
-
     batch = _batch(cfg)
 
     def lowered():
         cost, topo, params, _, _ = _program(cfg, recompute=False)
         feed = convert_feed(topo, batch)
-        text = jax.jit(jax.grad(lambda p: jnp.mean(
+        return _numbers_off(jax.jit(jax.grad(lambda p: jnp.mean(
             topo.apply(p, feed, mode="train")[0][cost.name]))
-        ).lower(params).as_text()
-        # a private function's number counts the lowerings of the process
-        return re.sub(r"@(\w+?)_\d+\b", r"@\1", text)
+        ).lower(params).as_text())
 
     named = lowered()
     monkeypatch.setattr(decoder, "checkpoint_name", lambda x, name: x)
@@ -204,8 +236,9 @@ def _kept_bytes():
 
 def test_the_gauge_reads_what_the_traced_step_keeps(cfg):
     """layers x positions x (the MLP's first product + the residual
-    stream) x the bytes of a value; 0 without recompute and for a model
-    with no recomputed block (the ResNet preset)."""
+    stream) x the bytes of a value, and Mamba-2 layers x positions x the
+    mixer's first product; 0 without recompute and for a model with no
+    recomputed block (the ResNet preset)."""
     from chipbench.models import resnet50 as bench_resnet
 
     def trace(cost, topo, params, feed):
@@ -215,8 +248,7 @@ def test_the_gauge_reads_what_the_traced_step_keeps(cfg):
     program = _program(cfg)[:3]
     feed = convert_feed(program[1], _batch(cfg))
     rows, time = feed["tokens"].data.shape
-    kept = cfg["num_hidden_layers"] * rows * time * (
-        2 * cfg["shared_intermediate_size"] + cfg["hidden_size"]) * 4
+    kept = rows * time * _kept_values(cfg) * 4
     trace(*program, feed)
     assert _kept_bytes() == kept
     trace(*_program(cfg, recompute=False)[:3], feed)
@@ -372,8 +404,7 @@ def test_it_trains_through_sgd_train_and_counts_its_tokens(cfg,
     assert tokens == 3 * sum(rows)
     assert positions == 3 * len(rows) * padded
     # set as the trainer's step was traced: what its ten blocks keep
-    assert _kept_bytes() == cfg["num_hidden_layers"] * len(rows) * padded \
-        * (2 * cfg["shared_intermediate_size"] + cfg["hidden_size"]) * 4
+    assert _kept_bytes() == len(rows) * padded * _kept_values(cfg) * 4
 
 
 def test_a_steps_tokens_are_its_widest_sequence_slots():
@@ -524,3 +555,87 @@ def test_the_olmo_model_trains_through_sgd_train(olmo):
     assert positions == 3 * len(rows) * padded
     assert _kept_bytes() == 3 * len(rows) * padded * (
         2 * olmo["intermediate_size"] + olmo["hidden_size"]) * 4
+
+
+# -- what a mixer offers the block round it -----------------------------------
+
+def _no_mixer_offers_a_name(monkeypatch):
+    """``MIXERS`` as it was before a kind of mixer named a value for the
+    block round it to keep."""
+    monkeypatch.setattr(hybrid_lm, "MIXERS", {
+        kind: (mixer, group, ())
+        for kind, (mixer, group, _) in hybrid_lm.MIXERS.items()})
+
+
+def _trainers_step(build, cfg, cell, monkeypatch):
+    """(The lowered text of ``SGD``'s train step over the preset's first
+    batch, as the cell's model builds it; each block's ``keep``, layer by
+    layer, nodes by their names.)"""
+    keeps = []
+    block = L.recompute
+
+    def recording(*a, keep=(), **kw):
+        keeps.append([k if isinstance(k, str) else k.name for k in keep])
+        return block(*a, keep=keep, **kw)
+
+    paddle.init(use_tpu=False, seed=7)
+    L.reset_name_counters()
+    with monkeypatch.context() as m:
+        m.setattr(L, "recompute", recording)
+        cost = build(cfg)
+    trainer = paddle.trainer.SGD(
+        cost, paddle.parameters.create(cost),
+        paddle.optimizer.Momentum(learning_rate=0.01, momentum=0.9))
+    feed = convert_feed(trainer.topology, traffic.make_pool(
+        cfg["inputs"], _load("workloads", cell), 7)[0])
+    text = trainer._train_step.lower(
+        trainer._trainable, trainer._replica, trainer._static,
+        trainer._state, trainer._opt_state, feed, trainer._rng).as_text()
+    return _numbers_off(text), keeps
+
+
+def test_a_model_with_no_mamba_layer_is_built_as_it_was(olmo, monkeypatch):
+    """The Olmo preset as its cell builds it: every block's ``keep`` and
+    the trainer's lowered step are what they are when no mixer offers a
+    name."""
+    from chipbench.models import olmo_hybrid as olmo_model
+
+    text, keeps = _trainers_step(olmo_model.build, olmo, OLMO_CELL,
+                                 monkeypatch)
+    layers = olmo["num_hidden_layers"]
+    kept = olmo_model.KEEP_LAYERS
+    assert [k[1:] for k in keeps] == [[]] * (layers - kept) \
+        + [[decoder.GATED_MLP_PRODUCT]] * kept
+    assert [len(k) for k in keeps] == [0] * (layers - kept) + [2] * kept
+    _no_mixer_offers_a_name(monkeypatch)
+    text_before, keeps_before = _trainers_step(olmo_model.build, olmo,
+                                               OLMO_CELL, monkeypatch)
+    assert keeps == keeps_before
+    assert text == text_before
+
+
+def test_a_mamba_layer_keeps_its_first_product_by_its_kind(cfg, monkeypatch):
+    """Granite's preset as its cell builds it: a Mamba-2 layer's block
+    lists the mixer's name after the two every block lists, the attention
+    layer's does not, and the trainer's step is another program than with
+    no name offered (the guard above can tell)."""
+    text, keeps = _trainers_step(bench_model.build, cfg, CELL, monkeypatch)
+    kinds = cfg["layer_types"][:cfg["num_hidden_layers"]]
+    two = [decoder.GATED_MLP_PRODUCT]
+    assert [k[1:] for k in keeps] == [
+        two + [decoder.MAMBA_IN_PRODUCT] if kind == "mamba" else two
+        for kind in kinds]
+    _no_mixer_offers_a_name(monkeypatch)
+    text_before, keeps_before = _trainers_step(bench_model.build, cfg, CELL,
+                                               monkeypatch)
+    assert [k[1:] for k in keeps_before] == [two] * len(kinds)
+    assert [k[0] for k in keeps] == [k[0] for k in keeps_before]
+    assert text != text_before
+
+
+def test_the_table_of_mixers_says_what_each_kind_offers():
+    offered = {kind: names for kind, (_, _, names)
+               in hybrid_lm.MIXERS.items()}
+    assert offered == {"mamba": (decoder.MAMBA_IN_PRODUCT,),
+                       "attention": (), "full_attention": (),
+                       "linear_attention": ()}
