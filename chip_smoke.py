@@ -19,7 +19,9 @@ It runs its stages as children, one after another:
           the Mosaic call in the lowered step; one profiler capture;
           twelve batches through a DeviceFeeder, each read back from the
           device and compared with its rows (the feeder's recycled host
-          buffers against transfers still in flight).
+          buffers against transfers still in flight); a tiny decoder
+          whose recomputed blocks hand values to later blocks takes
+          three steps, and its gauges read what its shapes give.
   export  python -m paddle_tpu.cli export --use-tpu ... --decode-slots
   serve   python -m paddle_tpu.cli serve --use-tpu <bundle> --continuous;
           /readyz, POST /infer at several lengths against the reference,
@@ -775,6 +777,58 @@ def phase_feed_readback(cfg, parallelism=None):
                 / (n - ring))
 
 
+def phase_shared_blocks():
+    """A decoder whose recomputed blocks hand values to later blocks (a
+    Mamba-1 layer's scan output to Gated Memory Units, a full-attention
+    layer's keys and values to cross-attention layers; window and
+    differential attention beside them) takes three steps through
+    SGD.train, and the gauges set as its step was traced read what the
+    shapes give: the tiny preset of the `phi4flash` layout, on whatever
+    device the stage holds."""
+    import numpy as np
+
+    import paddle_tpu as paddle
+    from chipbench import traffic
+    from paddle_tpu import layer as L
+    from paddle_tpu.models import hybrid_lm
+    from paddle_tpu.observe import metrics as observe_metrics
+    from paddle_tpu.topology import convert_feed
+
+    tiny = os.path.join(REPO, "tests", "chipbench", "tiny")
+    cfg = _read_json(os.path.join(
+        tiny, "configs", "phi-4-mini-flash-reasoning.json"))
+    cell = _read_json(os.path.join(
+        tiny, "workloads",
+        "phi-4-mini-flash-reasoning-seq4096-bs2-train.json"))
+    L.reset_name_counters()
+    cost = hybrid_lm.from_config(cfg)[3]
+    trainer = paddle.trainer.SGD(
+        cost, paddle.parameters.create(cost),
+        paddle.optimizer.Momentum(learning_rate=0.01, momentum=0.9))
+    pool = traffic.make_pool(cfg["inputs"], cell, SEED)
+    costs = []
+    trainer.train(lambda: iter(pool), event_handler=cost_collector(costs),
+                  feed_pipeline=True)
+    require(len(costs) == len(pool) and all(np.isfinite(costs)),
+            "the shared-value decoder's costs are %r", costs)
+    rows, padded = convert_feed(trainer.topology,
+                                pool[-1])["tokens"].data.shape
+    gauges = observe_metrics.get_registry().snapshot()["gauges"]
+    heads = cfg["num_attention_heads"]
+    width = cfg["mamba_expand"] * cfg["hidden_size"] \
+        + 2 * cfg["num_key_value_heads"] * (cfg["hidden_size"] // heads)
+    shared = gauges["paddle_tpu_shared_across_blocks_bytes"]
+    require(shared in (rows * padded * width * 2, rows * padded * width * 4),
+            "%r bytes live across blocks, not %d values of 2 or 4 bytes",
+            shared, rows * padded * width)
+    visited, possible = (gauges["paddle_tpu_attention_key_blocks_" + k]
+                         for k in ("visited", "possible"))
+    require(0 < visited <= possible, "attention visited %r of %r key blocks",
+            visited, possible)
+    return {"costs": costs, "shared_across_blocks_bytes": shared,
+            "attention_key_blocks": [visited, possible]}
+
+
 def stage_chip(args):
     cfg = TINY if args.dry_run_cpu else FULL
     device, env_report = open_device(args)
@@ -787,6 +841,7 @@ def stage_chip(args):
     phase_serve_reference(cfg)
     report["train"] = phase_train(cfg, args, device)
     report["feed_readback"] = phase_feed_readback(cfg)
+    report["shared_blocks"] = phase_shared_blocks()
     report["compile_cache"] = compile_cache.stats()
     with open(os.path.join(WORK, "chip.json"), "w") as fh:
         json.dump(report, fh)

@@ -127,6 +127,11 @@ class Context:
         # keeps for backward, and the bytes this trace's blocks keep
         self.recompute_keeping = frozenset()
         self.recompute_kept_bytes = 0
+        # the bytes of the values this trace's blocks hand out to later
+        # blocks beside the stream, and the key blocks its attention
+        # layers visit of those at or under the diagonal (layer/decoder.py)
+        self.shared_across_blocks_bytes = 0
+        self.attention_key_blocks = {"visited": 0, "possible": 0}
         # streaming-decode carry threading (serve/export.py decode step):
         # when ``decode_state`` is a dict, recurrent layers read their
         # initial carry from it (decode_state[layer_name] = [leaf, ...];

@@ -14,8 +14,9 @@ paddle_tpu.topology.Topology. Families:
   cost.py      classification_cost, cross_entropy, square_error, rank, ...
   mixed.py     mixed + projections/operators
   extra.py     nce, hsigmoid, crf, crf_decoding, ctc, warp_ctc, detection
-  decoder.py   rms_norm, gated_mlp, mamba2, gated_delta_net, gqa_attention,
-               lm_head, recompute (their token-level cost, lm_cost, is in cost.py)
+  decoder.py   rms_norm, layer_norm, gated_mlp, mamba2, mamba1, gmu,
+               gated_delta_net, gqa_attention, lm_head, recompute (their
+               token-level cost, lm_cost, is in cost.py)
 """
 
 from paddle_tpu.graph import LayerNode, LayerOutput, reset_name_counters
@@ -136,8 +137,11 @@ from paddle_tpu.layer.misc import (
 from paddle_tpu.layer.decoder import (
     gated_delta_net,
     gated_mlp,
+    gmu,
     gqa_attention,
+    layer_norm,
     lm_head,
+    mamba1,
     mamba2,
     recompute,
     rms_norm,

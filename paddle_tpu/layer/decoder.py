@@ -1,17 +1,23 @@
 """Layers of today's decoder-only language models: RMSNorm (plain and
-gated), the gated MLP, the Mamba-2 mixer, the Gated DeltaNet
-linear-attention mixer, grouped-query causal attention without positions
-(with or without normalised queries and keys), the head over a
+gated) and LayerNorm, the gated MLP, the Mamba-2 and Mamba-1 mixers, the
+Gated DeltaNet linear-attention mixer, the Gated Memory Unit,
+grouped-query causal attention without positions (with or without
+normalised queries and keys, a window, differential heads, biases, and
+over its own keys and values or another layer's), the head over a
 vocabulary table, and a block that is recomputed in backward (the
 token-level cost over the head's logits is ``layer/cost.py lm_cost``).
 
-All take and give ``SequenceBatch`` values [B, T, width]. The three layers
-that mix across time (``mamba2``, ``gated_delta_net``, ``gqa_attention``)
-refuse packed rows: their state, convolution taps and attention do not yet
-reset at segment starts. Every piece of device work runs under a
-``jax.named_scope`` of its own (docs/observability.md "Decoder scopes").
+All take and give ``SequenceBatch`` values [B, T, width]. The layers that
+mix across time (``mamba2``, ``mamba1``, ``gated_delta_net``,
+``gqa_attention``) refuse packed rows: their state, convolution taps and
+attention do not yet reset at segment starts. Every piece of device work
+runs under a ``jax.named_scope`` of its own (docs/observability.md
+"Decoder scopes"). A layer that hands out a second value (``mamba1``'s
+scan output, ``gqa_attention``'s keys and values) gives a tuple, and
+:func:`_values` makes one node of each of its values.
 """
 
+import contextlib
 import math
 
 import jax
@@ -21,9 +27,9 @@ from jax.ad_checkpoint import checkpoint_name
 from paddle_tpu.core.dtype import upcast_f32
 from paddle_tpu.graph import auto_name
 from paddle_tpu.initializer import Constant, Uniform
-from paddle_tpu.layer.base import (data_of, featurewise, is_seq, like,
-                                   make_node, register_layer, reject_packed,
-                                   to_list, weight_spec)
+from paddle_tpu.layer.base import (bias_spec, data_of, featurewise, is_seq,
+                                   like, make_node, register_layer,
+                                   reject_packed, to_list, weight_spec)
 from paddle_tpu.ops import attention as attention_ops
 from paddle_tpu.ops import delta_rule as delta_ops
 from paddle_tpu.ops import ssm as ssm_ops
@@ -83,13 +89,50 @@ def rms_norm(input, gate=None, eps=1e-5, name=None, param_attr=None,
                      layer_attr=layer_attr)
 
 
+@register_layer("layer_norm")
+def layer_norm(input, eps=1e-5, name=None, param_attr=None, bias_attr=None,
+               layer_attr=None):
+    """LayerNorm over the feature axis, (x - mean) * rsqrt(var + eps) *
+    scale + bias, in float32 at least; the scale ``<name>.w0`` starts at
+    ones, the bias ``<name>.wbias`` at zeros."""
+    name = name or auto_name("layer_norm")
+    scale = _ones_spec(name, (input.size,), param_attr)
+    bias = bias_spec(name, (input.size,), bias_attr)
+
+    def normalize(x, params):
+        with jax.named_scope("paddle_tpu.layer_norm"):
+            wide = upcast_f32(x)
+            centred = wide - jnp.mean(wide, axis=-1, keepdims=True)
+            out = centred * jax.lax.rsqrt(
+                jnp.mean(centred * centred, axis=-1, keepdims=True) + eps)
+            return (out * upcast_f32(params[scale.name])
+                    + upcast_f32(params[bias.name])).astype(x.dtype)
+
+    def forward(params, values, ctx):
+        return featurewise(lambda d: normalize(d, params), values[0])
+
+    return make_node("layer_norm", forward, [input], name=name,
+                     size=input.size, param_specs=[scale, bias],
+                     layer_attr=layer_attr)
+
+
 # What a recomputed block may keep for backward (``recompute(keep=...)``):
 # GATED_MLP_PRODUCT names x W_in inside ``gated_mlp``, before the split;
-# MAMBA_IN_PRODUCT u W_in inside ``mamba2``, before the split;
+# MAMBA_IN_PRODUCT u W_in inside ``mamba2``, MAMBA1_IN_PRODUCT inside
+# ``mamba1``, both before the split;
 # _KEPT_OUTPUT the outputs of the inner nodes a block lists.
 GATED_MLP_PRODUCT = "paddle_tpu.gated_mlp.product"
 MAMBA_IN_PRODUCT = "paddle_tpu.mamba2.in_product"
+MAMBA1_IN_PRODUCT = "paddle_tpu.mamba1.in_product"
 _KEPT_OUTPUT = "paddle_tpu.block.kept"
+
+
+def _values(node, sizes):
+    """One node for each value of ``node``, a layer whose forward gives a
+    tuple: the i-th has the i-th value and ``sizes[i]``."""
+    return [make_node("value_of", lambda params, values, ctx, i=i:
+                      values[0][i], [node], name="%s.%d" % (node.name, i),
+                      size=size) for i, size in enumerate(sizes)]
 
 
 def _kept(x, name, ctx):
@@ -154,10 +197,11 @@ class _InverseSoftplusOfLogUniform:
 
 
 class _LogArange:
-    """A_log = log(1..H)."""
+    """A_log = log(1..n) along the last axis, every row alike."""
 
     def __call__(self, rng, shape, dtype):
-        return jnp.log(jnp.arange(1, shape[0] + 1, dtype=dtype))
+        return jnp.broadcast_to(
+            jnp.log(jnp.arange(1, shape[-1] + 1, dtype=dtype)), shape)
 
 
 @register_layer("mamba2")
@@ -223,6 +267,108 @@ def mamba2(input, heads, head_dim, state, conv_width=4, groups=1, chunk=256,
 
     return make_node("mamba2", forward, [input], name=name, size=d,
                      param_specs=list(specs.values()), layer_attr=layer_attr)
+
+
+@register_layer("mamba1")
+def mamba1(input, state=16, conv_width=4, expand=2, dt_rank=None, chunk=16,
+           initial_std=0.02, name=None, layer_attr=None, hand_out=False,
+           eps=None):
+    """The Mamba-1 mixer (Gu & Dao 2023, arXiv:2312.00752), E = expand *
+    width channels, ``state`` states a channel, R = ``dt_rank`` (width /
+    16, rounded up, by default):
+        [x, z] = u W_in
+        x = silu(conv1d_causal(x))                 depthwise, with bias
+        [r, B, C] = x W_x                          R, state, state
+        dt = softplus(r W_dt + dt_bias);  A = -exp(A_log)     A_log [E, state]
+        S_t[c, n] = exp(dt_t[c] A[c, n]) S_{t-1}[c, n] + dt_t[c] x_t[c] B_t[n]
+        y_t[c] = sum_n S_t[c, n] C_t[n] + D[c] x_t[c]
+        out = (y * silu(z)) W_out
+    the scan token by token, kept by chunks of ``chunk``
+    (``ops/ssm.py selective_scan``). Parameters ``<name>.in_proj``,
+    ``.conv_w`` [E, K], ``.conv_b``, ``.x_proj``, ``.dt_proj``,
+    ``.dt_bias``, ``.A_log``, ``.D``, ``.out_proj``; no bias on the
+    projections. The first product, before the split, carries the name
+    ``MAMBA1_IN_PRODUCT``, which a ``recompute`` block around the layer may
+    keep. With ``hand_out`` returns [the layer, its scan output y before
+    the gate]: the memory that ``gmu`` layers read. ``eps`` is taken for
+    ``hybrid_lm``, which hands every mixer the model's, and not used: the
+    layer has no norm."""
+    name = name or auto_name("mamba1")
+    d = input.size
+    inner = expand * d
+    rank = dt_rank if dt_rank is not None else -(-d // 16)
+    specs = {
+        "in_proj": _named_spec(name, "in_proj", (d, 2 * inner),
+                               std=initial_std),
+        # as torch.nn.Conv1d starts a depthwise filter and its bias
+        "conv_w": _named_spec(name, "conv_w", (inner, conv_width),
+                              Uniform(-conv_width ** -0.5,
+                                      conv_width ** -0.5)),
+        "conv_b": _named_spec(name, "conv_b", (inner,),
+                              Uniform(-conv_width ** -0.5,
+                                      conv_width ** -0.5)),
+        "x_proj": _named_spec(name, "x_proj", (inner, rank + 2 * state),
+                              std=initial_std),
+        "dt_proj": _named_spec(name, "dt_proj", (rank, inner),
+                               Uniform(-rank ** -0.5, rank ** -0.5)),
+        "dt_bias": _named_spec(name, "dt_bias", (inner,),
+                               _InverseSoftplusOfLogUniform()),
+        "A_log": _named_spec(name, "A_log", (inner, state), _LogArange()),
+        "D": _named_spec(name, "D", (inner,), Constant(1.0)),
+        "out_proj": _named_spec(name, "out_proj", (inner, d),
+                                std=initial_std),
+    }
+
+    def forward(params, values, ctx):
+        seq = values[0]
+        reject_packed(seq, "mamba1")
+        enforce(is_seq(seq), "mamba1 needs a sequence input")
+        p = {k: params[s.name] for k, s in specs.items()}
+        with jax.named_scope("paddle_tpu.mamba1"):
+            product = _kept(jnp.matmul(seq.data, p["in_proj"]),
+                            MAMBA1_IN_PRODUCT, ctx)
+            x, z = jnp.split(product, 2, axis=-1)
+            x = jax.nn.silu(ssm_ops.causal_conv1d(
+                x, p["conv_w"], p["conv_b"], seq.lengths))
+            r, b_mat, c_mat = jnp.split(jnp.matmul(x, p["x_proj"]),
+                                        [rank, rank + state], axis=-1)
+            dt = jax.nn.softplus(
+                jnp.matmul(r, p["dt_proj"],
+                           preferred_element_type=upcast_f32(r).dtype)
+                + upcast_f32(p["dt_bias"]))
+            y, _ = ssm_ops.selective_scan(
+                x, dt, -jnp.exp(upcast_f32(p["A_log"])), b_mat, c_mat,
+                p["D"], chunk, seq.lengths)
+            out = like(seq, jnp.matmul(y * jax.nn.silu(z), p["out_proj"]))
+            return (out, like(seq, y)) if hand_out else out
+
+    node = make_node("mamba1", forward, [input], name=name, size=d,
+                     param_specs=list(specs.values()), layer_attr=layer_attr)
+    return _values(node, (d, inner)) if hand_out else node
+
+
+@register_layer("gmu")
+def gmu(input, memory, initial_std=0.02, name=None, layer_attr=None,
+        eps=None):
+    """The Gated Memory Unit (SambaY, arXiv:2507.06607):
+    ``(m * silu(u W_1)) W_2`` with ``m`` the value of ``memory``, an
+    earlier ``mamba1`` layer's scan output, position by position.
+    Parameters ``<name>.in_proj`` [d, width of m], ``.out_proj``; no bias.
+    ``eps`` as ``mamba1``'s: taken and not used."""
+    name = name or auto_name("gmu")
+    d = input.size
+    w_in = _named_spec(name, "in_proj", (d, memory.size), std=initial_std)
+    w_out = _named_spec(name, "out_proj", (memory.size, d), std=initial_std)
+
+    def forward(params, values, ctx):
+        with jax.named_scope("paddle_tpu.gmu"):
+            gate = jax.nn.silu(jnp.matmul(data_of(values[0]),
+                                          params[w_in.name]))
+            return like(values[0], jnp.matmul(data_of(values[1]) * gate,
+                                              params[w_out.name]))
+
+    return make_node("gmu", forward, [input, memory], name=name, size=d,
+                     param_specs=[w_in, w_out], layer_attr=layer_attr)
 
 
 class _LogUniform:
@@ -304,36 +450,90 @@ def gated_delta_net(input, heads, key_dim, value_dim, conv_width=4,
                      param_specs=list(specs.values()), layer_attr=layer_attr)
 
 
+def lambda_init(depth):
+    """A differential attention layer's starting lambda by its depth in
+    the model, 0.8 - 0.6 exp(-0.3 depth) (Ye et al. 2024,
+    arXiv:2410.05258, eq. 3, depth from 0)."""
+    return 0.8 - 0.6 * math.exp(-0.3 * depth)
+
+
+def _differential_attention(q, k, v, lam, lam_init, norm_w, eps, scale,
+                            lengths, block, window):
+    """Differential attention (arXiv:2410.05258): query heads (2p, 2p+1)
+    and key heads (2g, 2g+1) in pairs, g = p // (H / KV), the pair's
+    values side by side; per pair (softmax(Q1 K1^T) - lam softmax(Q2
+    K2^T)) [V1 | V2], RMS-normalised over its 2 D values and times
+    1 - lam_init. Both softmaxes go through one blockwise pass: the first
+    heads of the pairs, then the second, each over the pair's values;
+    the difference and the norm are float32."""
+    b, t, heads, d = q.shape
+    with jax.named_scope("paddle_tpu.diff_attention"):
+        pair_v = v.reshape(b, t, v.shape[2] // 2, 2 * d)
+        both = attention_ops.blockwise_attention(
+            jnp.concatenate([q[:, :, 0::2], q[:, :, 1::2]], axis=2),
+            jnp.concatenate([k[:, :, 0::2], k[:, :, 1::2]], axis=2),
+            jnp.concatenate([pair_v, pair_v], axis=2),
+            scale, True, lengths, block, window)
+        first, second = jnp.split(upcast_f32(both), 2, axis=2)
+        out = _rms_normalize(first - lam * second, norm_w, eps) \
+            * (1.0 - lam_init)
+        return out.astype(q.dtype)
+
+
 @register_layer("gqa_attention")
 def gqa_attention(input, heads, kv_heads, head_dim, scale=None, block=512,
                   initial_std=0.02, name=None, layer_attr=None,
-                  qk_norm=False, eps=1e-5):
+                  qk_norm=False, eps=1e-5, window=None, differential=None,
+                  bias=False, kv=None, hand_out=False):
     """Causal self-attention with ``heads`` query heads over ``kv_heads``
     shared key-value heads, no positional encoding and no bias; scores are
     multiplied by ``scale`` (1 / sqrt(head_dim) by default). Blockwise
     (``ops/attention.py``): no [T, T] score matrix is held. With
     ``qk_norm`` queries and keys are RMS-normalised with a learned scale,
     each over its whole projection, before the split into heads (the
-    OLMo 2 layout). Parameters ``<name>.q``, ``.k``, ``.v``, ``.o``, and
-    ``.q_norm``, ``.k_norm`` with ``qk_norm``."""
+    OLMo 2 layout). With ``window`` a query sees the ``window`` keys that
+    end with its own, and key blocks outside are not visited. With
+    ``differential`` (the layer's starting lambda, ``lambda_init`` of its
+    depth) heads go in pairs that subtract two softmaxes
+    (``_differential_attention``). With ``bias`` the four projections have
+    biases. With ``kv``, a node handed out by an earlier attention layer
+    (``hand_out``: returns [the layer, its keys and values side by side,
+    [B, T, 2 * kv_heads * head_dim]]), the layer has a query projection
+    only and attends to that layer's keys and values (cross-attention in
+    a decoder that shares one key-value cache). Parameters ``<name>.q``,
+    ``.k``, ``.v``, ``.o``; ``.q_norm``, ``.k_norm`` with ``qk_norm``;
+    ``.q_b``, ``.k_b``, ``.v_b``, ``.o_b`` with ``bias``; ``.lambda_q1``,
+    ``.lambda_k1``, ``.lambda_q2``, ``.lambda_k2`` [head_dim] and
+    ``.subln`` [2 * head_dim] with ``differential``."""
     name = name or auto_name("gqa_attention")
     d = input.size
     enforce(heads % kv_heads == 0, "kv_heads %d must divide heads %d",
             kv_heads, heads)
+    enforce(differential is None or kv_heads % 2 == 0,
+            "differential attention pairs heads: kv_heads is %d", kv_heads)
     scale = scale if scale is not None else head_dim ** -0.5
-    specs = {
-        "q": _named_spec(name, "q", (d, heads * head_dim), std=initial_std),
-        "k": _named_spec(name, "k", (d, kv_heads * head_dim),
-                         std=initial_std),
-        "v": _named_spec(name, "v", (d, kv_heads * head_dim),
-                         std=initial_std),
-        "o": _named_spec(name, "o", (heads * head_dim, d), std=initial_std),
-    }
+    widths = {"q": heads * head_dim, "k": kv_heads * head_dim,
+              "v": kv_heads * head_dim}
+    made = ("q",) if kv is not None else ("q", "k", "v")
+    specs = {n: _named_spec(name, n, (d, widths[n]), std=initial_std)
+             for n in made}
+    specs["o"] = _named_spec(name, "o", (heads * head_dim, d),
+                             std=initial_std)
     if qk_norm:
         specs["q_norm"] = _named_spec(name, "q_norm", (heads * head_dim,),
                                       Constant(1.0))
         specs["k_norm"] = _named_spec(name, "k_norm", (kv_heads * head_dim,),
                                       Constant(1.0))
+    if bias:
+        for n in made:
+            specs[n + "_b"] = _named_spec(name, n + "_b", (widths[n],),
+                                          Constant(0.0))
+        specs["o_b"] = _named_spec(name, "o_b", (d,), Constant(0.0))
+    if differential is not None:
+        for n in ("lambda_q1", "lambda_k1", "lambda_q2", "lambda_k2"):
+            specs[n] = _named_spec(name, n, (head_dim,), std=0.1)
+        specs["subln"] = _named_spec(name, "subln", (2 * head_dim,),
+                                     Constant(1.0))
 
     def forward(params, values, ctx):
         seq = values[0]
@@ -344,22 +544,55 @@ def gqa_attention(input, heads, kv_heads, head_dim, scale=None, block=512,
 
         def projected(n, h):
             x = jnp.matmul(u, params[specs[n].name])
+            if bias:
+                x = x + params[specs[n + "_b"].name]
             if qk_norm and n != "v":
                 with jax.named_scope("paddle_tpu.qk_norm"):
                     x = _rms_normalize(x, params[specs[n + "_norm"].name],
                                        eps)
             return x.reshape(b, t, h, head_dim)
 
-        with jax.named_scope("paddle_tpu.gqa_attention"):
-            q, k, v = (projected(n, h) for n, h in (
-                ("q", heads), ("k", kv_heads), ("v", kv_heads)))
-            y = attention_ops.blockwise_attention(
-                q, k, v, scale, True, seq.lengths, block)
-            return like(seq, jnp.matmul(y.reshape(b, t, heads * head_dim),
-                                        params[specs["o"].name]))
+        def attend(q, k, v):
+            if differential is None:
+                return attention_ops.blockwise_attention(
+                    q, k, v, scale, True, seq.lengths, block, window)
+            lam = [jnp.sum(upcast_f32(params[specs["lambda_q%d" % j].name])
+                           * upcast_f32(params[specs["lambda_k%d" % j].name]))
+                   for j in (1, 2)]
+            return _differential_attention(
+                q, k, v, jnp.exp(lam[0]) - jnp.exp(lam[1]) + differential,
+                differential, params[specs["subln"].name], eps, scale,
+                seq.lengths, block, window)
 
-    return make_node("gqa_attention", forward, [input], name=name, size=d,
-                     param_specs=list(specs.values()), layer_attr=layer_attr)
+        with jax.named_scope("paddle_tpu.gqa_attention"):
+            if kv is None:
+                q, k, v = (projected(n, h) for n, h in (
+                    ("q", heads), ("k", kv_heads), ("v", kv_heads)))
+            else:
+                with jax.named_scope("paddle_tpu.cross_attention"):
+                    q = projected("q", heads)
+                    k, v = (x.reshape(b, t, kv_heads, head_dim) for x in
+                            jnp.split(data_of(values[1]), 2, axis=-1))
+            for visited, w in (("visited", window), ("possible", None)):
+                ctx.attention_key_blocks[visited] += sum(
+                    end - first for first, end in attention_ops.key_blocks(
+                        t, block, True, w))
+            with jax.named_scope("paddle_tpu.window_attention") \
+                    if window is not None else contextlib.nullcontext():
+                y = attend(q, k, v)
+            out = jnp.matmul(y.reshape(b, t, heads * head_dim),
+                             params[specs["o"].name])
+            if bias:
+                out = out + params[specs["o_b"].name]
+            if not hand_out:
+                return like(seq, out)
+            return like(seq, out), like(seq, jnp.concatenate(
+                [k.reshape(b, t, -1), v.reshape(b, t, -1)], axis=-1))
+
+    node = make_node("gqa_attention", forward, [input] + to_list(kv),
+                     name=name, size=d, param_specs=list(specs.values()),
+                     layer_attr=layer_attr)
+    return _values(node, (d, 2 * kv_heads * head_dim)) if hand_out else node
 
 
 @register_layer("lm_head")
@@ -394,19 +627,29 @@ def recompute(output, inputs, enabled=True, name=None, keep=()):
     the parameters of the layers inside; ``enabled=False`` runs the same
     node without the checkpoint.
 
+    ``output`` may be a list of nodes: the block then hands out every
+    one, and a list of nodes comes back, one for each. What follows the
+    first is a value that later blocks read beside the stream (they list
+    it in their ``inputs``): it is made once and lives across the blocks,
+    and backward adds into it the gradients of every block that read it.
+    Its bytes are added to ``ctx.shared_across_blocks_bytes`` (the gauge
+    ``paddle_tpu_shared_across_blocks_bytes``).
+
     ``keep`` lists what backward keeps besides the inputs, so that the
     second forward need not make it again: a node inside the block (its
     output) or a name that a layer inside gives one of its values
-    (``GATED_MLP_PRODUCT``, ``MAMBA_IN_PRODUCT``). Worth keeping is a value
-    that is dear to make and small to hold, a large product's output;
-    whatever only fed a kept value is then dead in the second forward (the
-    product before a kept sum). With nothing listed the checkpoint has no
-    policy. The bytes kept are added to ``ctx.recompute_kept_bytes``, which
-    ``Topology.apply`` sets the gauge ``paddle_tpu_recompute_kept_bytes``
-    from."""
+    (``GATED_MLP_PRODUCT``, ``MAMBA_IN_PRODUCT``, ``MAMBA1_IN_PRODUCT``).
+    Worth keeping is a value that is dear to make and small to hold, a
+    large product's output; whatever only fed a kept value is then dead
+    in the second forward (the product before a kept sum). With nothing
+    listed the checkpoint has no policy. The bytes kept are added to
+    ``ctx.recompute_kept_bytes``, which ``Topology.apply`` sets the gauge
+    ``paddle_tpu_recompute_kept_bytes`` from."""
     inputs = to_list(inputs)
+    several = isinstance(output, (list, tuple))
+    outputs = to_list(output)
     boundary = {id(n) for n in inputs}
-    inside = _between(output, boundary)
+    inside = _between(outputs, boundary)
     kept_nodes = {id(k) for k in keep if not isinstance(k, str)}
     enforce(kept_nodes <= {id(n) for n in inside},
             "recompute: keep lists a node that is not inside the block")
@@ -435,26 +678,35 @@ def recompute(output, inputs, enabled=True, name=None, keep=()):
                     value = featurewise(
                         lambda d: _kept(d, _KEPT_OUTPUT, ctx), value)
                 seen[id(node)] = value
-            return seen[id(output)]
+            if not several:
+                return seen[id(output)]
+            return tuple(seen[id(n)] for n in outputs)
+
+        def handed_out(result):
+            for value in result[1:] if several else ():
+                x = data_of(value)
+                ctx.shared_across_blocks_bytes += x.size * x.dtype.itemsize
+            return result
 
         block_params = {k: params[k] for k in specs}
         with jax.named_scope("paddle_tpu.block"):
             if not enabled:
-                return run(block_params, list(values))
+                return handed_out(run(block_params, list(values)))
             outer, ctx.recompute_keeping = ctx.recompute_keeping, names
             try:
-                return jax.checkpoint(run, policy=policy)(
-                    block_params, list(values))
+                return handed_out(jax.checkpoint(run, policy=policy)(
+                    block_params, list(values)))
             finally:
                 ctx.recompute_keeping = outer
 
-    return make_node("recompute", forward, inputs,
-                     name=name or auto_name("recompute"), size=output.size,
-                     param_specs=list(specs.values()))
+    node = make_node("recompute", forward, inputs,
+                     name=name or auto_name("recompute"),
+                     size=outputs[0].size, param_specs=list(specs.values()))
+    return _values(node, [n.size for n in outputs]) if several else node
 
 
-def _between(output, boundary):
-    """Nodes from the boundary (left out) to ``output``, inputs first."""
+def _between(outputs, boundary):
+    """Nodes from the boundary (left out) to ``outputs``, inputs first."""
     order, seen = [], set(boundary)
 
     def visit(node):
@@ -465,5 +717,6 @@ def _between(output, boundary):
             visit(parent)
         order.append(node)
 
-    visit(output)
+    for node in outputs:
+        visit(node)
     return order

@@ -53,26 +53,45 @@ def online_softmax_finish(carry, dtype):
     return (o / norm).astype(dtype)
 
 
-def attention_mask(q_pos, k_pos, causal, lengths):
-    """[B or 1, 1, Lq, Lk] bool, or None when every key may be seen."""
+def attention_mask(q_pos, k_pos, causal, lengths, window=None):
+    """[B or 1, 1, Lq, Lk] bool, or None when every key may be seen. With
+    ``window`` a query sees the ``window`` keys that end with its own."""
     mask = None
     if causal:
         mask = (q_pos[:, None] >= k_pos[None, :])[None, None]
+    if window is not None:
+        near = (q_pos[:, None] - k_pos[None, :] < window)[None, None]
+        mask = near if mask is None else mask & near
     if lengths is not None:
         valid = (k_pos[None, :] < lengths[:, None])[:, None, None, :]
         mask = valid if mask is None else mask & valid
     return mask
 
 
+def key_blocks(t, block, causal=True, window=None):
+    """[(first, end)] of the key blocks each block of queries visits, for
+    ``t`` positions in blocks of ``block``: a causal block stops at its
+    own diagonal block, and with ``window`` it starts at the block that
+    holds the oldest key its first query sees."""
+    block = min(block, t)
+    n = -(-t // block)
+    return [(0 if window is None
+             else max(0, (i * block - window + 1) // block),
+             i + 1 if causal else n) for i in range(n)]
+
+
 def blockwise_attention(q, k, v, scale, causal=True, lengths=None,
-                        block=512):
-    """Attention of q [B, T, H, D] over k, v [B, T, KV, D] (H a multiple
-    of KV: each group of H // KV query heads shares one key-value head),
-    scores scaled by ``scale``, without a [T, T] score matrix: queries go
-    a block at a time, and for each the keys stream through the online
-    softmax a block at a time, every step recomputed in backward. A
-    causal query block stops at its own diagonal block. ``lengths`` [B]
-    hides the keys of the padded tail."""
+                        block=512, window=None):
+    """Attention of q [B, T, H, D] over k [B, T, KV, D] and v
+    [B, T, KV, Dv] (H a multiple of KV: each group of H // KV query heads
+    shares one key-value head), scores scaled by ``scale``, without a
+    [T, T] score matrix: queries go a block at a time, and for each the
+    keys stream through the online softmax a block at a time, every step
+    recomputed in backward. A causal query block stops at its own
+    diagonal block. ``lengths`` [B] hides the keys of the padded tail.
+    With ``window`` (causal) a query sees the ``window`` keys that end
+    with its own: key blocks wholly older than that are not visited
+    (:func:`key_blocks`), the block on the window's edge is masked."""
     b, t, h, d = q.shape
     groups = h // k.shape[2]
     if groups > 1:
@@ -87,7 +106,8 @@ def blockwise_attention(q, k, v, scale, causal=True, lengths=None,
     n = (t + pad) // block
     # [n, B, block, H, D]: one block of keys a scan step
     k_blocks, v_blocks = (
-        jnp.moveaxis(a.reshape(b, n, block, h, d), 1, 0) for a in (k, v))
+        jnp.moveaxis(a.reshape(b, n, block, h, a.shape[-1]), 1, 0)
+        for a in (k, v))
     offsets = jnp.arange(n) * block
     within = jnp.arange(block)
 
@@ -95,16 +115,16 @@ def blockwise_attention(q, k, v, scale, causal=True, lengths=None,
     def step(carry, q_blk, xs, q_start):
         k_blk, v_blk, k_start = xs
         mask = attention_mask(q_start + within, k_start + within, causal,
-                              lengths)
+                              lengths, window)
         return online_softmax_step(carry, q_blk, k_blk, v_blk, scale, mask)
 
     out = []
-    for i in range(n):
+    for i, (first, end) in enumerate(key_blocks(t + pad, block, causal,
+                                                window)):
         q_blk = q[:, i * block:(i + 1) * block]
-        seen = i + 1 if causal else n
         carry, _ = jax.lax.scan(
             lambda c, xs: (step(c, q_blk, xs, i * block), None),
-            online_softmax_init(b, block, h, d, q.dtype),
-            (k_blocks[:seen], v_blocks[:seen], offsets[:seen]))
+            online_softmax_init(b, block, h, v.shape[-1], q.dtype),
+            (k_blocks[first:end], v_blocks[first:end], offsets[first:end]))
         out.append(online_softmax_finish(carry, q.dtype))
     return jnp.concatenate(out, axis=1)[:, :t]

@@ -1,5 +1,6 @@
-"""State-space mixing for Mamba-2 layers: the chunked scan and the causal
-depthwise convolution in front of it.
+"""State-space mixing for Mamba layers: the chunked scan of Mamba-2, the
+selective scan of Mamba-1, and the causal depthwise convolution in front
+of both.
 
 The recurrence, a head at a time (x_t [P], B_t and C_t [N], dt_t and A
 scalars, state S [P, N]):
@@ -13,6 +14,15 @@ tokens, each chunk adds one state, and only those states are carried from
 chunk to chunk by ``lax.scan``. Plain XLA; its backward is what autodiff
 makes of it. Decays and their exponentials are float32 whatever the
 operands are.
+
+Mamba-1 (Gu & Dao 2023, arXiv:2312.00752, Algorithm 2) has a decay of its
+own for every channel c and state n, so no chunk is a matrix product:
+
+    S_t[c, n] = exp(dt_t[c] A[c, n]) S_{t-1}[c, n] + dt_t[c] x_t[c] B_t[n]
+    y_t[c] = sum_n S_t[c, n] C_t[n] + D[c] x_t[c]
+
+:func:`selective_scan` steps it token by token, a chunk of tokens at a
+time, and keeps for backward the state that enters each chunk only.
 """
 
 import jax
@@ -130,3 +140,63 @@ def _ssd_scan(x, dt, a, b_mat, c_mat, d_skip, chunk, lengths, initial_state,
     if return_state:
         return y, last.reshape(batch, heads, p, n)
     return y
+
+
+def selective_scan(x, dt, a, b_mat, c_mat, d_skip=None, chunk=16,
+                   lengths=None, initial_state=None):
+    """Mamba-1's recurrence for x [B, T, E], dt [B, T, E] (positive),
+    a [E, N] (negative), b_mat and c_mat [B, T, N], d_skip [E]: the state
+    [B, E, N] is stepped token by token in float32, exactly (no quotient
+    of decays, so no dt * |a| is too large); backward keeps the state that
+    enters each chunk of ``chunk`` tokens and steps the chunk again, so no
+    [T, E, N] value is alive for a whole row. ``lengths`` [B] freezes the
+    state over the padded tail and zeroes its outputs. Returns
+    (y [B, T, E], the state after the last valid token [B, E, N])."""
+    with jax.named_scope("paddle_tpu.selective_scan"):
+        return _selective_scan(x, dt, a, b_mat, c_mat, d_skip, chunk,
+                               lengths, initial_state)
+
+
+def _selective_scan(x, dt, a, b_mat, c_mat, d_skip, chunk, lengths,
+                    initial_state):
+    batch, t, channels = x.shape
+    n = a.shape[1]
+    wide = dtype_mod.wide(x.dtype)
+    dt = dt.astype(wide)
+    if lengths is not None:
+        dt = jnp.where(_valid(lengths, t)[..., None], dt, 0)
+    chunk = min(chunk, t)
+    pad = -t % chunk
+    nc = (t + pad) // chunk
+
+    def by_chunk(v):   # [B, T, W] -> [nc, chunk, B, W]; dt 0 over the padding
+        v = jnp.pad(v, ((0, 0), (0, pad), (0, 0)))
+        return jnp.moveaxis(v, 1, 0).reshape((nc, chunk) + v.shape[:1]
+                                             + v.shape[2:])
+
+    # the state as [B, N, E]: the channels lie along the lanes
+    a_t = a.astype(wide).T
+
+    def token(state, xs):
+        dt_t, x_t, b_t, c_t = xs           # [B, E] [B, E] [B, N] [B, N]
+        state = jnp.exp(dt_t[:, None, :] * a_t) * state \
+            + (dt_t * x_t)[:, None, :] * b_t[:, :, None]
+        return state, jnp.sum(state * c_t[:, :, None], axis=1)
+
+    @jax.checkpoint
+    def one_chunk(state, xs):
+        return jax.lax.scan(token, state, xs)
+
+    if initial_state is None:
+        state0 = jnp.zeros((batch, n, channels), wide)
+    else:
+        state0 = jnp.swapaxes(initial_state.astype(wide), 1, 2)
+    last, y = jax.lax.scan(
+        one_chunk, state0,
+        tuple(by_chunk(v.astype(wide)) for v in (dt, x, b_mat, c_mat)))
+    y = jnp.moveaxis(y.reshape(t + pad, batch, channels), 0, 1)[:, :t]
+    if d_skip is not None:
+        y = y + x.astype(wide) * d_skip.astype(wide)
+    if lengths is not None:
+        y = jnp.where(_valid(lengths, t)[..., None], y, 0)
+    return y.astype(x.dtype), jnp.swapaxes(last, 1, 2)

@@ -161,12 +161,28 @@ class Topology:
         values = self._run_nodes(params, feed, ctx)
         if mode == "train":
             # set as the step program is traced: what its recomputed
-            # blocks keep for backward (layer/decoder.py recompute)
-            observe_metrics.get_registry().gauge(
-                "paddle_tpu_recompute_kept_bytes",
-                help="bytes a train step keeps inside recomputed blocks "
-                     "besides their inputs, of the program traced last"
-            ).set(ctx.recompute_kept_bytes)
+            # blocks keep for backward, what they hand to later blocks
+            # (layer/decoder.py recompute), which key blocks attention
+            # visits (gqa_attention)
+            registry = observe_metrics.get_registry()
+            for name, value, help_ in (
+                    ("recompute_kept_bytes", ctx.recompute_kept_bytes,
+                     "bytes a train step keeps inside recomputed blocks "
+                     "besides their inputs"),
+                    ("shared_across_blocks_bytes",
+                     ctx.shared_across_blocks_bytes,
+                     "bytes of the values a train step's recomputed blocks "
+                     "hand out to later blocks beside the stream"),
+                    ("attention_key_blocks_visited",
+                     ctx.attention_key_blocks["visited"],
+                     "key blocks a train step's attention layers visit, "
+                     "summed over their query blocks"),
+                    ("attention_key_blocks_possible",
+                     ctx.attention_key_blocks["possible"],
+                     "key blocks at or under the diagonal of a train "
+                     "step's attention layers: visited without a window")):
+                registry.gauge("paddle_tpu_" + name, help=help_
+                               + ", of the program traced last").set(value)
         wanted = outputs or [o.name for o in self.outputs]
         return {name: _external(values[name]) for name in wanted}, \
             ctx.state_updates

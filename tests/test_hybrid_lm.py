@@ -447,7 +447,7 @@ def _olmo_feed(cfg, topo, seed=3):
 def test_the_olmo_preset_is_built_from_the_table_of_mixers(olmo):
     kinds = olmo["layer_types"][:olmo["num_hidden_layers"]]
     assert kinds == ["linear_attention"] * 3 + ["full_attention"]
-    assert set(hybrid_lm.MIXERS) == {"mamba", "attention",
+    assert set(hybrid_lm.MIXERS) >= {"mamba", "attention",
                                      "linear_attention", "full_attention"}
     cost, topo, params = _olmo_program(olmo)
     assert set(topo.param_specs()) == set(params)
@@ -563,8 +563,8 @@ def _no_mixer_offers_a_name(monkeypatch):
     """``MIXERS`` as it was before a kind of mixer named a value for the
     block round it to keep."""
     monkeypatch.setattr(hybrid_lm, "MIXERS", {
-        kind: (mixer, group, ())
-        for kind, (mixer, group, _) in hybrid_lm.MIXERS.items()})
+        kind: mixer._replace(keeps=())
+        for kind, mixer in hybrid_lm.MIXERS.items()})
 
 
 def _trainers_step(build, cfg, cell, monkeypatch):
@@ -634,8 +634,14 @@ def test_a_mamba_layer_keeps_its_first_product_by_its_kind(cfg, monkeypatch):
 
 
 def test_the_table_of_mixers_says_what_each_kind_offers():
-    offered = {kind: names for kind, (_, _, names)
-               in hybrid_lm.MIXERS.items()}
+    offered = {kind: mixer.keeps for kind, mixer in hybrid_lm.MIXERS.items()}
     assert offered == {"mamba": (decoder.MAMBA_IN_PRODUCT,),
+                       "mamba1": (decoder.MAMBA1_IN_PRODUCT,),
                        "attention": (), "full_attention": (),
-                       "linear_attention": ()}
+                       "sliding_attention": (), "cross_attention": (),
+                       "linear_attention": (), "gmu": ()}
+    # what a kind hands to later layers, and what it reads of an earlier one
+    assert {k: (m.makes, m.reads) for k, m in hybrid_lm.MIXERS.items()
+            if m.makes or m.reads} == {
+        "mamba1": ("memory", None), "full_attention": ("kv", None),
+        "gmu": (None, "memory"), "cross_attention": (None, "kv")}
