@@ -67,6 +67,8 @@ FULL = {
     "lstm_checks": [(256, "bfloat16"), (256, "float32"),
                     (1280, "bfloat16"), (1280, "float32")],
     "gru_checks": [(256, "float32"), (256, "bfloat16")],
+    # (dtype, tokens): three blocks of 128, the last one padded
+    "scan_checks": [("float32", 300), ("bfloat16", 300)],
     # ROADMAP Queue 1 #2's serve model and exp_serve.py's export shape
     "tagger": {"dict_size": 1000, "label_size": 32, "emb_size": 32,
                "hidden": 256},
@@ -81,6 +83,7 @@ TINY = {
     "batch": 4, "seq": 6, "steps": 2, "k": 2, "chunks": 1,
     "lstm_checks": [(16, "float32"), (16, "bfloat16"), (256, "float32")],
     "gru_checks": [(16, "float32")],
+    "scan_checks": [("float32", 20)],
     "tagger": {"dict_size": 50, "label_size": 4, "emb_size": 8, "hidden": 8},
     "batch_sizes": "1,2", "seq_len": 16, "slots": 4, "window": 3,
     "request_lens": [5, 2, 16, 9],
@@ -473,6 +476,62 @@ def check_gru_kernel(hidden, dtype_name, mesh=None, rows=8, t=12):
     return label
 
 
+def check_selective_scan_kernel(dtype_name, t, channels=512, states=16):
+    """The two kernels of Mamba-1's scan (ops/pallas_ssm.py) vs the plain
+    loops on this backend: loss and every gradient within GATE_TOL, over
+    lengths and a token count that is no multiple of the block."""
+    import numpy as np
+
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops import pallas_ssm
+    from paddle_tpu.ops import ssm
+
+    require(ssm.selective_scan_form(channels, states) == "fused",
+            "%d channels of %d states do not take the fused scan here",
+            channels, states)
+    dtype = jnp.dtype(dtype_name)
+    rng = np.random.RandomState(t + 11)
+    x = jnp.asarray(rng.randn(2, t, channels), dtype)
+    dt = jax.nn.softplus(jnp.asarray(rng.randn(2, t, channels) - 2.0,
+                                     jnp.float32))
+    a = -jnp.exp(jnp.asarray(rng.randn(channels, states) * 0.5, jnp.float32))
+    b_mat, c_mat = (jnp.asarray(rng.randn(2, t, states), dtype)
+                    for _ in range(2))
+    d_skip = jnp.asarray(rng.randn(channels), jnp.float32)
+    sel = jnp.asarray(rng.randn(2, t, channels), jnp.float32)
+    lengths = jnp.asarray([t, t - t // 4])
+
+    def plain_scan(*args, **kwargs):
+        # the oracle: selective_scan with the kernels refused for this one
+        # trace, the smoke's only way to the loops on a backend that fits
+        fits, pallas_ssm.fits = pallas_ssm.fits, lambda *_: False
+        try:
+            return ssm.selective_scan(*args, **kwargs)
+        finally:
+            pallas_ssm.fits = fits
+
+    def loss(fused, *args):
+        scan = ssm.selective_scan if fused else plain_scan
+        y, last = scan(*args, lengths=lengths)
+        return jnp.sum(y.astype(jnp.float32) * sel) + jnp.sum(last)
+
+    @jax.jit
+    def both(*args):
+        return tuple(jax.value_and_grad(lambda *a: loss(fused, *a),
+                                        argnums=range(6))(*args)
+                     for fused in (False, True))
+
+    label = "selective_scan[t=%d,e=%d,n=%d,%s]" % (t, channels, states,
+                                                   dtype_name)
+    (ref, gr), (fus, gf) = jax.device_get(both(x, dt, a, b_mat, c_mat,
+                                               d_skip))
+    _require_close(label, GATE_TOL[dtype_name], ref, fus,
+                   list(zip(gf, gr, ("x", "dt", "A", "B", "C", "D"))))
+    return label
+
+
 def _require_close(label, tol, ref, fus, grads):
     require(abs(float(fus) - float(ref)) / max(1.0, abs(float(ref))) < tol,
             "%s fwd mismatch: %r vs %r", label, float(fus), float(ref))
@@ -485,8 +544,11 @@ def phase_kernels(cfg, mesh=None):
     from paddle_tpu.ops import pallas_kernels as pk
 
     require(pk.enabled(), "fused kernels are off on this backend")
+    scans = [] if mesh is not None else [   # one device: see pallas_ssm.fits
+        check_selective_scan_kernel(dt, t) for dt, t in cfg["scan_checks"]]
     return ([check_lstm_kernel(h, dt, mesh) for h, dt in cfg["lstm_checks"]]
-            + [check_gru_kernel(h, dt, mesh) for h, dt in cfg["gru_checks"]])
+            + [check_gru_kernel(h, dt, mesh) for h, dt in cfg["gru_checks"]]
+            + scans)
 
 
 def phase_serve_reference(cfg):
@@ -800,6 +862,9 @@ def phase_shared_blocks():
     cell = _read_json(os.path.join(
         tiny, "workloads",
         "phi-4-mini-flash-reasoning-seq4096-bs2-train.json"))
+    # 128 channels of 8 states, where the preset has 4: the narrowest
+    # Mamba-1 layer that the fused scan takes (ops/pallas_ssm.py fits)
+    cfg["mamba_d_state"] = 8
     L.reset_name_counters()
     cost = hybrid_lm.from_config(cfg)[3]
     trainer = paddle.trainer.SGD(
@@ -825,8 +890,17 @@ def phase_shared_blocks():
                          for k in ("visited", "possible"))
     require(0 < visited <= possible, "attention visited %r of %r key blocks",
             visited, possible)
+    scans = sum(cfg["layer_types"][i] == "mamba1"
+                for i in cfg["kept_layers"])
+    fused, plain = (gauges["paddle_tpu_selective_scan_" + k]
+                    for k in ("fused", "plain"))
+    print("selective scans of the traced step: %d fused, %d plain"
+          % (fused, plain), flush=True)
+    require((fused, plain) == (scans, 0), "of %d Mamba-1 scans %r ran as "
+            "the fused kernels and %r as plain loops", scans, fused, plain)
     return {"costs": costs, "shared_across_blocks_bytes": shared,
-            "attention_key_blocks": [visited, possible]}
+            "attention_key_blocks": [visited, possible],
+            "selective_scans": {"fused": fused, "plain": plain}}
 
 
 def stage_chip(args):

@@ -132,6 +132,9 @@ class Context:
         # layers visit of those at or under the diagonal (layer/decoder.py)
         self.shared_across_blocks_bytes = 0
         self.attention_key_blocks = {"visited": 0, "possible": 0}
+        # this trace's Mamba-1 scans by the form they took
+        # (ops/ssm.py selective_scan_form)
+        self.selective_scans = {"fused": 0, "plain": 0}
         # streaming-decode carry threading (serve/export.py decode step):
         # when ``decode_state`` is a dict, recurrent layers read their
         # initial carry from it (decode_state[layer_name] = [leaf, ...];

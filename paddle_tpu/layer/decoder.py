@@ -283,8 +283,11 @@ def mamba1(input, state=16, conv_width=4, expand=2, dt_rank=None, chunk=16,
         S_t[c, n] = exp(dt_t[c] A[c, n]) S_{t-1}[c, n] + dt_t[c] x_t[c] B_t[n]
         y_t[c] = sum_n S_t[c, n] C_t[n] + D[c] x_t[c]
         out = (y * silu(z)) W_out
-    the scan token by token, kept by chunks of ``chunk``
-    (``ops/ssm.py selective_scan``). Parameters ``<name>.in_proj``,
+    the scan token by token (``ops/ssm.py selective_scan``): fused Pallas
+    kernels on the TPU where ``expand * d`` is a multiple of 128 and
+    ``state`` of 8, else plain loops kept by chunks of ``chunk``; the
+    gauges ``paddle_tpu_selective_scan_fused`` / ``_plain`` count a traced
+    step's scans by form. Parameters ``<name>.in_proj``,
     ``.conv_w`` [E, K], ``.conv_b``, ``.x_proj``, ``.dt_proj``,
     ``.dt_bias``, ``.A_log``, ``.D``, ``.out_proj``; no bias on the
     projections. The first product, before the split, carries the name
@@ -336,6 +339,8 @@ def mamba1(input, state=16, conv_width=4, expand=2, dt_rank=None, chunk=16,
                 jnp.matmul(r, p["dt_proj"],
                            preferred_element_type=upcast_f32(r).dtype)
                 + upcast_f32(p["dt_bias"]))
+            ctx.selective_scans[
+                ssm_ops.selective_scan_form(inner, state)] += 1
             y, _ = ssm_ops.selective_scan(
                 x, dt, -jnp.exp(upcast_f32(p["A_log"])), b_mat, c_mat,
                 p["D"], chunk, seq.lengths)

@@ -21,14 +21,17 @@ own for every channel c and state n, so no chunk is a matrix product:
     S_t[c, n] = exp(dt_t[c] A[c, n]) S_{t-1}[c, n] + dt_t[c] x_t[c] B_t[n]
     y_t[c] = sum_n S_t[c, n] C_t[n] + D[c] x_t[c]
 
-:func:`selective_scan` steps it token by token, a chunk of tokens at a
-time, and keeps for backward the state that enters each chunk only.
+:func:`selective_scan` steps it token by token and keeps for backward the
+state that enters each block of tokens only: on the TPU, at widths that
+tile, as two fused Pallas kernels (``ops/pallas_ssm.py``); everywhere
+else as two nested ``lax.scan``s, which are also the kernels' oracle.
 """
 
 import jax
 import jax.numpy as jnp
 
 from paddle_tpu.core import dtype as dtype_mod
+from paddle_tpu.ops import pallas_ssm
 
 
 def causal_conv1d(x, weight, bias=None, lengths=None):
@@ -142,29 +145,56 @@ def _ssd_scan(x, dt, a, b_mat, c_mat, d_skip, chunk, lengths, initial_state,
     return y
 
 
+def selective_scan_form(channels, states):
+    """"fused" where :func:`selective_scan` runs ``ops/pallas_ssm.py``'s
+    two kernels, "plain" where it runs the loops of this module: the
+    backend and the shapes decide (``pallas_ssm.fits``), nothing else."""
+    return "fused" if pallas_ssm.fits(channels, states) else "plain"
+
+
 def selective_scan(x, dt, a, b_mat, c_mat, d_skip=None, chunk=16,
                    lengths=None, initial_state=None):
     """Mamba-1's recurrence for x [B, T, E], dt [B, T, E] (positive),
     a [E, N] (negative), b_mat and c_mat [B, T, N], d_skip [E]: the state
     [B, E, N] is stepped token by token in float32, exactly (no quotient
-    of decays, so no dt * |a| is too large); backward keeps the state that
-    enters each chunk of ``chunk`` tokens and steps the chunk again, so no
-    [T, E, N] value is alive for a whole row. ``lengths`` [B] freezes the
+    of decays, so no dt * |a| is too large). ``lengths`` [B] freezes the
     state over the padded tail and zeroes its outputs. Returns
-    (y [B, T, E], the state after the last valid token [B, E, N])."""
+    (y [B, T, E], the state after the last valid token [B, E, N]).
+
+    Two forms of the one recurrence (:func:`selective_scan_form`). On the
+    TPU, where the channels are a multiple of 128 and the states of 8, the
+    fused one: two Pallas kernels that keep the state in VMEM and for
+    backward the state that enters each block of tokens
+    (``ops/pallas_ssm.py``). Everywhere else (the CPU, narrow layers) the
+    plain one, which is also the kernels' oracle: ``lax.scan`` over chunks
+    of ``chunk`` tokens round ``lax.scan`` over a chunk's tokens; backward
+    keeps the state that enters each chunk and steps the chunk again.
+    ``chunk`` means something to the plain form only. Neither keeps a
+    [T, E, N] value alive for a whole row."""
     with jax.named_scope("paddle_tpu.selective_scan"):
-        return _selective_scan(x, dt, a, b_mat, c_mat, d_skip, chunk,
-                               lengths, initial_state)
+        t = x.shape[1]
+        wide = dtype_mod.wide(x.dtype)
+        dt = dt.astype(wide)
+        if lengths is not None:
+            dt = jnp.where(_valid(lengths, t)[..., None], dt, 0)
+        if selective_scan_form(x.shape[2], a.shape[1]) == "fused":
+            y, last = pallas_ssm.selective_scan(x, dt, a, b_mat, c_mat,
+                                                initial_state)
+        else:
+            y, last = _token_loops(x, dt, a, b_mat, c_mat, chunk,
+                                   initial_state)
+        if d_skip is not None:
+            y = y + x.astype(wide) * d_skip.astype(wide)
+        if lengths is not None:
+            y = jnp.where(_valid(lengths, t)[..., None], y, 0)
+        return y.astype(x.dtype), last
 
 
-def _selective_scan(x, dt, a, b_mat, c_mat, d_skip, chunk, lengths,
-                    initial_state):
+def _token_loops(x, dt, a, b_mat, c_mat, chunk, initial_state):
+    """The plain form: (y [B, T, E] wide, the last state [B, E, N])."""
     batch, t, channels = x.shape
     n = a.shape[1]
-    wide = dtype_mod.wide(x.dtype)
-    dt = dt.astype(wide)
-    if lengths is not None:
-        dt = jnp.where(_valid(lengths, t)[..., None], dt, 0)
+    wide = dt.dtype
     chunk = min(chunk, t)
     pad = -t % chunk
     nc = (t + pad) // chunk
@@ -195,8 +225,4 @@ def _selective_scan(x, dt, a, b_mat, c_mat, d_skip, chunk, lengths,
         one_chunk, state0,
         tuple(by_chunk(v.astype(wide)) for v in (dt, x, b_mat, c_mat)))
     y = jnp.moveaxis(y.reshape(t + pad, batch, channels), 0, 1)[:, :t]
-    if d_skip is not None:
-        y = y + x.astype(wide) * d_skip.astype(wide)
-    if lengths is not None:
-        y = jnp.where(_valid(lengths, t)[..., None], y, 0)
-    return y.astype(x.dtype), jnp.swapaxes(last, 1, 2)
+    return y, jnp.swapaxes(last, 1, 2)

@@ -163,7 +163,8 @@ class Topology:
             # set as the step program is traced: what its recomputed
             # blocks keep for backward, what they hand to later blocks
             # (layer/decoder.py recompute), which key blocks attention
-            # visits (gqa_attention)
+            # visits (gqa_attention), which form its Mamba-1 scans took
+            # (mamba1)
             registry = observe_metrics.get_registry()
             for name, value, help_ in (
                     ("recompute_kept_bytes", ctx.recompute_kept_bytes,
@@ -180,7 +181,13 @@ class Topology:
                     ("attention_key_blocks_possible",
                      ctx.attention_key_blocks["possible"],
                      "key blocks at or under the diagonal of a train "
-                     "step's attention layers: visited without a window")):
+                     "step's attention layers: visited without a window"),
+                    ("selective_scan_fused", ctx.selective_scans["fused"],
+                     "Mamba-1 scans of a train step that run as the fused "
+                     "Pallas kernels"),
+                    ("selective_scan_plain", ctx.selective_scans["plain"],
+                     "Mamba-1 scans of a train step that run as plain "
+                     "loops")):
                 registry.gauge("paddle_tpu_" + name, help=help_
                                + ", of the program traced last").set(value)
         wanted = outputs or [o.name for o in self.outputs]
