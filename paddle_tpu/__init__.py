@@ -57,7 +57,14 @@ _SUBMODULES = (
 
 def __getattr__(name):
     if name in _SUBMODULES:
-        mod = _importlib.import_module("paddle_tpu." + name)
+        from paddle_tpu.observe import spans as observe_spans
+
+        # the set-up span `import` (docs/observability.md): the first
+        # `paddle.layer` of a process imports the layers, ops and kernels
+        # (a first use inside another's import is that one's time)
+        with observe_spans.phase("import", args={"module": name},
+                                 unless_in="import"):
+            mod = _importlib.import_module("paddle_tpu." + name)
         globals()[name] = mod
         return mod
     if name == "infer":
@@ -90,45 +97,52 @@ def init(use_tpu=None, trainer_count=1, seed=None, log_level=None, **kwargs):
     ``trainer_count`` declares the data-parallel width used by
     :mod:`paddle_tpu.parallel` when building the device mesh. Also places
     the persistent compile cache (:mod:`paddle_tpu.utils.compile_cache`).
+    The body is the set-up span ``init`` (docs/observability.md).
     """
     global _initialized
-    import jax
+    from paddle_tpu.observe import spans as observe_spans
 
-    from paddle_tpu.core.place import backend_initialized, tpu_devices
-    from paddle_tpu.utils import compile_cache
+    with observe_spans.phase("init"):
+        import jax
 
-    compile_cache.enable()
-    if use_tpu is not None and not use_tpu:
-        # really the CPU: pin the platform while that is still possible,
-        # and refuse once JAX has already opened another backend
-        if not backend_initialized():
-            jax.config.update("jax_platforms", "cpu")
-        enforce(jax.default_backend() == "cpu",
-                "init(use_tpu=False) after JAX initialised the %r backend: "
-                "call init before any other JAX use, or set "
-                "JAX_PLATFORMS=cpu", jax.default_backend())
-    elif use_tpu:
-        tpu_devices()  # EnforceError naming jax.devices() when none is a TPU
-    else:
-        use_tpu = jax.default_backend() == "tpu"
-    _flags.set_flag("use_tpu", bool(use_tpu))
-    _flags.set_flag("trainer_count", int(trainer_count))
-    if seed is not None:
-        _flags.set_flag("seed", int(seed))
-    for key, value in kwargs.items():
-        _flags.set_flag(key, value, create=True)
-    if log_level is not None:
-        from paddle_tpu.utils import logger as _logger
+        from paddle_tpu.core.place import backend_initialized, tpu_devices
+        from paddle_tpu.utils import compile_cache
 
-        _logger.set_level(log_level)
-    # FPE-trap parity (reference: feenableexcept(FE_INVALID|FE_DIVBYZERO|
-    # FE_OVERFLOW) at trainer start, TrainerMain.cpp:49): fail fast on
-    # NaN/Inf from jitted programs instead of training through garbage.
-    # Set unconditionally so re-init with trap_fpe=False turns it back off.
-    _trap = bool(_flags.get_flag("trap_fpe"))
-    jax.config.update("jax_debug_nans", _trap)
-    jax.config.update("jax_debug_infs", _trap)
-    set_default_place(TPUPlace() if use_tpu else CPUPlace())
+        compile_cache.enable()
+        if use_tpu is not None and not use_tpu:
+            # really the CPU: pin the platform while that is still
+            # possible, and refuse once JAX has already opened another
+            # backend
+            if not backend_initialized():
+                jax.config.update("jax_platforms", "cpu")
+            enforce(jax.default_backend() == "cpu",
+                    "init(use_tpu=False) after JAX initialised the %r "
+                    "backend: call init before any other JAX use, or set "
+                    "JAX_PLATFORMS=cpu", jax.default_backend())
+        elif use_tpu:
+            # EnforceError naming jax.devices() when none is a TPU
+            tpu_devices()
+        else:
+            use_tpu = jax.default_backend() == "tpu"
+        _flags.set_flag("use_tpu", bool(use_tpu))
+        _flags.set_flag("trainer_count", int(trainer_count))
+        if seed is not None:
+            _flags.set_flag("seed", int(seed))
+        for key, value in kwargs.items():
+            _flags.set_flag(key, value, create=True)
+        if log_level is not None:
+            from paddle_tpu.utils import logger as _logger
+
+            _logger.set_level(log_level)
+        # FPE-trap parity (reference: feenableexcept(FE_INVALID|
+        # FE_DIVBYZERO|FE_OVERFLOW) at trainer start, TrainerMain.cpp:49):
+        # fail fast on NaN/Inf from jitted programs instead of training
+        # through garbage. Set unconditionally so re-init with
+        # trap_fpe=False turns it back off.
+        _trap = bool(_flags.get_flag("trap_fpe"))
+        jax.config.update("jax_debug_nans", _trap)
+        jax.config.update("jax_debug_infs", _trap)
+        set_default_place(TPUPlace() if use_tpu else CPUPlace())
     _initialized = True
     return None
 

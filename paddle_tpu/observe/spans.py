@@ -56,7 +56,36 @@ import threading
 import time
 from contextlib import contextmanager
 
+from paddle_tpu.observe import metrics as observe_metrics
 from paddle_tpu.utils.stat import global_stats
+
+# What a process does before its first step and round each `train` call
+# (docs/observability.md "Set-up spans"): span name -> (always-on registry
+# histogram, help). One observation an occurrence, in ms, made by
+# :func:`phase`.
+PHASE_HISTOGRAMS = {
+    "import": ("paddle_tpu_setup_import_ms",
+               "one first use of a paddle.<submodule>: its import"),
+    "init": ("paddle_tpu_setup_init_ms",
+             "one paddle.init: the compile cache placed, the backend "
+             "opened where the caller has not"),
+    "params_create": ("paddle_tpu_setup_params_create_ms",
+                      "one Parameters.create: every leaf initialised"),
+    "params_update": ("paddle_tpu_setup_params_update_ms",
+                      "one Parameters.update_from called by a user (not "
+                      "the one inside sync_back)"),
+    "trainer_prepare": ("paddle_tpu_setup_trainer_prepare_ms",
+                        "one SGD.__init__: topology, step functions, "
+                        "masters, replica and optimizer slots handed to "
+                        "the device"),
+    "train_enter": ("paddle_tpu_train_enter_ms",
+                    "one train call from its entry to its feeder built"),
+    "train_exit": ("paddle_tpu_train_exit_ms",
+                   "one train call from its last step read back to its "
+                   "return, sync_back included"),
+    "sync_back": ("paddle_tpu_train_sync_back_ms",
+                  "one read of every parameter back into Parameters"),
+}
 
 
 def _annotation(name, args):
@@ -212,13 +241,15 @@ class SpanTracer:
         with self._lock:
             return list(self._events)
 
-    def reset(self):
-        """Drop recorded spans and restart the trace clock (the StatSet
-        aggregates are owned by the StatSet and are NOT reset here)."""
+    def reset(self, at=None):
+        """Drop recorded spans and restart the trace clock, now or at the
+        earlier ``perf_counter`` reading ``at`` (where a span that is
+        still open began); the StatSet aggregates are owned by the
+        StatSet and are NOT reset here."""
         with self._lock:
             self._events = []
             self._dropped = 0
-            self._t0 = time.perf_counter()
+            self._t0 = time.perf_counter() if at is None else at
 
     def to_chrome_trace(self):
         """Chrome trace-event dict: ``{"traceEvents": [...]}`` with "X"
@@ -303,6 +334,26 @@ def get_tracer():
 def span(name, sync=None, args=None, trace=None):
     """Module-level shortcut: ``with observe.span("feed"): ...``."""
     return _global_tracer.span(name, sync=sync, args=args, trace=trace)
+
+
+@contextmanager
+def phase(name, args=None, labels=None, unless_in=None):
+    """A span of :data:`PHASE_HISTOGRAMS` on the process-global tracer
+    that also observes its histogram once, in ms, when it closes (also
+    where its body raised: the time was spent). Opened inside a span
+    named ``unless_in`` it is that span's child and observes nothing:
+    its time is already its parent's."""
+    scope = None
+    try:
+        with _global_tracer.span(name, args=args) as scope:
+            yield scope
+    finally:
+        if scope is not None and (unless_in is None
+                                  or scope.parent != unless_in):
+            hist, help = PHASE_HISTOGRAMS[name]
+            # looked up an occurrence, not held: a handful a process
+            observe_metrics.get_registry().histogram(
+                hist, help=help, labels=labels).observe(scope.dur * 1e3)
 
 
 def export(path):

@@ -138,13 +138,12 @@ import weakref
 SCHEMA_VERSION = 1
 
 # StepLogs (and CompileWatchers) currently subscribed to jax.monitoring
-# events. Weak so a log that was never closed (crashed run) doesn't stay
-# pinned by the listener. Mutated only under _registry_lock: subscribers
+# duration events (through utils/compile_cache.py's one listener). Weak
+# so a log that was never closed (crashed run) doesn't stay pinned by it. Mutated only under _registry_lock: subscribers
 # come and go from arbitrary threads while the listener fans out.
 _registry_lock = threading.Lock()
 _open_logs = weakref.WeakSet()
 _compile_watchers = weakref.WeakSet()
-_listener_registered = False
 # every live StepLog, whether or not it subscribed to compile events —
 # the atexit durability guard flushes these so flush_every=N batching
 # (serving logs) cannot drop its last <N buffered records when the
@@ -179,30 +178,30 @@ def _ensure_atexit():
 COMPILE_EVENT_MARKERS = ("backend_compile",)
 
 
-def _ensure_monitoring_listener():
-    """Register the ONE process-wide jax.monitoring duration listener
-    (registration is append-only in jax — there is no unregister)."""
-    global _listener_registered
-    from jax import monitoring
-
-    def _listener(event, secs, **kw):
-        # snapshot under the same lock the writers take: WeakSet
-        # iteration races with add/discard from other threads otherwise
-        with _registry_lock:
-            logs = list(_open_logs)
-            watchers = list(_compile_watchers)
-        for log in logs:
-            log._on_monitoring_event(event, secs)
-        for watcher in watchers:
-            watcher._on_monitoring_event(event, secs)
-
+def _on_duration_event(event, secs):
+    """Every ``jax.monitoring`` duration event of the process, handed on
+    by ``utils/compile_cache.py``'s listener: fan it out to the open logs
+    and the live watchers."""
+    # snapshot under the same lock the writers take: WeakSet
+    # iteration races with add/discard from other threads otherwise
     with _registry_lock:
-        if _listener_registered:
-            return
-        # plainly: a registration that failed quietly would make every
-        # "zero compiles after warm-up" check pass by counting nothing
-        monitoring.register_event_duration_secs_listener(_listener)
-        _listener_registered = True
+        logs = list(_open_logs)
+        watchers = list(_compile_watchers)
+    for log in logs:
+        log._on_monitoring_event(event, secs)
+    for watcher in watchers:
+        watcher._on_monitoring_event(event, secs)
+
+
+def _ensure_monitoring_listener():
+    """Subscribe, once, to the process's ONE jax.monitoring duration
+    listener (``utils/compile_cache.py listen``: it also times the
+    compile phases, so what is counted here is what is timed there)."""
+    from paddle_tpu.utils import compile_cache
+
+    # plainly: a subscription that failed quietly would make every
+    # "zero compiles after warm-up" check pass by counting nothing
+    compile_cache.subscribe(_on_duration_event)
 
 
 class CompileWatcher:
@@ -346,10 +345,10 @@ class StepLog:
 
     def _subscribe_compile_events(self):
         """Mirror jax.monitoring duration events (compile times and
-        friends) into the log. Listener registration is append-only in
-        jax, so ONE module-level listener fans out to the currently-open
-        logs (weakly held, dropped on close) — constructing many StepLogs
-        in one process must not accumulate dead listeners."""
+        friends) into the log. ONE module-level subscriber of the
+        process's one listener fans out to the currently-open logs
+        (weakly held, dropped on close) — constructing many StepLogs in
+        one process must not accumulate dead listeners."""
         _ensure_monitoring_listener()
         with _registry_lock:
             _open_logs.add(self)

@@ -15,6 +15,7 @@ import time
 
 import numpy as np
 
+from paddle_tpu.observe import spans as observe_spans
 from paddle_tpu.utils.error import enforce
 
 
@@ -29,20 +30,21 @@ class Parameters:
     @staticmethod
     def create(topology_or_cost, rng=None, dtype=None):
         """Create and initialize parameters for a topology (v2
-        paddle.parameters.create parity)."""
+        paddle.parameters.create parity). The set-up span
+        ``params_create`` (docs/observability.md)."""
         from paddle_tpu.topology import Topology
         from paddle_tpu.graph import LayerNode
-
-        topo = topology_or_cost
         from paddle_tpu.multi_network import MultiNetwork
 
-        if isinstance(topo, MultiNetwork):
-            topo = Topology(topo.costs)
-        elif isinstance(topo, (LayerNode, list)):
-            topo = Topology(topo)
-        params = Parameters()
-        params._specs = dict(topo.param_specs())
-        params._values = dict(topo.init_params(rng=rng, dtype=dtype))
+        with observe_spans.phase("params_create"):
+            topo = topology_or_cost
+            if isinstance(topo, MultiNetwork):
+                topo = Topology(topo.costs)
+            elif isinstance(topo, (LayerNode, list)):
+                topo = Topology(topo)
+            params = Parameters()
+            params._specs = dict(topo.param_specs())
+            params._values = dict(topo.init_params(rng=rng, dtype=dtype))
         return params
 
     # -- dict-like ----------------------------------------------------------
@@ -118,9 +120,13 @@ class Parameters:
         return clone
 
     def update_from(self, values):
-        for key, val in values.items():
-            if key in self._values:
-                self._values[key] = val
+        """Take over the values of the names this object has. The span
+        ``params_update``; inside the trainer's ``sync_back`` it is that
+        span's child and its time is ``sync_back``'s, observed once."""
+        with observe_spans.phase("params_update", unless_in="sync_back"):
+            for key, val in values.items():
+                if key in self._values:
+                    self._values[key] = val
 
     # -- serialization ------------------------------------------------------
     def to_tar(self, f):

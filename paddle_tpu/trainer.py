@@ -15,6 +15,8 @@ batch-sharded inputs and replicated (or ZeRO-sharded) parameters, replacing
 MultiGradientMachine and the pserver path (SURVEY.md §2.4).
 """
 
+import contextlib
+import functools
 import os
 import time
 
@@ -68,25 +70,36 @@ class SGD:
                 "update_equation must be an Optimizer")
         from paddle_tpu.multi_network import MultiNetwork
 
-        if isinstance(cost, MultiNetwork):
-            # multi_nn parity: joint cost = sum_i w_i * mean(cost_i)
-            self.costs = list(cost.costs)
-            self._cost_weights = list(cost.weights)
-        else:
-            self.costs = [cost] if isinstance(cost, LayerNode) else list(cost)
-            self._cost_weights = [1.0] * len(self.costs)
-        extra = [e for e in (extra_layers or [])]
-        self.evaluators = [e for e in extra if getattr(e, "is_evaluator", False)]
-        self.extra_outputs = [e for e in extra if not getattr(e, "is_evaluator", False)]
-        self.topology = Topology(self.costs + self.evaluators + self.extra_outputs)
-        self.parameters = parameters
-        self.optimizer = update_equation
-        self.feeding = feeding
-        self.parallelism = parallelism
-        # the slowest steps with the phases of their wall interval
-        # (_close_step); dumped and reset per pass under PADDLE_TPU_STATS=1
-        self.slow_steps = observe_tracing.TraceExemplars(capacity=5)
-        self.__prepare__()
+        # the set-up span `trainer_prepare` (docs/observability.md): the
+        # topology, the step functions, and masters, replica and optimizer
+        # slots handed to the device (the calls, no wait for the device)
+        placed = sum(int(getattr(v, "nbytes", 0))
+                     for v in parameters.as_dict().values())
+        with self._phase("trainer_prepare", args={"bytes": placed}):
+            if isinstance(cost, MultiNetwork):
+                # multi_nn parity: joint cost = sum_i w_i * mean(cost_i)
+                self.costs = list(cost.costs)
+                self._cost_weights = list(cost.weights)
+            else:
+                self.costs = ([cost] if isinstance(cost, LayerNode)
+                              else list(cost))
+                self._cost_weights = [1.0] * len(self.costs)
+            extra = [e for e in (extra_layers or [])]
+            self.evaluators = [e for e in extra
+                               if getattr(e, "is_evaluator", False)]
+            self.extra_outputs = [e for e in extra
+                                  if not getattr(e, "is_evaluator", False)]
+            self.topology = Topology(self.costs + self.evaluators
+                                     + self.extra_outputs)
+            self.parameters = parameters
+            self.optimizer = update_equation
+            self.feeding = feeding
+            self.parallelism = parallelism
+            # the slowest steps with the phases of their wall interval
+            # (_close_step); dumped and reset per pass under
+            # PADDLE_TPU_STATS=1
+            self.slow_steps = observe_tracing.TraceExemplars(capacity=5)
+            self.__prepare__()
 
     def __prepare__(self):
         trainable_names, static_names, state_names = self.parameters.partition()
@@ -320,152 +333,177 @@ class SGD:
         fused loop (``steps_per_call=K``) checkpoints land at chunk
         boundaries — the first step boundary at or past the cadence.
         """
-        user_handler = event_handler or default_event_handler
-        # what the step thread did since the last finalized step, in ms:
-        # each span of the loop below adds its duration as it closes
-        phases = dict.fromkeys(_STEP_PHASES, 0.0)
+        # `train_enter` runs from here to the feeder built, `train_exit`
+        # from the last step read back to the return (docs/observability.md
+        # "Set-up spans"): both open and close inside `_train_passes`
+        call_start = time.perf_counter()
+        with contextlib.ExitStack() as enter, \
+                contextlib.ExitStack() as leave:
+            enter.enter_context(self._phase("train_enter"))
+            # opens `train_exit` the first time it is called
+            leaving = functools.cache(
+                lambda: leave.enter_context(self._phase("train_exit")))
+            user_handler = event_handler or default_event_handler
+            # what the step thread did since the last finalized step, in ms:
+            # each span of the loop below adds its duration as it closes
+            phases = dict.fromkeys(_STEP_PHASES, 0.0)
 
-        def event_handler(event):
-            with observe_spans.span("handler") as scope:
-                user_handler(event)
-            phases["handler"] += scope.dur * 1e3
+            def event_handler(event):
+                with observe_spans.span("handler") as scope:
+                    user_handler(event)
+                phases["handler"] += scope.dur * 1e3
 
-        feeding = feeding or self.feeding
-        if buckets is not None and buckets is not False:
-            from paddle_tpu.data import bucketing as data_bucketing
+            feeding = feeding or self.feeding
+            if buckets is not None and buckets is not False:
+                from paddle_tpu.data import bucketing as data_bucketing
 
-            opts = dict(buckets) if isinstance(buckets, dict) else {
-                "boundaries": None if buckets is True else buckets}
-            bounds = opts.get("boundaries")
-            reader = data_bucketing.rebucket_batches(
-                reader, buckets=bounds,
-                drop_remainder=bool(opts.get("drop_remainder", False)),
-                length_of=data_bucketing.topology_length_of(
-                    self.topology, feeding))
-        k = int(steps_per_call or 0)
-        if k:
-            enforce(k >= 1, "steps_per_call must be >= 1, got %d", k)
-            enforce(self._train_chunk is not None,
-                    "steps_per_call requires a parallelism with a "
-                    "shard_train_chunk wrapper (%s has none)",
-                    type(self.parallelism).__name__)
-        if os.environ.get("PADDLE_TPU_ANALYZE"):
-            # pre-compile static checks (docs/analyze.md): packing
-            # legality, dtype hazards, donation conflicts — warnings
-            # log, errors raise before the first dispatch
-            from paddle_tpu.analyze.topology_check import pretrain_check
+                opts = dict(buckets) if isinstance(buckets, dict) else {
+                    "boundaries": None if buckets is True else buckets}
+                bounds = opts.get("boundaries")
+                reader = data_bucketing.rebucket_batches(
+                    reader, buckets=bounds,
+                    drop_remainder=bool(opts.get("drop_remainder", False)),
+                    length_of=data_bucketing.topology_length_of(
+                        self.topology, feeding))
+            k = int(steps_per_call or 0)
+            if k:
+                enforce(k >= 1, "steps_per_call must be >= 1, got %d", k)
+                enforce(self._train_chunk is not None,
+                        "steps_per_call requires a parallelism with a "
+                        "shard_train_chunk wrapper (%s has none)",
+                        type(self.parallelism).__name__)
+            if os.environ.get("PADDLE_TPU_ANALYZE"):
+                # pre-compile static checks (docs/analyze.md): packing
+                # legality, dtype hazards, donation conflicts — warnings
+                # log, errors raise before the first dispatch
+                from paddle_tpu.analyze.topology_check import pretrain_check
 
-            pretrain_check(self, steps_per_call=k or None)
+                pretrain_check(self, steps_per_call=k or None)
 
-        # observability: host spans around every phase (feed / device step
-        # / evaluator read-back — they feed the global StatSet, dumped per
-        # pass under PADDLE_TPU_STATS=1, reference: the per-pass
-        # globalStat.printAllStatus dump) and, under
-        # PADDLE_TPU_TELEMETRY=<dir>, a JSONL step log + Chrome-trace
-        # export of the spans (docs/observability.md).
-        tracer = observe_spans.get_tracer()
-        meta = {"phase": "train", "num_passes": int(num_passes)}
-        if k:
-            meta["steps_per_call"] = k
-        # training-fleet identity (observe/trainview.py): a distributed
-        # worker stamps PADDLE_TPU_TRAIN_WORKER before training, and
-        # every artifact this run emits carries it — the steplog meta
-        # plus a per-worker file name (train-t<i>.steps.jsonl), so
-        # `cli observe` can pool a shared telemetry directory by worker
-        wid = observe_trainview.worker_id()
-        run_name = "train"
-        if wid is not None:
-            meta["worker"] = wid
-            run_name = observe_trainview.worker_run_name("train", wid)
-        slog = observe_steplog.from_env(run_name=run_name, meta=meta)
-        prev_recording = tracer.record_events
-        if slog is not None:
-            # telemetry may be flag-configured (no env var), so force
-            # event recording on — this run WILL export a trace (restored
-            # after, so later non-telemetry runs don't keep buffering)
-            tracer.record_events = True
-            tracer.reset()  # the exported trace covers exactly this run
-        # the in-flight loss sentinel + flight recorder (observe/
-        # sentinel.py): cheap host checks on the already-read-back cost,
-        # PADDLE_TPU_SENTINEL governs warn/halt/off; the crash artifact
-        # lands next to the steplog when telemetry is on
-        sentinel = observe_sentinel.from_env(steplog=slog,
-                                             run_name=run_name,
-                                             worker=wid)
-        start_pass = start_cursor = 0
-        if checkpoint_dir and resume:
-            start_pass, start_cursor = self._resume_restore(checkpoint_dir,
-                                                            mode=resume)
-        ckpt_ctx = None
-        if checkpoint_dir and checkpoint_every:
-            ckpt_ctx = self._checkpoint_setup(
-                checkpoint_dir, checkpoint_every, checkpoint_keep,
-                checkpoint_sync, slog)
-        # first step's wall interval is anchored at train start, so the
-        # first record honestly includes compile time (the compile shows
-        # up as an ``event`` record too when jax.monitoring emits it)
-        completed = False
-        last_final = {"t": time.perf_counter(), "phases": phases}
-        try:
-            self._train_passes(reader, num_passes, event_handler, feeding,
-                               sync_params, test_reader, slog, last_final,
-                               sentinel, k, feed_pipeline, start_pass,
-                               start_cursor, ckpt_ctx)
-            completed = True
-        except BaseException as exc:
-            # any escape from the training loop dumps the black box
-            # (a sentinel halt already dumped; on_exception skips it)
-            if sentinel is not None:
-                sentinel.on_exception(exc)
-            if ckpt_ctx is not None and ckpt_ctx["writer"] is not None:
-                from paddle_tpu.distributed.elastic import (SelfLeaseLost,
-                                                            WorkerLost)
-
-                if isinstance(exc, (WorkerLost, SelfLeaseLost)):
-                    # reform abort: each worker stops at its OWN step
-                    # boundary, so draining the pending snapshot here
-                    # would advance the shared directory's rewind target
-                    # differently per worker; everyone must rewind to
-                    # the same committed checkpoint (run_elastic settles
-                    # the directory before it restores). A self-lapsed
-                    # worker especially: its peers have already
-                    # reformed, so its pending snapshot is from the
-                    # ABANDONED pre-reform branch — committing it would
-                    # hand the next rewind pre-reform state.
-                    ckpt_ctx["writer"].discard_pending()
-            raise
-        finally:
-            # ``completed`` (not sys.exc_info(), which also reports an
-            # OUTER handled exception when train() runs inside an except
-            # block) decides who wins: on a normal exit a writer error
-            # must surface, while an exception already unwinding must
-            # stay visible over the writer's
+            # observability: host spans around every phase (feed / device
+            # step / evaluator read-back — they feed the global StatSet,
+            # dumped per pass under PADDLE_TPU_STATS=1, reference: the
+            # per-pass globalStat.printAllStatus dump) and, under
+            # PADDLE_TPU_TELEMETRY=<dir>, a JSONL step log + Chrome-trace
+            # export of the spans (docs/observability.md).
+            tracer = observe_spans.get_tracer()
+            meta = {"phase": "train", "num_passes": int(num_passes)}
+            if k:
+                meta["steps_per_call"] = k
+            # training-fleet identity (observe/trainview.py): a distributed
+            # worker stamps PADDLE_TPU_TRAIN_WORKER before training, and
+            # every artifact this run emits carries it — the steplog meta
+            # plus a per-worker file name (train-t<i>.steps.jsonl), so
+            # `cli observe` can pool a shared telemetry directory by worker
+            wid = observe_trainview.worker_id()
+            run_name = "train"
+            if wid is not None:
+                meta["worker"] = wid
+                run_name = observe_trainview.worker_run_name("train", wid)
+            slog = observe_steplog.from_env(run_name=run_name, meta=meta)
+            prev_recording = tracer.record_events
+            if slog is not None:
+                # telemetry may be flag-configured (no env var), so force
+                # event recording on — this run WILL export a trace (restored
+                # after, so later non-telemetry runs don't keep buffering)
+                tracer.record_events = True
+                # the exported trace covers exactly this run, from its
+                # open `train_enter` on
+                tracer.reset(at=call_start)
+            # the in-flight loss sentinel + flight recorder (observe/
+            # sentinel.py): cheap host checks on the already-read-back cost,
+            # PADDLE_TPU_SENTINEL governs warn/halt/off; the crash artifact
+            # lands next to the steplog when telemetry is on
+            sentinel = observe_sentinel.from_env(steplog=slog,
+                                                 run_name=run_name,
+                                                 worker=wid)
+            start_pass = start_cursor = 0
+            if checkpoint_dir and resume:
+                start_pass, start_cursor = self._resume_restore(
+                    checkpoint_dir, mode=resume)
+            ckpt_ctx = None
+            if checkpoint_dir and checkpoint_every:
+                ckpt_ctx = self._checkpoint_setup(
+                    checkpoint_dir, checkpoint_every, checkpoint_keep,
+                    checkpoint_sync, slog)
+            # first step's wall interval is anchored at train start, so the
+            # first record honestly includes compile time (the compile shows
+            # up as an ``event`` record too when jax.monitoring emits it)
+            completed = False
+            last_final = {"t": time.perf_counter(), "phases": phases}
             try:
-                # drain + join the ckpt-writer thread; a writer error
-                # surfaces HERE
-                if ckpt_ctx is not None:
-                    self._checkpoint_close(ckpt_ctx)
-            except Exception:
-                if completed:
-                    raise
-                logger.exception("checkpoint writer error during unwind")
+                self._train_passes(reader, num_passes, event_handler, feeding,
+                                   sync_params, test_reader, slog, last_final,
+                                   sentinel, k, feed_pipeline, start_pass,
+                                   start_cursor, ckpt_ctx, enter.close,
+                                   leaving)
+                completed = True
+            except BaseException as exc:
+                # any escape from the training loop dumps the black box
+                # (a sentinel halt already dumped; on_exception skips it)
+                if sentinel is not None:
+                    sentinel.on_exception(exc)
+                if ckpt_ctx is not None and ckpt_ctx["writer"] is not None:
+                    from paddle_tpu.distributed.elastic import (
+                        SelfLeaseLost, WorkerLost)
+
+                    if isinstance(exc, (WorkerLost, SelfLeaseLost)):
+                        # reform abort: each worker stops at its OWN step
+                        # boundary, so draining the pending snapshot here
+                        # would advance the shared directory's rewind target
+                        # differently per worker; everyone must rewind to
+                        # the same committed checkpoint (run_elastic settles
+                        # the directory before it restores). A self-lapsed
+                        # worker especially: its peers have already
+                        # reformed, so its pending snapshot is from the
+                        # ABANDONED pre-reform branch — committing it would
+                        # hand the next rewind pre-reform state.
+                        ckpt_ctx["writer"].discard_pending()
+                raise
             finally:
-                if slog is not None:
-                    try:
-                        tracer.export(slog.trace_path)
-                    finally:
-                        tracer.record_events = prev_recording
-                        slog.close()
+                # ``completed`` (not sys.exc_info(), which also reports an
+                # OUTER handled exception when train() runs inside an except
+                # block) decides who wins: on a normal exit a writer error
+                # must surface, while an exception already unwinding must
+                # stay visible over the writer's
+                try:
+                    # drain + join the ckpt-writer thread; a writer error
+                    # surfaces HERE
+                    if ckpt_ctx is not None:
+                        self._checkpoint_close(ckpt_ctx)
+                except Exception:
+                    if completed:
+                        raise
+                    logger.exception(
+                        "checkpoint writer error during unwind")
+                finally:
+                    if slog is not None:
+                        try:
+                            tracer.export(slog.trace_path)
+                        finally:
+                            tracer.record_events = prev_recording
+                            slog.close()
+
+    @staticmethod
+    def _worker_labels():
+        """A training-fleet worker labels its series so a shared scrape
+        keeps the processes apart (observe/trainview.py)."""
+        wid = observe_trainview.worker_id()
+        return {"worker": wid} if wid is not None else None
+
+    @classmethod
+    def _phase(cls, name, args=None):
+        """A set-up or call phase of this trainer: the span and its
+        always-on histogram (observe/spans.py PHASE_HISTOGRAMS)."""
+        return observe_spans.phase(name, args=args,
+                                   labels=cls._worker_labels())
 
     # process-wide training metrics (observe/metrics.py; scraped through
     # any serve front end in the same process, snapshot()-able anywhere)
-    @staticmethod
-    def _train_metrics():
+    @classmethod
+    def _train_metrics(cls):
         m = observe_metrics.get_registry()
-        # a training-fleet worker labels its series so a shared scrape
-        # keeps the processes apart (observe/trainview.py)
-        wid = observe_trainview.worker_id()
-        labels = {"worker": wid} if wid is not None else None
+        labels = cls._worker_labels()
         return (m.counter("paddle_tpu_train_steps_total",
                           help="finalized training steps", labels=labels),
                 m.counter("paddle_tpu_train_examples_total",
@@ -532,7 +570,8 @@ class SGD:
 
     def _train_passes(self, reader, num_passes, event_handler, feeding,
                       sync_params, test_reader, slog, last_final, sentinel,
-                      k, feed_pipeline, start_pass, start_cursor, ckpt):
+                      k, feed_pipeline, start_pass, start_cursor, ckpt,
+                      entered, leaving):
         """The train loop, over dispatch units (data/feeder.py
         ChunkBatch): n >= 1 consecutive batches handed to the device in
         one call. Where a unit comes from is its source's business: the
@@ -559,7 +598,12 @@ class SGD:
         is unmeasurable inside a fused region, so ``step`` records then
         carry no wall_ms and the interval lands on the unit's
         ``train_chunk`` record (span, trainview and sentinel ring
-        likewise); without it the interval is the step's own."""
+        likewise); without it the interval is the step's own.
+
+        ``entered()`` closes the call's ``train_enter`` span once the
+        source of units is built; ``leaving()`` opens ``train_exit`` once
+        the last pass's last step is read back, so that pass's end (its
+        test, ``sync_back``, ``EndPass``) is the call's way out."""
         log_period = flags.get_flag("log_period")
         test_period = flags.get_flag("test_period")
         (m_steps, m_examples, m_loss, m_examples_per_sec,
@@ -582,6 +626,7 @@ class SGD:
                 reader, self.topology, feeding=feeding,
                 depth=max(self._feed_depth(feed_pipeline), k),
                 parallelism=self.parallelism)
+        entered()
         for pass_id in range(start_pass, num_passes):
             # resumed pass: the first ``start_cursor`` batches were
             # already trained before the checkpoint — skip them on the
@@ -767,9 +812,12 @@ class SGD:
             taken = ()
             if pending is not None:
                 finalize(pending)
+            if pass_id == num_passes - 1:
+                leaving()
             self._finish_pass(pass_id, eval_acc, event_handler, feeding,
                               sync_params, test_reader, test_period, slog,
                               last_final)
+        leaving()  # here where the last pass was skipped, or none ran
         if sync_params:
             self._sync_back()
 
@@ -923,10 +971,12 @@ class SGD:
     def _sync_back(self):
         """Copy device training state back into the Parameters object so
         save/inspect sees current values (v2's gm<->parameters append)."""
-        with observe_spans.span("sync_back"):
+        read = sum(leaf.nbytes for leaf in jax.tree_util.tree_leaves(
+            (self._trainable, self._state)))
+        with self._phase("sync_back", args={"bytes": read}):
             host = jax.device_get({**self._expanded_trainable(),
                                    **self._state})
-        self.parameters.update_from(host)
+            self.parameters.update_from(host)
 
     def save_parameter_to_tar(self, f):
         self._sync_back()
