@@ -84,7 +84,9 @@ class Parameters:
         self.set(key, value)
 
     def get_shape(self, key):
-        return tuple(np.asarray(self._values[key]).shape)
+        # the leaf's own attribute: np.asarray would copy a device array
+        # to the host to read it
+        return tuple(np.shape(self._values[key]))
 
     def spec(self, key):
         return self._specs.get(key)

@@ -6,10 +6,31 @@ and the process's one set of ``jax.monitoring`` listeners.
 compile (JAX decides whether the cache is in use at the first compile of
 the process, so a later call is too late for that process).
 
-Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX already reads it and this
-module sets nothing. Otherwise the cache lives at the fixed path
+**Where it lives** is the user's choice: where
+``JAX_COMPILATION_CACHE_DIR`` is set JAX already reads it and this module
+places nothing. Otherwise the cache lives at the fixed path
 ``<checkout>/.jax_cache``: the path is part of the cache's key, so one
 built from a temp dir, a pid or the time would never hit.
+
+**What it keeps** is the framework's, wherever it lives: every program a
+process compiles (:data:`KEEP_FROM_SECS`), not only those that took JAX's
+default of a second. A process's set-up is a hundred small programs (one
+``fold_in`` and one initialiser a leaf in ``Parameters.create``, the
+eager ``jnp`` calls of ``SGD.__init__``, the programs round the first
+steps), each quick and together 14-20 s of every warm set-up on the chip
+(PERF.md §5) while JAX wrote none of them: a read looks the key up
+whatever the threshold, only the write is gated. So the first run on a
+machine writes them (a few KB each on the CPU, more on the TPU: sizes in
+docs/observability.md "Set-up spans") and every later process reads
+them. An explicit ``JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS`` is the
+user's word and stands. A cold run is a deleted directory: nothing here
+or in JAX evicts an entry.
+
+**How often it engages**: ``paddle_tpu_compile_backend_ms``'s count is
+the programs the process asked the backend for,
+``paddle_tpu_compile_cache_retrieval_ms``'s count and sum are those of
+them that were read and what the reads took; :func:`stats` holds the
+same as ``requests`` and ``hits``, and the entries on disk.
 
 **Compile phases** (docs/observability.md "Set-up spans"): JAX times its
 own tracing, lowering and backend compile and reports each as a
@@ -34,6 +55,10 @@ from paddle_tpu.observe import metrics as observe_metrics
 _CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 DEFAULT_DIR = os.path.join(_CHECKOUT, ".jax_cache")
+# Seconds of compiling from which a program is written to the cache. JAX's
+# own 1.0 keeps the step and drops every small program of set-up, which a
+# warm process then compiles again, 0.1-0.15 s each on the chip (PERF.md §5).
+KEEP_FROM_SECS = 0.0
 
 # JAX's event -> (histogram, help). The first three nest in one another
 # (jax/_src/dispatch.py log_elapsed_time: a scalar event at the start, a
@@ -130,12 +155,15 @@ def subscribe(callback):
 
 
 def enable():
-    """Place the cache and start counting its hits and timing the compile
-    phases. Returns the directory in use. A directory that cannot be
-    created is an error."""
+    """Place the cache, have it keep quick programs too, and start
+    counting its hits and timing the compile phases. Returns the directory
+    in use. A directory that cannot be created is an error."""
     import jax
 
     listen()
+    if "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS" not in os.environ:
+        jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                          KEEP_FROM_SECS)
     placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if placed:
         return placed

@@ -280,7 +280,11 @@ def test_telemetry_counts_through_the_one_listener(tmp_path, monkeypatch):
     # the log mirrors every duration event, whole durations as ever
     assert sum("jaxpr_trace" in r["event"] for r in events) == \
         _added(before, after, TRACE)[0]
-    assert all(r["secs"] >= 0 for r in events)
+    # but for JAX's reckoning of what a read saved: it keeps a program's
+    # compile time in whole seconds, so a quick program that the cache
+    # answered reads 0 less the time of the read
+    assert all(r["secs"] >= 0 for r in events
+               if "compile_time_saved" not in r["event"])
 
     def ours(listeners):
         return [fn for fn in listeners
