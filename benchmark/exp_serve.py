@@ -104,6 +104,7 @@ Usage:
 """
 
 import argparse
+import gc
 import json
 import os
 import signal
@@ -1338,6 +1339,10 @@ def measure_trace_overhead(args):
             os.environ["PADDLE_TPU_TRACE_SAMPLE"] = repr(rate)
         else:
             os.environ.pop("PADDLE_TPU_TRACE_SAMPLE", None)
+        # a full collection of the interpreter's garbage takes tens of
+        # ms over jax's heap: made here it does not land on one side's
+        # slowest request, which a short pass's p99 is
+        gc.collect()
         lat, wall_s = run_closed_loop(engine, bundle, args.clients,
                                       args.requests,
                                       args.rows_per_request, rng)

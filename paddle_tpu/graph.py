@@ -22,9 +22,11 @@ import itertools
 import threading
 
 import jax
+import jax.numpy as jnp
 
 from paddle_tpu.attr import ExtraAttr, ParamAttr
 from paddle_tpu.core import dtype as dtype_mod
+from paddle_tpu.observe import step_counts
 from paddle_tpu.utils.error import enforce, layer_scope
 
 _name_lock = threading.Lock()
@@ -135,6 +137,12 @@ class Context:
         # this trace's Mamba-1 scans by the form they took
         # (ops/ssm.py selective_scan_form)
         self.selective_scans = {"fused": 0, "plain": 0}
+        # this trace's expert layers: the experts each holds of how many,
+        # and the rows of their sorted buffers, all layers (layer.moe)
+        self.moe = {"held": 0, "total": 0, "rows_bound": 0}
+        # the step's counters whose values are data, folded by name
+        # (observe/step_counts.py)
+        self.counts = {}
         # streaming-decode carry threading (serve/export.py decode step):
         # when ``decode_state`` is a dict, recurrent layers read their
         # initial carry from it (decode_state[layer_name] = [leaf, ...];
@@ -148,6 +156,14 @@ class Context:
     @property
     def is_train(self):
         return self.mode == "train"
+
+    def count(self, name, value):
+        """Folds a traced scalar into the step's counter ``name`` as
+        ``step_counts.COUNTS`` says: summed or the largest."""
+        fold = {"sum": jnp.add, "max": jnp.maximum}[
+            step_counts.COUNTS[name][0]]
+        self.counts[name] = fold(self.counts[name], value) \
+            if name in self.counts else value
 
     def next_rng(self):
         enforce(

@@ -14,9 +14,9 @@ paddle_tpu.topology.Topology. Families:
   cost.py      classification_cost, cross_entropy, square_error, rank, ...
   mixed.py     mixed + projections/operators
   extra.py     nce, hsigmoid, crf, crf_decoding, ctc, warp_ctc, detection
-  decoder.py   rms_norm, layer_norm, gated_mlp, mamba2, mamba1, gmu,
-               gated_delta_net, gqa_attention, lm_head, recompute (their
-               token-level cost, lm_cost, is in cost.py)
+  decoder.py   rms_norm, layer_norm, gated_mlp, moe, mamba2, mamba1, gmu,
+               short_conv, gated_delta_net, gqa_attention, lm_head,
+               recompute (their token-level cost, lm_cost, is in cost.py)
 """
 
 from paddle_tpu.graph import LayerNode, LayerOutput, reset_name_counters
@@ -143,8 +143,10 @@ from paddle_tpu.layer.decoder import (
     lm_head,
     mamba1,
     mamba2,
+    moe,
     recompute,
     rms_norm,
+    short_conv,
 )
 from paddle_tpu.layer.step import gru_step, gru_step_naive, lstm_step
 from paddle_tpu.layer.detection import (

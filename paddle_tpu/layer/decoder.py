@@ -1,18 +1,20 @@
 """Layers of today's decoder-only language models: RMSNorm (plain and
-gated) and LayerNorm, the gated MLP, the Mamba-2 and Mamba-1 mixers, the
-Gated DeltaNet linear-attention mixer, the Gated Memory Unit,
-grouped-query causal attention without positions (with or without
-normalised queries and keys, a window, differential heads, biases, and
-over its own keys and values or another layer's), the head over a
-vocabulary table, and a block that is recomputed in backward (the
-token-level cost over the head's logits is ``layer/cost.py lm_cost``).
+gated) and LayerNorm, the gated MLP, a sparse expert layer that holds
+some of the experts, the Mamba-2 and Mamba-1 mixers, the Gated DeltaNet
+linear-attention mixer, the Gated Memory Unit, the gated short
+convolution, grouped-query causal attention with rotary positions or
+none (with or without normalised queries and keys, a window,
+differential heads, biases, and over its own keys and values or another
+layer's), the head over a vocabulary table, and a block that is
+recomputed in backward (the token-level cost over the head's logits is
+``layer/cost.py lm_cost``).
 
 All take and give ``SequenceBatch`` values [B, T, width]. The layers that
 mix across time (``mamba2``, ``mamba1``, ``gated_delta_net``,
-``gqa_attention``) refuse packed rows: their state, convolution taps and
-attention do not yet reset at segment starts. Every piece of device work
-runs under a ``jax.named_scope`` of its own (docs/observability.md
-"Decoder scopes"). A layer that hands out a second value (``mamba1``'s
+``short_conv``, ``gqa_attention``) refuse packed rows: their state,
+convolution taps and attention do not yet reset at segment starts. Every
+piece of device work runs under a ``jax.named_scope`` of its own
+(docs/observability.md "Decoder scopes"). A layer that hands out a second value (``mamba1``'s
 scan output, ``gqa_attention``'s keys and values) gives a tuple, and
 :func:`_values` makes one node of each of its values.
 """
@@ -32,6 +34,7 @@ from paddle_tpu.layer.base import (bias_spec, data_of, featurewise, is_seq,
                                    reject_packed, to_list, weight_spec)
 from paddle_tpu.ops import attention as attention_ops
 from paddle_tpu.ops import delta_rule as delta_ops
+from paddle_tpu.ops import moe as moe_ops
 from paddle_tpu.ops import ssm as ssm_ops
 from paddle_tpu.utils.error import enforce
 
@@ -119,9 +122,11 @@ def layer_norm(input, eps=1e-5, name=None, param_attr=None, bias_attr=None,
 # What a recomputed block may keep for backward (``recompute(keep=...)``):
 # GATED_MLP_PRODUCT names x W_in inside ``gated_mlp``, before the split;
 # MAMBA_IN_PRODUCT u W_in inside ``mamba2``, MAMBA1_IN_PRODUCT inside
-# ``mamba1``, both before the split;
+# ``mamba1``, both before the split; MOE_PRODUCT the sorted rows times the
+# held experts' first matrices inside ``moe``, before the split;
 # _KEPT_OUTPUT the outputs of the inner nodes a block lists.
 GATED_MLP_PRODUCT = "paddle_tpu.gated_mlp.product"
+MOE_PRODUCT = "paddle_tpu.moe.product"
 MAMBA_IN_PRODUCT = "paddle_tpu.mamba2.in_product"
 MAMBA1_IN_PRODUCT = "paddle_tpu.mamba1.in_product"
 _KEPT_OUTPUT = "paddle_tpu.block.kept"
@@ -174,12 +179,113 @@ def gated_mlp(input, size, name=None, param_attr=None, layer_attr=None):
                      layer_attr=layer_attr)
 
 
-def _named_spec(name, suffix, shape, initializer=None, std=None):
+def _named_spec(name, suffix, shape, initializer=None, std=None,
+                static=False):
     from paddle_tpu.attr import ParamAttr
 
     attr = ParamAttr(name="%s.%s" % (name, suffix), initializer=initializer,
-                     initial_std=std)
+                     initial_std=std, is_static=static)
     return weight_spec(name, 0, shape, attr)
+
+
+@register_layer("moe")
+def moe(input, experts_total, experts_held, first_held, top_k, width,
+        normalize=True, scaling=1.0, use_bias=True, initial_std=0.02,
+        name=None, layer_attr=None):
+    """A sparse expert layer on a chip that holds ``experts_held`` of the
+    ``experts_total`` experts, those from ``first_held`` on
+    (``ops/moe.py``):
+        s = sigmoid(u W_r)                    all experts, float32
+        chosen = top_k(s + expert_bias)       the bias selects and no more
+        w = s[chosen] / (sum s[chosen] + 1e-6) * scaling      ``normalize``
+        out = sum over chosen e held here of w_e * expert_e(u)
+    each expert a gated MLP of ``width``. What the absent experts would
+    add is left out (their chips compute it); no pair of a held expert is
+    dropped whatever the imbalance, the sorted buffer having ``top_k`` rows
+    a position; padded positions route nowhere. Parameters
+    ``<name>.router`` [d, total], ``.expert_bias`` [total] (static: it
+    selects and is never differentiated; zeros at the start; absent
+    without ``use_bias``), ``.w_in`` [held, d, 2 * width] (gate then up),
+    ``.w_out`` [held, width, d]. The first grouped product carries the
+    name ``MOE_PRODUCT``, which a ``recompute`` block around the layer may
+    keep. Each traced layer adds to the gauges ``paddle_tpu_moe_*`` and to
+    the step's two data counters (docs/observability.md)."""
+    name = name or auto_name("moe")
+    d = input.size
+    enforce(0 <= first_held and first_held + experts_held <= experts_total,
+            "moe holds experts %d..%d of %d", first_held,
+            first_held + experts_held - 1, experts_total)
+    specs = {
+        "router": _named_spec(name, "router", (d, experts_total),
+                              std=initial_std),
+        "w_in": _named_spec(name, "w_in", (experts_held, d, 2 * width),
+                            std=initial_std),
+        "w_out": _named_spec(name, "w_out", (experts_held, width, d),
+                             std=initial_std),
+    }
+    if use_bias:
+        specs["expert_bias"] = _named_spec(
+            name, "expert_bias", (experts_total,), Constant(0.0),
+            static=True)
+
+    def forward(params, values, ctx):
+        seq = values[0]
+        x = data_of(seq)
+        rows = x.reshape(-1, d)
+        lengths = seq.lengths if is_seq(seq) else None
+        valid = jnp.ones(rows.shape[:1], bool) if lengths is None else (
+            jnp.arange(x.shape[1])[None, :] < lengths[:, None]).reshape(-1)
+        p = {k: params[s.name] for k, s in specs.items()}
+        out, here, busiest = moe_ops.moe(
+            rows, valid, p["router"], p.get("expert_bias"), p["w_in"],
+            p["w_out"], top_k, first_held, scaling, normalize,
+            kept=lambda product: _kept(product, MOE_PRODUCT, ctx))
+        ctx.moe["held"] = experts_held
+        ctx.moe["total"] = experts_total
+        ctx.moe["rows_bound"] += top_k * rows.shape[0]
+        ctx.count("paddle_tpu_moe_rows_here", here)
+        ctx.count("paddle_tpu_moe_expert_load_max", busiest)
+        return like(seq, out.reshape(x.shape))
+
+    return make_node("moe", forward, [input], name=name, size=d,
+                     param_specs=list(specs.values()), layer_attr=layer_attr)
+
+
+@register_layer("short_conv")
+def short_conv(input, conv_width=3, initial_std=0.02, name=None,
+               layer_attr=None, eps=None):
+    """The gated short convolution (LFM2's ``conv`` layers):
+        [B, C, x] = u W_in                        d -> 3 d, no bias
+        y = C * conv1d_causal(B * x)              depthwise, no bias, no activation
+        out = y W_out
+    Parameters ``<name>.in_proj`` [d, 3 d], ``.conv_w`` [d, K],
+    ``.out_proj`` [d, d]. ``eps`` is taken for ``hybrid_lm``, which hands
+    every mixer the model's, and not used: the layer has no norm."""
+    name = name or auto_name("short_conv")
+    d = input.size
+    specs = {
+        "in_proj": _named_spec(name, "in_proj", (d, 3 * d), std=initial_std),
+        # as torch.nn.Conv1d starts a depthwise filter
+        "conv_w": _named_spec(name, "conv_w", (d, conv_width),
+                              Uniform(-conv_width ** -0.5,
+                                      conv_width ** -0.5)),
+        "out_proj": _named_spec(name, "out_proj", (d, d), std=initial_std),
+    }
+
+    def forward(params, values, ctx):
+        seq = values[0]
+        reject_packed(seq, "short_conv")
+        enforce(is_seq(seq), "short_conv needs a sequence input")
+        p = {k: params[s.name] for k, s in specs.items()}
+        with jax.named_scope("paddle_tpu.short_conv"):
+            b_gate, c_gate, x = jnp.split(
+                jnp.matmul(seq.data, p["in_proj"]), 3, axis=-1)
+            y = c_gate * ssm_ops.causal_conv1d(b_gate * x, p["conv_w"], None,
+                                               seq.lengths)
+            return like(seq, jnp.matmul(y, p["out_proj"]))
+
+    return make_node("short_conv", forward, [input], name=name, size=d,
+                     param_specs=list(specs.values()), layer_attr=layer_attr)
 
 
 class _InverseSoftplusOfLogUniform:
@@ -489,14 +595,18 @@ def _differential_attention(q, k, v, lam, lam_init, norm_w, eps, scale,
 def gqa_attention(input, heads, kv_heads, head_dim, scale=None, block=512,
                   initial_std=0.02, name=None, layer_attr=None,
                   qk_norm=False, eps=1e-5, window=None, differential=None,
-                  bias=False, kv=None, hand_out=False):
+                  bias=False, kv=None, hand_out=False, rope_theta=None):
     """Causal self-attention with ``heads`` query heads over ``kv_heads``
     shared key-value heads, no positional encoding and no bias; scores are
     multiplied by ``scale`` (1 / sqrt(head_dim) by default). Blockwise
     (``ops/attention.py``): no [T, T] score matrix is held. With
     ``qk_norm`` queries and keys are RMS-normalised with a learned scale,
     each over its whole projection, before the split into heads (the
-    OLMo 2 layout). With ``window`` a query sees the ``window`` keys that
+    OLMo 2 layout), or with ``qk_norm="head"`` each head over its own
+    ``head_dim`` values, one scale [head_dim] for the queries and one for
+    the keys. With ``rope_theta`` queries and keys then turn by rotary
+    positions 0..T-1 over the whole head (``ops/attention.py rotary``).
+    With ``window`` a query sees the ``window`` keys that
     end with its own, and key blocks outside are not visited. With
     ``differential`` (the layer's starting lambda, ``lambda_init`` of its
     depth) heads go in pairs that subtract two softmaxes
@@ -524,11 +634,17 @@ def gqa_attention(input, heads, kv_heads, head_dim, scale=None, block=512,
              for n in made}
     specs["o"] = _named_spec(name, "o", (heads * head_dim, d),
                              std=initial_std)
+    per_head = qk_norm == "head"
+    enforce(kv is None or not (per_head or rope_theta),
+            "gqa_attention: cross-attention takes another layer's keys as "
+            "they are, without a per-head norm or rotary positions")
     if qk_norm:
-        specs["q_norm"] = _named_spec(name, "q_norm", (heads * head_dim,),
-                                      Constant(1.0))
-        specs["k_norm"] = _named_spec(name, "k_norm", (kv_heads * head_dim,),
-                                      Constant(1.0))
+        specs["q_norm"] = _named_spec(
+            name, "q_norm", (head_dim if per_head else heads * head_dim,),
+            Constant(1.0))
+        specs["k_norm"] = _named_spec(
+            name, "k_norm", (head_dim if per_head else kv_heads * head_dim,),
+            Constant(1.0))
     if bias:
         for n in made:
             specs[n + "_b"] = _named_spec(name, n + "_b", (widths[n],),
@@ -551,11 +667,20 @@ def gqa_attention(input, heads, kv_heads, head_dim, scale=None, block=512,
             x = jnp.matmul(u, params[specs[n].name])
             if bias:
                 x = x + params[specs[n + "_b"].name]
-            if qk_norm and n != "v":
+            if qk_norm and not per_head and n != "v":
                 with jax.named_scope("paddle_tpu.qk_norm"):
                     x = _rms_normalize(x, params[specs[n + "_norm"].name],
                                        eps)
-            return x.reshape(b, t, h, head_dim)
+            x = x.reshape(b, t, h, head_dim)
+            if n == "v":
+                return x
+            if per_head:
+                with jax.named_scope("paddle_tpu.qk_norm"):
+                    x = _rms_normalize(x, params[specs[n + "_norm"].name],
+                                       eps)
+            if rope_theta:
+                x = attention_ops.rotary(x, float(rope_theta))
+            return x
 
         def attend(q, k, v):
             if differential is None:
@@ -643,11 +768,11 @@ def recompute(output, inputs, enabled=True, name=None, keep=()):
     ``keep`` lists what backward keeps besides the inputs, so that the
     second forward need not make it again: a node inside the block (its
     output) or a name that a layer inside gives one of its values
-    (``GATED_MLP_PRODUCT``, ``MAMBA_IN_PRODUCT``, ``MAMBA1_IN_PRODUCT``).
-    Worth keeping is a value that is dear to make and small to hold, a
-    large product's output; whatever only fed a kept value is then dead
-    in the second forward (the product before a kept sum). With nothing
-    listed the checkpoint has no policy. The bytes kept are added to
+    (``GATED_MLP_PRODUCT``, ``MAMBA_IN_PRODUCT``, ``MAMBA1_IN_PRODUCT``,
+    ``MOE_PRODUCT``). Worth keeping is a value that is dear to make and
+    small to hold, a large product's output; whatever only fed a kept
+    value is then dead in the second forward (the product before a kept
+    sum). With nothing listed the checkpoint has no policy. The bytes kept are added to
     ``ctx.recompute_kept_bytes``, which ``Topology.apply`` sets the gauge
     ``paddle_tpu_recompute_kept_bytes`` from."""
     inputs = to_list(inputs)
@@ -675,19 +800,29 @@ def recompute(output, inputs, enabled=True, name=None, keep=()):
 
     def forward(params, values, ctx):
         def run(block_params, block_inputs):
-            seen = {id(n): v for n, v in zip(inputs, block_inputs)}
-            for node in inside:
-                value = node.forward(
-                    block_params, [seen[id(p)] for p in node.inputs], ctx)
-                if id(node) in kept_nodes:
-                    value = featurewise(
-                        lambda d: _kept(d, _KEPT_OUTPUT, ctx), value)
-                seen[id(node)] = value
-            if not several:
-                return seen[id(output)]
-            return tuple(seen[id(n)] for n in outputs)
+            # the counters the layers inside make leave with the outputs:
+            # a value of the checkpointed trace cannot leave through ctx
+            outer_counts, ctx.counts = ctx.counts, {}
+            try:
+                seen = {id(n): v for n, v in zip(inputs, block_inputs)}
+                for node in inside:
+                    value = node.forward(
+                        block_params, [seen[id(p)] for p in node.inputs],
+                        ctx)
+                    if id(node) in kept_nodes:
+                        value = featurewise(
+                            lambda d: _kept(d, _KEPT_OUTPUT, ctx), value)
+                    seen[id(node)] = value
+                result = tuple(seen[id(n)] for n in outputs) if several \
+                    else seen[id(output)]
+                return result, ctx.counts
+            finally:
+                ctx.counts = outer_counts
 
-        def handed_out(result):
+        def handed_out(made):
+            result, counts = made
+            for name, value in counts.items():
+                ctx.count(name, value)
             for value in result[1:] if several else ():
                 x = data_of(value)
                 ctx.shared_across_blocks_bytes += x.size * x.dtype.itemsize

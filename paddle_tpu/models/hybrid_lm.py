@@ -1,24 +1,43 @@
 """A decoder-only language model assembled from a list of layer kinds,
-each a mixer followed by a gated MLP on one residual stream, with values
-that a later layer reads of an earlier one beside the stream. The kinds
-are the keys of ``MIXERS``: Mamba-2 state-space layers and position-free
-grouped-query attention (the ``granitemoehybrid`` layout without experts:
+each a mixer followed by a feed-forward on one residual stream, with
+values that a later layer reads of an earlier one beside the stream. The
+mixer kinds are the keys of ``MIXERS``: Mamba-2 state-space layers and
+position-free grouped-query attention (the ``granitemoehybrid`` layout
+without experts:
 IBM Granite 4.0-H, https://huggingface.co/ibm-granite/granite-4.0-h-micro),
 Gated DeltaNet linear-attention layers and attention with normalised
 queries and keys (``olmo_hybrid``: https://huggingface.co/allenai/Olmo-Hybrid-7B),
-and SambaY with differential attention (``phi4flash``:
+SambaY with differential attention (``phi4flash``:
 https://huggingface.co/microsoft/Phi-4-mini-flash-reasoning,
 arXiv:2507.06607): Mamba-1 layers beside window attention, one
-full-attention layer, then Gated Memory Units and cross-attention.
+full-attention layer, then Gated Memory Units and cross-attention; and
+gated short convolutions beside rotary attention with per-head norms of
+queries and keys (``lfm2_moe``: https://huggingface.co/LiquidAI/LFM2-8B-A1B).
+The feed-forward is chosen layer by layer: one gated MLP (``dense``), or
+sparse experts of which this chip holds some (``experts``).
 
     h0 = embedding_multiplier * E[ids]
     norm "before":  h += residual_multiplier * mixer_i(N(h), shared)    mixer by layer_types[i]
-                    h += residual_multiplier * MLP(N(h))
+                    h += residual_multiplier * FFN_i(N(h))
     norm "after":   h += residual_multiplier * N(mixer_i(h, shared))
-                    h += residual_multiplier * N(MLP(h))
+                    h += residual_multiplier * N(FFN_i(h))
     logits = N(h) W^T / logits_scaling             W = E (tied) or the head's own table
     N = RMSNorm (norm_kind "rms": x / rms(x) * w) or LayerNorm ("layer":
         (x - mean) / sqrt(var + eps) * w + b)
+    FFN_i "dense":    (silu(a) * b) W_2, [a, b] = u W_1, width mlp_size
+    FFN_i "experts":  s = sigmoid(u W_r) over all experts, float32
+                      chosen = top_k(s + expert_bias)     the bias selects and no more
+                      w = s[chosen] / (sum s[chosen] + 1e-6) * scaling
+                      sum over chosen e held here of w_e * expert_e(u), each a gated MLP
+    conv mixer:       [B, C, x] = u W_in;  (C * conv1d_causal(B * x)) W_out    3 taps, no bias
+
+A layer whose index in the whole model is under ``dense_layers`` has the
+dense feed-forward, the others the experts' (all dense where
+``dense_layers`` is None). The expert layer (``layer.moe``,
+``ops/moe.py``) is told which experts it holds, ``held`` of ``total``
+from ``first_held``: it routes over all of them and computes its own
+experts' part; what the absent experts would add is left out, as their
+chips would compute it. No pair is dropped under any imbalance.
 
 The shared values, by the fourth and fifth fields of ``MIXERS``. A
 ``mamba1`` layer makes ``memory``, its scan output y before the gate,
@@ -53,10 +72,14 @@ before the split into z, xBC and dt; at Granite 4.0-H Micro's widths 8,512
 values a position, 139 MB and 0.286 TFLOP a layer at 8,192 positions) and
 a Mamba-1 mixer's (``MAMBA1_IN_PRODUCT``, [B, T, 4 * hidden], before the
 split into x and z), each as dear per byte as the MLP's product; the
-attention, Gated DeltaNet and Gated Memory Unit mixers offer nothing. The
-scans, the convolution, the norms and the gates are made again: they cost
-little to make. ``keep_layers`` says in how many of the layers, the last
-ones, a block keeps anything: a choice by what the chip's memory leaves,
+attention, Gated DeltaNet, Gated Memory Unit and short-convolution mixers
+offer nothing. An expert block keeps, in the dense product's place, the
+sorted rows' first grouped product (``MOE_PRODUCT``, [top_k * B * T,
+2 * width]: the whole buffer, of which the groups fill the share of the
+experts held). The scans, the convolutions, the norms, the gates, the
+router and the sort are made again: they cost little to make.
+``keep_layers`` says in how many of the layers, the last ones, a block
+keeps anything: a choice by what the chip's memory leaves,
 model by model. The last ones, because a step's memory peaks in the
 backward of the first layers, when nearly every gradient is alive and
 what the later layers kept has been used and freed.
@@ -68,7 +91,8 @@ from paddle_tpu import data_type
 from paddle_tpu import layer as L
 from paddle_tpu.attr import ParamAttr
 from paddle_tpu.layer.decoder import (GATED_MLP_PRODUCT, MAMBA1_IN_PRODUCT,
-                                      MAMBA_IN_PRODUCT, lambda_init)
+                                      MAMBA_IN_PRODUCT, MOE_PRODUCT,
+                                      lambda_init)
 from paddle_tpu.utils.error import enforce
 
 # A layer kind: the mixer's layer; which of hybrid_lm's groups of options
@@ -89,6 +113,7 @@ MIXERS = {
     "sliding_attention": Mixer(L.gqa_attention, "sliding_attention"),
     "cross_attention": Mixer(L.gqa_attention, "attention", reads="kv"),
     "gmu": Mixer(L.gmu, None, reads="memory"),
+    "conv": Mixer(L.short_conv, "conv"),
 }
 NORMS = {"rms": L.rms_norm, "layer": L.layer_norm}
 
@@ -119,17 +144,22 @@ def hybrid_lm(vocab, hidden, layer_types, mlp_size, attention=None,
               logits_scaling=1.0, eps=1e-5, initial_std=0.02,
               recompute=True, prefix="lm", linear_attention=None,
               norm="before", tie_head=True, keep_layers=None, mamba1=None,
-              sliding_attention=None, norm_kind="rms", layer_indices=None):
+              sliding_attention=None, norm_kind="rms", layer_indices=None,
+              conv=None, experts=None, dense_layers=None):
     """Builds the model over two ``integer_value_sequence`` slots, tokens
     and targets. The options of each kind of mixer in ``layer_types``:
     ``attention`` (and ``sliding_attention``): heads, kv_heads, head_dim,
-    and scale, block, qk_norm, bias, window, differential (True: each
-    layer's starting lambda follows its index);
+    and scale, block, qk_norm, bias, window, rope_theta, differential
+    (True: each layer's starting lambda follows its index);
     ``mamba``: heads, head_dim, state, conv_width, groups, chunk;
     ``mamba1``: state, conv_width, expand, dt_rank, chunk;
     ``linear_attention``: heads, key_dim, value_dim, conv_width,
-    neg_eigval, chunk. ``norm``: "before" each branch or "after" it;
-    ``norm_kind``: "rms" or "layer" (LayerNorm with a bias), the final
+    neg_eigval, chunk; ``conv``: conv_width. ``experts``: the options of
+    ``layer.moe`` (experts_total, experts_held, first_held, top_k, width,
+    normalize, scaling, use_bias), the feed-forward of every layer whose
+    index in the whole model is ``dense_layers`` or more (None: every
+    layer has the gated MLP of ``mlp_size``). ``norm``: "before" each
+    branch or "after" it; ``norm_kind``: "rms" or "layer" (LayerNorm with a bias), the final
     norm too. ``layer_indices``: the index each layer has in the whole
     model, where ``layer_types`` is a cut of it (its own position by
     default). ``tie_head=False`` gives the head a table of its own,
@@ -137,7 +167,8 @@ def hybrid_lm(vocab, hidden, layer_types, mlp_size, attention=None,
     for backward; otherwise a layer keeps its input and, in the last
     ``keep_layers`` layers (all of them by default), its MLP's first
     product, the residual stream after its mixer and what ``MIXERS`` says
-    its kind of mixer offers. Returns (tokens, targets, logits, cost)."""
+    its kind of mixer offers (an expert layer its first grouped product
+    in the MLP's place). Returns (tokens, targets, logits, cost)."""
     enforce(norm in ("before", "after"), "norm is %r, not before or after",
             norm)
     enforce(norm_kind in NORMS, "norm_kind is %r, not one of %s", norm_kind,
@@ -145,7 +176,8 @@ def hybrid_lm(vocab, hidden, layer_types, mlp_size, attention=None,
     make_norm = NORMS[norm_kind]
     options = {"attention": attention, "mamba": mamba,
                "linear_attention": linear_attention, "mamba1": mamba1,
-               "sliding_attention": sliding_attention, None: {}}
+               "sliding_attention": sliding_attention, "conv": conv,
+               None: {}}
     tokens = L.data(name="tokens",
                     type=data_type.integer_value_sequence(vocab))
     targets = L.data(name="targets",
@@ -199,14 +231,25 @@ def hybrid_lm(vocab, hidden, layer_types, mlp_size, attention=None,
             return out
 
         after_mixer = branch(h, mix, name + ".norm1")
-        h = branch(after_mixer, lambda x: L.gated_mlp(
-            input=x, size=mlp_size, param_attr=matrix, name=name + ".mlp"),
-            name + ".norm2")
+        sparse = dense_layers is not None \
+            and layer_indices[i] >= dense_layers
+        if sparse:
+            enforce(experts is not None, "layer_types[%d] is past the %d "
+                    "dense layers and no experts options are given", i,
+                    dense_layers)
+            h = branch(after_mixer, lambda x: L.moe(
+                input=x, initial_std=initial_std, name=name + ".moe",
+                **experts), name + ".norm2")
+        else:
+            h = branch(after_mixer, lambda x: L.gated_mlp(
+                input=x, size=mlp_size, param_attr=matrix,
+                name=name + ".mlp"), name + ".norm2")
         block = L.recompute(
             [h] + made if made else h,
             inputs=[entry] + ([shared[sources[i]]] if i in sources else []),
             enabled=recompute, name=name + ".block",
-            keep=[after_mixer, GATED_MLP_PRODUCT, *mixer.keeps]
+            keep=[after_mixer, MOE_PRODUCT if sparse else GATED_MLP_PRODUCT,
+                  *mixer.keeps]
             if i >= len(layer_types) - keep_layers else [])
         if made:
             h, shared[i] = block
@@ -223,7 +266,9 @@ def hybrid_lm(vocab, hidden, layer_types, mlp_size, attention=None,
 
 def _granite_options(cfg):
     enforce(not cfg.get("num_local_experts"),
-            "hybrid_lm has no expert layer (num_local_experts %r)",
+            "hybrid_lm reads no granitemoehybrid expert keys yet "
+            "(num_local_experts %r, num_experts_per_tok, a shared expert "
+            "beside the routed ones): its expert layer is lfm2_moe's",
             cfg.get("num_local_experts"))
     hidden = cfg["hidden_size"]
     heads = cfg["num_attention_heads"]
@@ -288,10 +333,42 @@ def _phi4flash_options(cfg):
         norm_kind="layer", tie_head=cfg["tie_word_embeddings"])
 
 
+def _lfm2_moe_options(cfg):
+    """LFM2's sparse models: gated short convolutions beside rotary
+    attention whose queries and keys are RMS-normalised head by head;
+    ``num_dense_layers`` leading layers with a gated MLP, sparse experts
+    after. ``num_experts`` is what this chip holds of the
+    ``num_experts_published`` the router scores (the same, where the
+    configuration holds them all), from ``first_expert`` on. The scores
+    are sigmoids (the config has no key for it)."""
+    hidden = cfg["hidden_size"]
+    heads = cfg["num_attention_heads"]
+    enforce(not cfg.get("conv_bias"),
+            "short_conv has no bias (conv_bias %r)", cfg.get("conv_bias"))
+    return dict(
+        mlp_size=cfg["intermediate_size"], eps=cfg["norm_eps"],
+        attention={"heads": heads, "kv_heads": cfg["num_key_value_heads"],
+                   "head_dim": hidden // heads, "qk_norm": "head",
+                   "rope_theta": cfg["rope_theta"]},
+        conv={"conv_width": cfg["conv_L_cache"]},
+        dense_layers=cfg["num_dense_layers"],
+        experts={"experts_total": cfg.get("num_experts_published",
+                                          cfg["num_experts"]),
+                 "experts_held": cfg["num_experts"],
+                 "first_held": cfg.get("first_expert", 0),
+                 "top_k": cfg["num_experts_per_tok"],
+                 "width": cfg["moe_intermediate_size"],
+                 "normalize": cfg["norm_topk_prob"],
+                 "scaling": cfg["routed_scaling_factor"],
+                 "use_bias": cfg["use_expert_bias"]},
+        tie_head=cfg.get("tie_word_embeddings", True))
+
+
 # config.json's model_type: the options hybrid_lm takes from its keys
 MODEL_TYPES = {"granitemoehybrid": _granite_options,
                "olmo_hybrid": _olmo_hybrid_options,
-               "phi4flash": _phi4flash_options}
+               "phi4flash": _phi4flash_options,
+               "lfm2_moe": _lfm2_moe_options}
 
 
 def from_config(cfg, recompute=True, prefix="lm", keep_layers=None):

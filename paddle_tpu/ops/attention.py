@@ -128,3 +128,22 @@ def blockwise_attention(q, k, v, scale, causal=True, lengths=None,
             (k_blocks[first:end], v_blocks[first:end], offsets[first:end]))
         out.append(online_softmax_finish(carry, q.dtype))
     return jnp.concatenate(out, axis=1)[:, :t]
+
+
+def rotary(x, theta):
+    """Rotary positions 0..T-1 over the whole last axis of x
+    [B, T, H, D], D even, in the rotate-half pairing (value i turns with
+    value i + D/2 by the angle position * theta^(-2i/D)); angles, sines
+    and the turn in float32."""
+    with jax.named_scope("paddle_tpu.rope"):
+        half = x.shape[-1] // 2
+        wide = dtype_mod.wide(x.dtype)
+        inverse = theta ** (-jnp.arange(half, dtype=wide) / half)
+        angles = jnp.arange(x.shape[1], dtype=wide)[:, None] \
+            * inverse[None, :]
+        cos = jnp.cos(angles)[None, :, None, :]
+        sin = jnp.sin(angles)[None, :, None, :]
+        first, second = jnp.split(x.astype(wide), 2, axis=-1)
+        return jnp.concatenate([first * cos - second * sin,
+                                second * cos + first * sin],
+                               axis=-1).astype(x.dtype)

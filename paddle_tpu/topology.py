@@ -150,21 +150,26 @@ class Topology:
         return out
 
     # -- evaluation ---------------------------------------------------------
-    def apply(self, params, feed, mode="train", rng=None, outputs=None):
+    def apply(self, params, feed, mode="train", rng=None, outputs=None,
+              counts=None):
         """Evaluate the DAG. Returns ({layer_name: value}, state_updates).
 
         ``feed`` maps data-layer names to already-converted device values
         (see :func:`convert_feed`); ``outputs`` optionally restricts which
-        layers' values are returned (all output nodes by default).
+        layers' values are returned (all output nodes by default);
+        ``counts``, a dict, takes the step's counters whose values are
+        data (observe/step_counts.py), traced scalars by name.
         """
         ctx = Context(mode=mode, rng=rng)
         values = self._run_nodes(params, feed, ctx)
+        if counts is not None:
+            counts.update(ctx.counts)
         if mode == "train":
             # set as the step program is traced: what its recomputed
             # blocks keep for backward, what they hand to later blocks
             # (layer/decoder.py recompute), which key blocks attention
             # visits (gqa_attention), which form its Mamba-1 scans took
-            # (mamba1)
+            # (mamba1), what its expert layers hold (moe)
             registry = observe_metrics.get_registry()
             for name, value, help_ in (
                     ("recompute_kept_bytes", ctx.recompute_kept_bytes,
@@ -187,7 +192,17 @@ class Topology:
                      "Pallas kernels"),
                     ("selective_scan_plain", ctx.selective_scans["plain"],
                      "Mamba-1 scans of a train step that run as plain "
-                     "loops")):
+                     "loops"),
+                    ("moe_experts_held", ctx.moe["held"],
+                     "experts each expert layer of a train step holds "
+                     "here"),
+                    ("moe_experts_total", ctx.moe["total"],
+                     "experts each expert layer of a train step routes "
+                     "over"),
+                    ("moe_rows_bound", ctx.moe["rows_bound"],
+                     "rows of the sorted buffers of a train step's expert "
+                     "layers, choices x positions x layers: no routing "
+                     "overflows them")):
                 registry.gauge("paddle_tpu_" + name, help=help_
                                + ", of the program traced last").set(value)
         wanted = outputs or [o.name for o in self.outputs]

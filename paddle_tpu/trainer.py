@@ -38,6 +38,7 @@ from paddle_tpu.utils.logger import logger
 from paddle_tpu.observe import metrics as observe_metrics
 from paddle_tpu.observe import sentinel as observe_sentinel
 from paddle_tpu.observe import spans as observe_spans
+from paddle_tpu.observe import step_counts
 from paddle_tpu.observe import steplog as observe_steplog
 from paddle_tpu.observe import tracing as observe_tracing
 from paddle_tpu.observe import trainview as observe_trainview
@@ -142,11 +143,16 @@ class SGD:
         def forward_all(params, feed, mode, rng):
             wanted = cost_names + [e.name for e in eval_nodes] \
                 + [o.name for o in self.extra_outputs]
+            counts = {}
             values, updates = topo.apply(params, feed, mode=mode, rng=rng,
-                                         outputs=wanted)
+                                         outputs=wanted, counts=counts)
             cost_total = sum(w * jnp.mean(values[c])
                              for c, w in zip(cost_names, cost_weights))
             eval_stats = {e.name: values[e.name] for e in eval_nodes}
+            if counts and mode == "train":
+                # the step's counters whose values are data leave beside
+                # the cost and are read in its readback
+                eval_stats[step_counts.KEY] = counts
             return cost_total, values, updates, eval_stats
 
         def train_step(trainable, replica, static, state, opt_state, feed,
@@ -656,8 +662,7 @@ class SGD:
                                         args={"batch": b_id}) as readback:
                     costs = np.atleast_1d(
                         np.asarray(jax.device_get(losses), dtype=np.float64))
-                    host_stats = (jax.device_get(stats)
-                                  if self.evaluators else {})
+                    host_stats = jax.device_get(stats) if stats else {}
                 phases["readback"] += readback.dur * 1e3
                 wall_ms = self._close_step(last_final, m_phases,
                                            base_step + 1, taken)
@@ -701,6 +706,12 @@ class SGD:
                             if unit.stacked else per)
                         metrics[e.name] = e.result(eval_acc[e.name])
                     cost = float(costs[i])
+                    if step_counts.KEY in host_stats:
+                        step_counts.observe(
+                            observe_metrics.get_registry(),
+                            host_stats[step_counts.KEY],
+                            i if unit.stacked else None,
+                            self._worker_labels())
                     if slog is not None:
                         slog.log_step(
                             step=step, pass_id=pass_id, batch_id=b,

@@ -639,7 +639,7 @@ def test_the_table_of_mixers_says_what_each_kind_offers():
                        "mamba1": (decoder.MAMBA1_IN_PRODUCT,),
                        "attention": (), "full_attention": (),
                        "sliding_attention": (), "cross_attention": (),
-                       "linear_attention": (), "gmu": ()}
+                       "linear_attention": (), "gmu": (), "conv": ()}
     # what a kind hands to later layers, and what it reads of an earlier one
     assert {k: (m.makes, m.reads) for k, m in hybrid_lm.MIXERS.items()
             if m.makes or m.reads} == {

@@ -384,3 +384,44 @@ def test_the_kernels_compile_for_v5e_at_the_published_size(one_chip,
     assert text.count("tpu_custom_call") >= 2
     # dy, d dt float32 and the 21 MB of block states, no [T, E, N] (5.4 GB)
     assert compiled.memory_analysis().temp_size_in_bytes < 0.6e9
+
+
+def test_the_expert_layer_compiles_for_v5e_at_the_published_size(one_chip):
+    """`ops/moe.py moe`, forward and backward, at the `lfm2-8b-a1b` cell's
+    shapes (8,192 positions of 2,048, top-4 of 32, 8 experts of 1,792
+    held) for a described v5e. It lives here because only one test file
+    may describe the chip (the module's fixture). The grouped products
+    become Mosaic calls (`ragged-dot` with its metadata: the tiles the
+    groups fill), six of them forward and backward, and nothing holds a
+    [held, rows, width] expansion of the buffer: that dense form, which
+    the CPU lowers to, would be 1.9 GB."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from paddle_tpu.ops import moe as moe_ops
+
+    def shape(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def total(x, router, w_in, w_out, bias, valid):
+        out, here, busiest = moe_ops.moe(x, valid, router, bias, w_in, w_out,
+                                         4, 0)
+        return jnp.sum(out.astype(jnp.float32)), (here, busiest)
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        compiled = jax.jit(jax.grad(total, argnums=range(4),
+                                    has_aux=True)).lower(
+            shape((8192, 2048)), shape((2048, 32)), shape((8, 2048, 3584)),
+            shape((8, 1792, 2048)), shape((32,), jnp.float32),
+            shape((8192,), jnp.bool_)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+    text = compiled.as_text()
+    assert text.count('op_name="ragged-dot-metadata"') >= 1
+    assert text.count("tpu_custom_call") >= 6
+    # the sorted rows, two products' outputs and their gradients: 32,768
+    # rows of 2,048 to 3,584 values in bfloat16, well under a gigabyte
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
