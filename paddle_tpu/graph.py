@@ -138,8 +138,11 @@ class Context:
         # (ops/ssm.py selective_scan_form)
         self.selective_scans = {"fused": 0, "plain": 0}
         # this trace's expert layers: the experts each holds of how many,
-        # and the rows of their sorted buffers, all layers (layer.moe)
-        self.moe = {"held": 0, "total": 0, "rows_bound": 0}
+        # the rows of their sorted buffers, all layers, and the layers by
+        # the form their row passes took (layer.moe, ops/moe.py
+        # experts_form)
+        self.moe = {"held": 0, "total": 0, "rows_bound": 0, "fused": 0,
+                    "plain": 0}
         # the step's counters whose values are data, folded by name
         # (observe/step_counts.py)
         self.counts = {}

@@ -208,8 +208,12 @@ def moe(input, experts_total, experts_held, first_held, top_k, width,
     without ``use_bias``), ``.w_in`` [held, d, 2 * width] (gate then up),
     ``.w_out`` [held, width, d]. The first grouped product carries the
     name ``MOE_PRODUCT``, which a ``recompute`` block around the layer may
-    keep. Each traced layer adds to the gauges ``paddle_tpu_moe_*`` and to
-    the step's two data counters (docs/observability.md)."""
+    keep. The passes over the sorted rows run as ``ops/pallas_moe.py``'s
+    kernels on the TPU where the widths and the positions tile, else as
+    gathers and ``ragged_dot``; the gauges ``paddle_tpu_moe_fused`` / ``_plain`` count
+    a traced step's layers by form. Each traced layer adds to the gauges
+    ``paddle_tpu_moe_*`` and to the step's two data counters
+    (docs/observability.md)."""
     name = name or auto_name("moe")
     d = input.size
     enforce(0 <= first_held and first_held + experts_held <= experts_total,
@@ -243,6 +247,7 @@ def moe(input, experts_total, experts_held, first_held, top_k, width,
         ctx.moe["held"] = experts_held
         ctx.moe["total"] = experts_total
         ctx.moe["rows_bound"] += top_k * rows.shape[0]
+        ctx.moe[moe_ops.experts_form(d, width, rows.shape[0])] += 1
         ctx.count("paddle_tpu_moe_rows_here", here)
         ctx.count("paddle_tpu_moe_expert_load_max", busiest)
         return like(seq, out.reshape(x.shape))

@@ -24,6 +24,13 @@ Gradients are autodiff's, through the grouped products to the experts'
 weights and the rows, and through ``w`` to the router; the choice and the
 bias carry none. The two gathers are permutations, so each one's backward
 is the other's gather (``custom_vjp``) and no scatter-add runs.
+
+Two forms of the gather, the experts and the combine
+(:func:`experts_form`). On the TPU, where the widths and the positions
+tile, the fused one: ``ops/pallas_moe.py``'s kernels, which read, write
+and multiply only the rows the groups fill. Everywhere else the plain one
+above, which is also the kernels' oracle. The router and the dispatch are
+the same in both.
 """
 
 import functools
@@ -32,6 +39,7 @@ import jax
 import jax.numpy as jnp
 
 from paddle_tpu.core.dtype import upcast_f32
+from paddle_tpu.ops import pallas_moe
 
 NORM_EPS = 1e-6   # in the sum of a token's chosen scores
 
@@ -140,14 +148,28 @@ def combine(y, order, place, here, weights):
                           weights).astype(y.dtype)
 
 
+def experts_form(d, width, tokens):
+    """"fused" where :func:`moe` over ``tokens`` rows runs
+    ``ops/pallas_moe.py``'s kernels, "plain" where it runs the gathers,
+    ``ragged_dot`` and the combine of this module: the backend and the
+    shapes decide (``pallas_moe.fits``), nothing else."""
+    return "fused" if pallas_moe.fits(d, width, tokens) else "plain"
+
+
 def moe(x, valid, w_router, bias, w_in, w_out, k, first_held, scaling=1.0,
         normalize=True, kept=lambda product: product):
     """The layer over rows x [N, d]: (out [N, d], rows computed here
-    (int32 scalar), the busiest held expert's rows (int32 scalar))."""
+    (int32 scalar), the busiest held expert's rows (int32 scalar)), in the
+    form :func:`experts_form` chooses."""
     held = w_in.shape[0]
     chosen, weights = route(x, w_router, bias, k, scaling, normalize)
     order, place, sizes, here = dispatch(chosen, valid, first_held, held)
     total = jnp.sum(sizes)
+    if experts_form(x.shape[1], w_out.shape[1], x.shape[0]) == "fused":
+        with jax.named_scope("paddle_tpu.moe_experts"):
+            out = pallas_moe.experts(x, order, place, sizes, weights, w_in,
+                                     w_out, k, kept)
+        return out, total, jnp.max(sizes)
     with jax.named_scope("paddle_tpu.moe_dispatch"):
         used = jnp.arange(order.shape[0]) < total
         rows = jnp.where(used[:, None], gather_rows(x, order, place, k), 0)

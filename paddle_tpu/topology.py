@@ -169,7 +169,8 @@ class Topology:
             # blocks keep for backward, what they hand to later blocks
             # (layer/decoder.py recompute), which key blocks attention
             # visits (gqa_attention), which form its Mamba-1 scans took
-            # (mamba1), what its expert layers hold (moe)
+            # (mamba1), what its expert layers hold and which form
+            # their row passes took (moe)
             registry = observe_metrics.get_registry()
             for name, value, help_ in (
                     ("recompute_kept_bytes", ctx.recompute_kept_bytes,
@@ -202,7 +203,13 @@ class Topology:
                     ("moe_rows_bound", ctx.moe["rows_bound"],
                      "rows of the sorted buffers of a train step's expert "
                      "layers, choices x positions x layers: no routing "
-                     "overflows them")):
+                     "overflows them"),
+                    ("moe_fused", ctx.moe["fused"],
+                     "expert layers of a train step whose row passes run "
+                     "as the fused Pallas kernels"),
+                    ("moe_plain", ctx.moe["plain"],
+                     "expert layers of a train step whose row passes run "
+                     "as a gather and ragged_dot")):
                 registry.gauge("paddle_tpu_" + name, help=help_
                                + ", of the program traced last").set(value)
         wanted = outputs or [o.name for o in self.outputs]

@@ -645,3 +645,24 @@ def test_the_table_of_mixers_says_what_each_kind_offers():
             if m.makes or m.reads} == {
         "mamba1": ("memory", None), "full_attention": ("kv", None),
         "gmu": (None, "memory"), "cross_attention": (None, "kv")}
+
+
+@pytest.mark.parametrize("which", ["granite", "olmo"])
+def test_a_model_without_expert_layers_never_reaches_the_expert_kernels(
+        which, cfg, olmo, monkeypatch):
+    """Granite's and Olmo's presets as their cells build them: the
+    trainer's lowered step is the same text whether an expert layer would
+    take `ops/pallas_moe.py`'s kernels or not, so nothing of theirs runs
+    the code that chooses (the byte-for-byte check against the parent
+    commit is PERF.md's, PR 38)."""
+    from chipbench.models import olmo_hybrid as olmo_model
+    from paddle_tpu.ops import pallas_moe
+
+    build, preset, cell = {
+        "granite": (bench_model.build, cfg, CELL),
+        "olmo": (olmo_model.build, olmo, OLMO_CELL)}[which]
+    texts = []
+    for fits in (False, True):
+        monkeypatch.setattr(pallas_moe, "fits", lambda *a, fits=fits: fits)
+        texts.append(_trainers_step(build, preset, cell, monkeypatch)[0])
+    assert texts[0] == texts[1]

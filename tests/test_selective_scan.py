@@ -7,6 +7,7 @@ Then what only the fused form has: who chooses it, what its program holds,
 what it costs. Float32 at `highest`."""
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -386,18 +387,30 @@ def test_the_kernels_compile_for_v5e_at_the_published_size(one_chip,
     assert compiled.memory_analysis().temp_size_in_bytes < 0.6e9
 
 
-def test_the_expert_layer_compiles_for_v5e_at_the_published_size(one_chip):
+@pytest.mark.parametrize("form", ["plain", "fused"])
+def test_the_expert_layer_compiles_for_v5e_at_the_published_size(
+        one_chip, form, monkeypatch):
     """`ops/moe.py moe`, forward and backward, at the `lfm2-8b-a1b` cell's
     shapes (8,192 positions of 2,048, top-4 of 32, 8 experts of 1,792
     held) for a described v5e. It lives here because only one test file
-    may describe the chip (the module's fixture). The grouped products
-    become Mosaic calls (`ragged-dot` with its metadata: the tiles the
-    groups fill), six of them forward and backward, and nothing holds a
-    [held, rows, width] expansion of the buffer: that dense form, which
-    the CPU lowers to, would be 1.9 GB."""
+    may describe the chip (the module's fixture). The plain form (which
+    the TPU no longer takes at these shapes): the grouped products become
+    Mosaic calls (`ragged-dot` with its metadata: the tiles the groups
+    fill), six of them, and nothing holds a [held, rows, width]
+    expansion of the buffer: that dense form, which the CPU lowers to,
+    would be 1.9 GB. The fused form: the ten named kernels of
+    `ops/pallas_moe.py`, and outside them nothing that makes or moves a
+    row of the 32,768-row buffer as wide as an expert (the pair
+    permutations are kernels too), in less temporary memory than the
+    plain form's 1.03 GB."""
     from jax.experimental.compilation_cache import compilation_cache
 
     from paddle_tpu.ops import moe as moe_ops
+
+    if form == "fused":
+        monkeypatch.setattr(pk, "_INTERPRET", True)
+        monkeypatch.setattr(pk, "_interpret", lambda: False)
+    assert moe_ops.experts_form(2048, 1792, 8192) == form
 
     def shape(dims, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
@@ -411,8 +424,8 @@ def test_the_expert_layer_compiles_for_v5e_at_the_published_size(one_chip):
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
     try:
-        compiled = jax.jit(jax.grad(total, argnums=range(4),
-                                    has_aux=True)).lower(
+        compiled = jax.jit(jax.value_and_grad(total, argnums=range(4),
+                                              has_aux=True)).lower(
             shape((8192, 2048)), shape((2048, 32)), shape((8, 2048, 3584)),
             shape((8, 1792, 2048)), shape((32,), jnp.float32),
             shape((8192,), jnp.bool_)).compile()
@@ -420,8 +433,29 @@ def test_the_expert_layer_compiles_for_v5e_at_the_published_size(one_chip):
         jax.config.update("jax_enable_compilation_cache", was)
         compilation_cache.reset_cache()
     text = compiled.as_text()
-    assert text.count('op_name="ragged-dot-metadata"') >= 1
-    assert text.count("tpu_custom_call") >= 6
-    # the sorted rows, two products' outputs and their gradients: 32,768
-    # rows of 2,048 to 3,584 values in bfloat16, well under a gigabyte
-    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    if form == "plain":
+        assert text.count('op_name="ragged-dot-metadata"') >= 1
+        assert text.count("tpu_custom_call") >= 6
+        # the sorted rows, two products' outputs and their gradients:
+        # 32,768 rows of 2,048 to 3,584 values in bfloat16, under 1.5 GB
+        assert temp < 1.5e9
+        return
+    names = ("moe_gather", "moe_gmm_in", "moe_gmm_out", "moe_combine",
+             "moe_combine_t", "moe_gmm_out_t", "moe_tgmm_out", "moe_gmm_in_t",
+             "moe_gather_t", "moe_tgmm_in")
+    assert text.count("tpu_custom_call") == len(names)
+    for name in names:
+        assert re.search(r"%%%s(\.\d+)? = .*tpu_custom_call" % name, text), name
+    # the step's own instructions (a fusion's insides carry no names): none
+    # but the kernels makes or moves a row of the buffer
+    entry = text[text.index("\nENTRY"):]
+    entry = entry[:entry.index("\n}")]
+    for line in re.findall(r"^\s*(?:ROOT )?%\S+ = \w+\[32768,\d+\][^\n]*",
+                           entry, re.M):
+        width = int(re.search(r"\[32768,(\d+)\]", line).group(1))
+        assert width < 1792 or re.search(
+            r"tpu_custom_call|parameter\(|get-tuple-element\(", line), \
+            line[:200]
+    # the parent's plain form read 1,026,910,208 bytes
+    assert temp < 1_026_910_208
