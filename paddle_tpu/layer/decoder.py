@@ -191,29 +191,33 @@ def _named_spec(name, suffix, shape, initializer=None, std=None,
 @register_layer("moe")
 def moe(input, experts_total, experts_held, first_held, top_k, width,
         normalize=True, scaling=1.0, use_bias=True, initial_std=0.02,
-        name=None, layer_attr=None):
+        name=None, layer_attr=None, shared_width=None):
     """A sparse expert layer on a chip that holds ``experts_held`` of the
     ``experts_total`` experts, those from ``first_held`` on
     (``ops/moe.py``):
         s = sigmoid(u W_r)                    all experts, float32
         chosen = top_k(s + expert_bias)       the bias selects and no more
         w = s[chosen] / (sum s[chosen] + 1e-6) * scaling      ``normalize``
-        out = sum over chosen e held here of w_e * expert_e(u)
-    each expert a gated MLP of ``width``. What the absent experts would
+        out = sum over chosen e held here of w_e * expert_e(u)  [+ shared(u)]
+    each expert a gated MLP of ``width``; with ``shared_width`` a shared
+    expert beside them, a gated MLP of that width that every token crosses
+    unweighted and every chip computes alike. What the absent experts would
     add is left out (their chips compute it); no pair of a held expert is
     dropped whatever the imbalance, the sorted buffer having ``top_k`` rows
     a position; padded positions route nowhere. Parameters
     ``<name>.router`` [d, total], ``.expert_bias`` [total] (static: it
     selects and is never differentiated; zeros at the start; absent
     without ``use_bias``), ``.w_in`` [held, d, 2 * width] (gate then up),
-    ``.w_out`` [held, width, d]. The first grouped product carries the
-    name ``MOE_PRODUCT``, which a ``recompute`` block around the layer may
-    keep. The passes over the sorted rows run as ``ops/pallas_moe.py``'s
-    kernels on the TPU where the widths and the positions tile, else as
-    gathers and ``ragged_dot``; the gauges ``paddle_tpu_moe_fused`` / ``_plain`` count
-    a traced step's layers by form. Each traced layer adds to the gauges
-    ``paddle_tpu_moe_*`` and to the step's two data counters
-    (docs/observability.md)."""
+    ``.w_out`` [held, width, d]; ``.shared_in`` [d, 2 * shared_width] and
+    ``.shared_out`` with ``shared_width``. The first grouped product
+    carries the name ``MOE_PRODUCT``, which a ``recompute`` block around
+    the layer may keep. The passes over the sorted rows run as
+    ``ops/pallas_moe.py``'s kernels on the TPU where the widths and the
+    positions tile, else as gathers and ``ragged_dot``; the gauges
+    ``paddle_tpu_moe_fused`` / ``_plain`` count a traced step's layers by
+    form. Each traced layer adds to the gauges ``paddle_tpu_moe_*`` and to
+    the step's data counters, the fused form to
+    ``paddle_tpu_moe_rows_visited`` too (docs/observability.md)."""
     name = name or auto_name("moe")
     d = input.size
     enforce(0 <= first_held and first_held + experts_held <= experts_total,
@@ -231,6 +235,12 @@ def moe(input, experts_total, experts_held, first_held, top_k, width,
         specs["expert_bias"] = _named_spec(
             name, "expert_bias", (experts_total,), Constant(0.0),
             static=True)
+    if shared_width:
+        specs["shared_in"] = _named_spec(name, "shared_in",
+                                         (d, 2 * shared_width),
+                                         std=initial_std)
+        specs["shared_out"] = _named_spec(name, "shared_out",
+                                          (shared_width, d), std=initial_std)
 
     def forward(params, values, ctx):
         seq = values[0]
@@ -240,16 +250,23 @@ def moe(input, experts_total, experts_held, first_held, top_k, width,
         valid = jnp.ones(rows.shape[:1], bool) if lengths is None else (
             jnp.arange(x.shape[1])[None, :] < lengths[:, None]).reshape(-1)
         p = {k: params[s.name] for k, s in specs.items()}
-        out, here, busiest = moe_ops.moe(
+        out, here, busiest, visited = moe_ops.moe(
             rows, valid, p["router"], p.get("expert_bias"), p["w_in"],
             p["w_out"], top_k, first_held, scaling, normalize,
             kept=lambda product: _kept(product, MOE_PRODUCT, ctx))
+        if shared_width:
+            with jax.named_scope("paddle_tpu.shared_expert"):
+                a, b = jnp.split(jnp.matmul(rows, p["shared_in"]), 2,
+                                 axis=-1)
+                out = out + jnp.matmul(jax.nn.silu(a) * b, p["shared_out"])
         ctx.moe["held"] = experts_held
         ctx.moe["total"] = experts_total
         ctx.moe["rows_bound"] += top_k * rows.shape[0]
-        ctx.moe[moe_ops.experts_form(d, width, rows.shape[0])] += 1
+        ctx.moe[moe_ops.experts_form(d, width, rows.shape[0], top_k)] += 1
         ctx.count("paddle_tpu_moe_rows_here", here)
         ctx.count("paddle_tpu_moe_expert_load_max", busiest)
+        if visited is not None:
+            ctx.count("paddle_tpu_moe_rows_visited", visited)
         return like(seq, out.reshape(x.shape))
 
     return make_node("moe", forward, [input], name=name, size=d,
@@ -600,7 +617,8 @@ def _differential_attention(q, k, v, lam, lam_init, norm_w, eps, scale,
 def gqa_attention(input, heads, kv_heads, head_dim, scale=None, block=512,
                   initial_std=0.02, name=None, layer_attr=None,
                   qk_norm=False, eps=1e-5, window=None, differential=None,
-                  bias=False, kv=None, hand_out=False, rope_theta=None):
+                  bias=False, kv=None, hand_out=False, rope_theta=None,
+                  rope=None, gate=None):
     """Causal self-attention with ``heads`` query heads over ``kv_heads``
     shared key-value heads, no positional encoding and no bias; scores are
     multiplied by ``scale`` (1 / sqrt(head_dim) by default). Blockwise
@@ -609,8 +627,12 @@ def gqa_attention(input, heads, kv_heads, head_dim, scale=None, block=512,
     each over its whole projection, before the split into heads (the
     OLMo 2 layout), or with ``qk_norm="head"`` each head over its own
     ``head_dim`` values, one scale [head_dim] for the queries and one for
-    the keys. With ``rope_theta`` queries and keys then turn by rotary
-    positions 0..T-1 over the whole head (``ops/attention.py rotary``).
+    the keys. With ``rope`` (the keywords of ``ops/attention.py rotary``:
+    ``theta``, and ``dims``, ``inverse``, ``factor`` for a partial or
+    scaled turn) queries and keys then turn by rotary positions 0..T-1;
+    ``rope_theta`` is ``rope={"theta": rope_theta}``, the whole head.
+    With ``gate="head"`` each head's output is multiplied by its own
+    sigmoid(u W_g) before the output projection, ``W_g`` [d, heads].
     With ``window`` a query sees the ``window`` keys that
     end with its own, and key blocks outside are not visited. With
     ``differential`` (the layer's starting lambda, ``lambda_init`` of its
@@ -624,13 +646,19 @@ def gqa_attention(input, heads, kv_heads, head_dim, scale=None, block=512,
     ``.k``, ``.v``, ``.o``; ``.q_norm``, ``.k_norm`` with ``qk_norm``;
     ``.q_b``, ``.k_b``, ``.v_b``, ``.o_b`` with ``bias``; ``.lambda_q1``,
     ``.lambda_k1``, ``.lambda_q2``, ``.lambda_k2`` [head_dim] and
-    ``.subln`` [2 * head_dim] with ``differential``."""
+    ``.subln`` [2 * head_dim] with ``differential``; ``.g`` with
+    ``gate``."""
     name = name or auto_name("gqa_attention")
     d = input.size
     enforce(heads % kv_heads == 0, "kv_heads %d must divide heads %d",
             kv_heads, heads)
     enforce(differential is None or kv_heads % 2 == 0,
             "differential attention pairs heads: kv_heads is %d", kv_heads)
+    enforce(gate in (None, "head"), "gqa_attention: gate is %r, not None "
+            "or head", gate)
+    enforce(not (rope and rope_theta), "gqa_attention: rope_theta is "
+            "rope={'theta': ...}; give one of the two")
+    rope = {"theta": float(rope_theta)} if rope_theta else rope
     scale = scale if scale is not None else head_dim ** -0.5
     widths = {"q": heads * head_dim, "k": kv_heads * head_dim,
               "v": kv_heads * head_dim}
@@ -640,7 +668,7 @@ def gqa_attention(input, heads, kv_heads, head_dim, scale=None, block=512,
     specs["o"] = _named_spec(name, "o", (heads * head_dim, d),
                              std=initial_std)
     per_head = qk_norm == "head"
-    enforce(kv is None or not (per_head or rope_theta),
+    enforce(kv is None or not (per_head or rope),
             "gqa_attention: cross-attention takes another layer's keys as "
             "they are, without a per-head norm or rotary positions")
     if qk_norm:
@@ -660,6 +688,8 @@ def gqa_attention(input, heads, kv_heads, head_dim, scale=None, block=512,
             specs[n] = _named_spec(name, n, (head_dim,), std=0.1)
         specs["subln"] = _named_spec(name, "subln", (2 * head_dim,),
                                      Constant(1.0))
+    if gate:
+        specs["g"] = _named_spec(name, "g", (d, heads), std=initial_std)
 
     def forward(params, values, ctx):
         seq = values[0]
@@ -683,8 +713,8 @@ def gqa_attention(input, heads, kv_heads, head_dim, scale=None, block=512,
                 with jax.named_scope("paddle_tpu.qk_norm"):
                     x = _rms_normalize(x, params[specs[n + "_norm"].name],
                                        eps)
-            if rope_theta:
-                x = attention_ops.rotary(x, float(rope_theta))
+            if rope:
+                x = attention_ops.rotary(x, **rope)
             return x
 
         def attend(q, k, v):
@@ -715,6 +745,12 @@ def gqa_attention(input, heads, kv_heads, head_dim, scale=None, block=512,
             with jax.named_scope("paddle_tpu.window_attention") \
                     if window is not None else contextlib.nullcontext():
                 y = attend(q, k, v)
+            if gate:
+                with jax.named_scope("paddle_tpu.attention_gate"):
+                    g = jax.nn.sigmoid(jnp.matmul(
+                        u, params[specs["g"].name],
+                        preferred_element_type=upcast_f32(u).dtype))
+                    y = y * g.astype(y.dtype)[..., None]
             out = jnp.matmul(y.reshape(b, t, heads * head_dim),
                              params[specs["o"].name])
             if bias:

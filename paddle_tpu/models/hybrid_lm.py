@@ -12,9 +12,13 @@ https://huggingface.co/microsoft/Phi-4-mini-flash-reasoning,
 arXiv:2507.06607): Mamba-1 layers beside window attention, one
 full-attention layer, then Gated Memory Units and cross-attention; and
 gated short convolutions beside rotary attention with per-head norms of
-queries and keys (``lfm2_moe``: https://huggingface.co/LiquidAI/LFM2-8B-A1B).
+queries and keys (``lfm2_moe``: https://huggingface.co/LiquidAI/LFM2-8B-A1B);
+full attention with partial YaRN rotary positions beside window attention
+with plain ones, each kind with its own number of heads and every head
+gated (``laguna``: https://huggingface.co/poolside/Laguna-XS.2).
 The feed-forward is chosen layer by layer: one gated MLP (``dense``), or
-sparse experts of which this chip holds some (``experts``).
+sparse experts of which this chip holds some (``experts``), beside a
+shared expert or not.
 
     h0 = embedding_multiplier * E[ids]
     norm "before":  h += residual_multiplier * mixer_i(N(h), shared)    mixer by layer_types[i]
@@ -29,6 +33,7 @@ sparse experts of which this chip holds some (``experts``).
                       chosen = top_k(s + expert_bias)     the bias selects and no more
                       w = s[chosen] / (sum s[chosen] + 1e-6) * scaling
                       sum over chosen e held here of w_e * expert_e(u), each a gated MLP
+                      [+ shared(u), a gated MLP of shared_width, unweighted]
     conv mixer:       [B, C, x] = u W_in;  (C * conv1d_causal(B * x)) W_out    3 taps, no bias
 
 A layer whose index in the whole model is under ``dense_layers`` has the
@@ -86,6 +91,7 @@ what the later layers kept has been used and freed.
 """
 
 import collections
+import math
 
 from paddle_tpu import data_type
 from paddle_tpu import layer as L
@@ -93,6 +99,7 @@ from paddle_tpu.attr import ParamAttr
 from paddle_tpu.layer.decoder import (GATED_MLP_PRODUCT, MAMBA1_IN_PRODUCT,
                                       MAMBA_IN_PRODUCT, MOE_PRODUCT,
                                       lambda_init)
+from paddle_tpu.ops import attention as attention_ops
 from paddle_tpu.utils.error import enforce
 
 # A layer kind: the mixer's layer; which of hybrid_lm's groups of options
@@ -149,17 +156,18 @@ def hybrid_lm(vocab, hidden, layer_types, mlp_size, attention=None,
     """Builds the model over two ``integer_value_sequence`` slots, tokens
     and targets. The options of each kind of mixer in ``layer_types``:
     ``attention`` (and ``sliding_attention``): heads, kv_heads, head_dim,
-    and scale, block, qk_norm, bias, window, rope_theta, differential
+    and scale, block, qk_norm, bias, window, rope_theta or rope (rotary's
+    keywords: theta, dims, inverse, factor), gate ("head"), differential
     (True: each layer's starting lambda follows its index);
     ``mamba``: heads, head_dim, state, conv_width, groups, chunk;
     ``mamba1``: state, conv_width, expand, dt_rank, chunk;
     ``linear_attention``: heads, key_dim, value_dim, conv_width,
     neg_eigval, chunk; ``conv``: conv_width. ``experts``: the options of
     ``layer.moe`` (experts_total, experts_held, first_held, top_k, width,
-    normalize, scaling, use_bias), the feed-forward of every layer whose
-    index in the whole model is ``dense_layers`` or more (None: every
-    layer has the gated MLP of ``mlp_size``). ``norm``: "before" each
-    branch or "after" it; ``norm_kind``: "rms" or "layer" (LayerNorm with a bias), the final
+    normalize, scaling, use_bias, shared_width), the feed-forward of every
+    layer whose index in the whole model is ``dense_layers`` or more
+    (None: every layer has the gated MLP of ``mlp_size``). ``norm``:
+    "before" each branch or "after" it; ``norm_kind``: "rms" or "layer" (LayerNorm with a bias), the final
     norm too. ``layer_indices``: the index each layer has in the whole
     model, where ``layer_types`` is a cut of it (its own position by
     default). ``tie_head=False`` gives the head a table of its own,
@@ -267,8 +275,9 @@ def hybrid_lm(vocab, hidden, layer_types, mlp_size, attention=None,
 def _granite_options(cfg):
     enforce(not cfg.get("num_local_experts"),
             "hybrid_lm reads no granitemoehybrid expert keys yet "
-            "(num_local_experts %r, num_experts_per_tok, a shared expert "
-            "beside the routed ones): its expert layer is lfm2_moe's",
+            "(num_local_experts %r): layer.moe routes by sigmoid scores, "
+            "beside a shared expert or not (shared_width), and has no "
+            "softmax router, which granitemoehybrid's experts take",
             cfg.get("num_local_experts"))
     hidden = cfg["hidden_size"]
     heads = cfg["num_attention_heads"]
@@ -364,11 +373,91 @@ def _lfm2_moe_options(cfg):
         tie_head=cfg.get("tie_word_embeddings", True))
 
 
+def _rope(params, head_dim):
+    """``rotary``'s keywords from one layer kind's entry of
+    ``rope_parameters`` (the ``transformers`` layout): the first
+    ``partial_rotary_factor`` share of a head turns, by theta's plain
+    frequencies (``rope_type`` default) or YaRN's, whose
+    ``attention_factor`` multiplies cos and sin."""
+    kind = params.get("rope_type", "default")
+    enforce(kind in ("default", "yarn"), "hybrid_lm turns by plain or YaRN "
+            "rotary positions, not rope_type %r", kind)
+    dims = int(head_dim * params.get("partial_rotary_factor", 1.0))
+    rope = {"theta": float(params["rope_theta"])}
+    if dims != head_dim:
+        rope["dims"] = dims
+    if kind == "yarn":
+        rope["inverse"] = attention_ops.yarn_inverse_frequencies(
+            dims, rope["theta"], params["factor"],
+            params["original_max_position_embeddings"],
+            params.get("beta_fast", 32), params.get("beta_slow", 1))
+        rope["factor"] = float(params.get(
+            "attention_factor", 0.1 * math.log(params["factor"]) + 1.0))
+    return rope
+
+
+def _laguna_options(cfg):
+    """Laguna: full attention layers beside window layers (``sliding_window``
+    keys), each kind with its own number of query heads
+    (``num_attention_heads_per_layer``, one number a kind) and its own
+    rotary positions (``rope_parameters`` by kind), every head's output
+    times its own sigmoid gate (``gating``); the leading ``dense`` entries
+    of ``mlp_layer_types`` have a gated MLP, the rest sparse experts, sigmoid
+    top-k normalised and scaled by ``moe_routed_scaling_factor``, beside a
+    shared expert; an untied head. ``num_experts`` is what this chip holds
+    of ``num_experts_published``, from ``first_expert`` on, as for
+    ``lfm2_moe``. The config has no key for the scores' function or their
+    normalisation: sigmoids, normalised."""
+    enforce(cfg["gating"] is True, "hybrid_lm reads gating true as a gate "
+            "a head, not %r", cfg["gating"])
+    enforce(not cfg.get("attention_bias")
+            and not cfg.get("moe_apply_router_weight_on_input"),
+            "hybrid_lm has no attention bias and weighs an expert's output, "
+            "not its input (attention_bias %r, "
+            "moe_apply_router_weight_on_input %r)",
+            cfg.get("attention_bias"),
+            cfg.get("moe_apply_router_weight_on_input"))
+    kinds = cfg["mlp_layer_types"]
+    dense = next((i for i, k in enumerate(kinds) if k != "dense"),
+                 len(kinds))
+    enforce(all(k == "sparse" for k in kinds[dense:]), "mlp_layer_types "
+            "lists dense layers after the first sparse one: %s", kinds)
+    heads = {}
+    for kind, count in zip(cfg["layer_types"],
+                           cfg["num_attention_heads_per_layer"]):
+        enforce(heads.setdefault(kind, count) == count, "%s layers have %d "
+                "and %d query heads", kind, heads[kind], count)
+    head_dim = cfg["head_dim"]
+
+    def attention(kind):
+        return {"heads": heads[kind], "kv_heads": cfg["num_key_value_heads"],
+                "head_dim": head_dim, "gate": "head",
+                "rope": _rope(cfg["rope_parameters"][kind], head_dim)}
+
+    return dict(
+        mlp_size=cfg["intermediate_size"], eps=cfg["rms_norm_eps"],
+        attention=attention("full_attention"),
+        sliding_attention=dict(attention("sliding_attention"),
+                               window=cfg["sliding_window"]),
+        dense_layers=dense,
+        experts={"experts_total": cfg.get("num_experts_published",
+                                          cfg["num_experts"]),
+                 "experts_held": cfg["num_experts"],
+                 "first_held": cfg.get("first_expert", 0),
+                 "top_k": cfg["num_experts_per_tok"],
+                 "width": cfg["moe_intermediate_size"],
+                 "scaling": cfg["moe_routed_scaling_factor"],
+                 "use_bias": False,
+                 "shared_width": cfg["shared_expert_intermediate_size"]},
+        tie_head=cfg["tie_word_embeddings"])
+
+
 # config.json's model_type: the options hybrid_lm takes from its keys
 MODEL_TYPES = {"granitemoehybrid": _granite_options,
                "olmo_hybrid": _olmo_hybrid_options,
                "phi4flash": _phi4flash_options,
-               "lfm2_moe": _lfm2_moe_options}
+               "lfm2_moe": _lfm2_moe_options,
+               "laguna": _laguna_options}
 
 
 def from_config(cfg, recompute=True, prefix="lm", keep_layers=None):

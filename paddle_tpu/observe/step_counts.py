@@ -24,6 +24,10 @@ COUNTS = {
     "paddle_tpu_moe_expert_load_max": (
         "max", "rows of the busiest held expert of a train step, the "
         "largest over its expert layers"),
+    "paddle_tpu_moe_rows_visited": (
+        "sum", "rows a train step's fused expert layers' grouped products "
+        "cover with their row tiles (a tile two groups share counts "
+        "twice), all layers"),
 }
 # where the step's counters ride among its evaluators' statistics
 KEY = "paddle_tpu.step_counts"

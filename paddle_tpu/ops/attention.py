@@ -9,9 +9,11 @@ is what ``layer.gqa_attention`` runs.
 """
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from paddle_tpu.core import dtype as dtype_mod
 
@@ -130,20 +132,59 @@ def blockwise_attention(q, k, v, scale, causal=True, lengths=None,
     return jnp.concatenate(out, axis=1)[:, :t]
 
 
-def rotary(x, theta):
-    """Rotary positions 0..T-1 over the whole last axis of x
-    [B, T, H, D], D even, in the rotate-half pairing (value i turns with
-    value i + D/2 by the angle position * theta^(-2i/D)); angles, sines
-    and the turn in float32."""
+def rotary(x, theta, dims=None, inverse=None, factor=1.0):
+    """Rotary positions 0..T-1 over the first ``dims`` values (all D by
+    default, even) of the last axis of x [B, T, H, D], in the rotate-half
+    pairing: value i turns with value i + dims/2 by the angle position *
+    inverse[i], ``inverse`` [dims/2] given (a scaled table, as
+    :func:`yarn_inverse_frequencies` makes) or theta^(-2i/dims). Cosines
+    and sines are multiplied by ``factor``; the values past ``dims`` pass
+    through as they are. Angles, sines and the turn in float32."""
     with jax.named_scope("paddle_tpu.rope"):
-        half = x.shape[-1] // 2
+        dims = x.shape[-1] if dims is None else dims
+        half = dims // 2
         wide = dtype_mod.wide(x.dtype)
-        inverse = theta ** (-jnp.arange(half, dtype=wide) / half)
+        if inverse is None:
+            inverse = theta ** (-jnp.arange(half, dtype=wide) / half)
+        else:
+            inverse = jnp.asarray(inverse, wide)
         angles = jnp.arange(x.shape[1], dtype=wide)[:, None] \
             * inverse[None, :]
         cos = jnp.cos(angles)[None, :, None, :]
         sin = jnp.sin(angles)[None, :, None, :]
-        first, second = jnp.split(x.astype(wide), 2, axis=-1)
-        return jnp.concatenate([first * cos - second * sin,
-                                second * cos + first * sin],
-                               axis=-1).astype(x.dtype)
+        if factor != 1.0:
+            cos, sin = cos * factor, sin * factor
+        turned = x if dims == x.shape[-1] else x[..., :dims]
+        first, second = jnp.split(turned.astype(wide), 2, axis=-1)
+        out = [first * cos - second * sin, second * cos + first * sin]
+        if dims != x.shape[-1]:
+            out.append(x[..., dims:].astype(wide))
+        return jnp.concatenate(out, axis=-1).astype(x.dtype)
+
+
+def yarn_inverse_frequencies(dim, theta, factor, original, beta_fast=32,
+                             beta_slow=1):
+    """[dim / 2] float32 inverse frequencies of YaRN (Peng et al. 2023,
+    arXiv:2309.00071) over ``dim`` rotated values, in the form of
+    ``transformers``' ``_compute_yarn_parameters``: pair i blends
+    theta^(-2i/dim) (extrapolated) and the same over ``factor``
+    (interpolated) by a ramp between the pairs that turn ``beta_fast``
+    and ``beta_slow`` times over ``original`` positions, floored and
+    ceiled; the fast pairs keep their frequency, the slow ones take the
+    interpolated one. The attention factor multiplies cos and sin in
+    :func:`rotary`, not this table."""
+    def pair_of(turns):
+        return dim * math.log(original / (turns * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(pair_of(beta_fast)), 0)
+    high = min(math.ceil(pair_of(beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    freqs = np.float32(theta) ** (np.arange(0, dim, 2, dtype=np.float32)
+                                  / np.float32(dim))
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float32) - low)
+                   / (high - low), 0, 1).astype(np.float32)
+    kept = 1 - ramp    # the share of the extrapolated frequency
+    return ((1 / (factor * freqs)) * (1 - kept) + (1 / freqs) * kept
+            ).astype(np.float32)

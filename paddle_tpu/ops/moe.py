@@ -148,30 +148,33 @@ def combine(y, order, place, here, weights):
                           weights).astype(y.dtype)
 
 
-def experts_form(d, width, tokens):
-    """"fused" where :func:`moe` over ``tokens`` rows runs
+def experts_form(d, width, tokens, k):
+    """"fused" where :func:`moe` over ``tokens`` rows routed ``k`` times runs
     ``ops/pallas_moe.py``'s kernels, "plain" where it runs the gathers,
     ``ragged_dot`` and the combine of this module: the backend and the
     shapes decide (``pallas_moe.fits``), nothing else."""
-    return "fused" if pallas_moe.fits(d, width, tokens) else "plain"
+    return "fused" if pallas_moe.fits(d, width, tokens, k) else "plain"
 
 
 def moe(x, valid, w_router, bias, w_in, w_out, k, first_held, scaling=1.0,
         normalize=True, kept=lambda product: product):
     """The layer over rows x [N, d]: (out [N, d], rows computed here
-    (int32 scalar), the busiest held expert's rows (int32 scalar)), in the
-    form :func:`experts_form` chooses."""
+    (int32 scalar), the busiest held expert's rows (int32 scalar), the
+    rows the grouped products' row tiles cover (int32 scalar; None in the
+    plain form, whose products' tiling is XLA's)), in the form
+    :func:`experts_form` chooses."""
     held = w_in.shape[0]
     chosen, weights = route(x, w_router, bias, k, scaling, normalize)
     order, place, sizes, here = dispatch(chosen, valid, first_held, held)
     total = jnp.sum(sizes)
-    if experts_form(x.shape[1], w_out.shape[1], x.shape[0]) == "fused":
+    if experts_form(x.shape[1], w_out.shape[1], x.shape[0], k) == "fused":
         with jax.named_scope("paddle_tpu.moe_experts"):
-            out = pallas_moe.experts(x, order, place, sizes, weights, w_in,
-                                     w_out, k, kept)
-        return out, total, jnp.max(sizes)
+            out, visited = pallas_moe.experts(x, order, place, sizes,
+                                              weights, w_in, w_out, k, kept)
+        return out, total, jnp.max(sizes), visited
     with jax.named_scope("paddle_tpu.moe_dispatch"):
         used = jnp.arange(order.shape[0]) < total
         rows = jnp.where(used[:, None], gather_rows(x, order, place, k), 0)
     y = experts(rows, sizes, w_in, w_out, kept)
-    return combine(y, order, place, here, weights), total, jnp.max(sizes)
+    return combine(y, order, place, here, weights), total, jnp.max(sizes), \
+        None

@@ -55,20 +55,27 @@ from paddle_tpu.ops import pallas_kernels as pk
 _LANES = 128
 _ROW_TILE = 256        # rows a tile
 _WEIGHT_BLOCK = 1 << 22   # d x width at most: an expert's half of W_in in VMEM
+# k x N pairs at most: a list of 4 bytes a pair, prefetched whole, takes
+# half of the 1 MiB scalar memory at top-8 of 16,384 positions (compiled
+# for v5e); twice that cannot fit beside anything
+_SMEM_PAIRS = 1 << 17
 _F32 = jnp.float32
 _TRANSPOSED_RHS = (((1,), (1,)), ((), ()))   # a [m, k] . b [n, k]
 _TRANSPOSED_LHS = (((0,), (0,)), ((), ()))   # a [m, k]^T . b [m, n]
 
 
-def fits(d, width, tokens):
-    """Whether the fused form runs over ``tokens`` rows: Pallas can lower
-    here (the TPU backend, or the tests' interpret flag), the widths tile
-    the lanes, the tokens (and so the sorted rows) tile, an expert's [d,
-    width] block fits VMEM, and the step is traced for one device: XLA
-    cannot partition a Mosaic kernel."""
+def fits(d, width, tokens, k):
+    """Whether the fused form runs over ``tokens`` rows routed ``k`` times
+    each: Pallas can lower here (the TPU backend, or the tests' interpret
+    flag), the widths tile the lanes, the tokens (and so the sorted rows)
+    tile, an expert's [d, width] block fits VMEM, the pairs' int32 lists
+    that the gathers and pair sums prefetch whole fit the scalar memory,
+    and the step is traced for one device: XLA cannot partition a Mosaic
+    kernel."""
     scope = mesh_scope.current()
     return pk.enabled() and d % _LANES == 0 and width % _LANES == 0 \
         and tokens % _ROW_TILE == 0 and d * width <= _WEIGHT_BLOCK \
+        and k * tokens <= _SMEM_PAIRS \
         and (scope is None or scope[0].size == 1)
 
 
@@ -345,7 +352,9 @@ def _pair_sum(ys, coef, place, total, name, interpret):
     rows are never read. A tile of tokens visits only its pairs here: the
     pairs here, listed in token order (one sort of the k N pair keys),
     each packed as (row, pair within the tile), and where each tile's
-    run of them starts."""
+    run of them starts. The list and the starts are prefetched whole
+    into the scalar memory (1 MiB: 4 bytes a pair, 131,072 pairs at top-8
+    of 16,384 positions), the tile's coefficients a block a step."""
     rows, blocks, lanes = ys.shape
     n, k = coef.shape
     d = blocks * lanes
@@ -356,9 +365,8 @@ def _pair_sum(ys, coef, place, total, name, interpret):
     packed = place[listed] * tile + listed % tile
     starts = jnp.concatenate([jnp.zeros(1, jnp.int32), jnp.cumsum(
         jnp.sum(here.reshape(-1, tile), axis=1, dtype=jnp.int32))])
-    weights = coef.reshape(-1)[listed].astype(_F32)
 
-    def kernel(packed_ref, weights_ref, starts_ref, ys_hbm, out_ref,
+    def kernel(packed_ref, starts_ref, weights_ref, ys_hbm, out_ref,
                rows_ref, acc_ref, sem):
         i = pl.program_id(0)
         first, last = starts_ref[i], starts_ref[i + 1]
@@ -368,9 +376,9 @@ def _pair_sum(ys, coef, place, total, name, interpret):
 
         @pl.loop(first, last)
         def _(j):
-            token = packed_ref[j] % tile // k
-            acc_ref[token] += rows_ref[j - first].astype(_F32) \
-                * weights_ref[j]
+            pair = packed_ref[j] % tile
+            acc_ref[pair // k] += rows_ref[j - first].astype(_F32) \
+                * weights_ref[pair]
 
         out_ref[...] = acc_ref[...].astype(out_ref.dtype).reshape(
             out_ref.shape)
@@ -378,10 +386,12 @@ def _pair_sum(ys, coef, place, total, name, interpret):
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3, grid=(n // _ROW_TILE,),
-            in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+            num_scalar_prefetch=2, grid=(n // _ROW_TILE,),
+            in_specs=[pl.BlockSpec((tile,), lambda i, p, s: (i,),
+                                   memory_space=pltpu.SMEM),
+                      pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=pl.BlockSpec((_ROW_TILE, d),
-                                   lambda i, p, w, s: (i, 0)),
+                                   lambda i, p, s: (i, 0)),
             scratch_shapes=[
                 pltpu.VMEM((tile, blocks, lanes), ys.dtype),
                 pltpu.VMEM((_ROW_TILE, blocks, lanes), _F32),
@@ -391,7 +401,7 @@ def _pair_sum(ys, coef, place, total, name, interpret):
         cost_estimate=pl.CostEstimate(flops=2 * rows * d, transcendentals=0,
                                       bytes_accessed=(rows + n) * d * 2),
         interpret=interpret, name=name,
-    )(packed, weights, starts, ys)
+    )(packed, starts, coef.reshape(-1).astype(_F32), ys)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -616,8 +626,10 @@ _second.defvjp(_second_fwd, _second_bwd)
 
 def experts(x, order, place, sizes, weights, w_in, w_out, k, kept):
     """The fused form of ``ops/moe.py``'s gather, ``experts`` and
-    ``combine``: [N, d], each token's weighted sum over its pairs here.
-    ``kept`` names the first product for a recomputed block's policy."""
+    ``combine``: ([N, d], each token's weighted sum over its pairs here;
+    the rows the products' row tiles cover, int32: a tile that two groups
+    share counts once for each). ``kept`` names the first product for a
+    recomputed block's policy."""
     rows = order.shape[0]
     meta, steps = groups(sizes, rows=rows, visit_empty=False)
     meta_t, steps_t = groups(sizes, rows=rows, visit_empty=True)
@@ -625,4 +637,4 @@ def experts(x, order, place, sizes, weights, w_in, w_out, k, kept):
     h = kept(_first(x, w_in, order, place, total, (meta, meta_t),
                     (steps, steps_t), k))
     return _second(h, w_out, weights, order, place, total, (meta, meta_t),
-                   (steps, steps_t), k)
+                   (steps, steps_t), k), steps * _ROW_TILE

@@ -113,7 +113,7 @@ def test_the_layer_is_its_sum_written_out_token_by_token():
     w_in = rng.standard_normal((held, d, 2 * width)).astype(np.float32) * 0.4
     w_out = rng.standard_normal((held, width, d)).astype(np.float32) * 0.4
     valid = np.arange(n) < 26
-    out, here, busiest = moe_ops.moe(
+    out, here, busiest, _ = moe_ops.moe(
         jnp.asarray(x), jnp.asarray(valid), jnp.asarray(router),
         jnp.asarray(bias), jnp.asarray(w_in), jnp.asarray(w_out), k, first)
     want = _written_out(x.astype(np.float64), valid, router, bias, w_in,
@@ -159,7 +159,9 @@ def test_a_recomputed_block_hands_its_layers_counters_out():
                            grads)
     assert read[True][1] == read[False][1]
     counts = read[True][1]
-    assert sorted(counts) == sorted(step_counts.COUNTS)
+    # the plain form observes no row tiles
+    assert sorted(counts) == sorted(
+        set(step_counts.COUNTS) - {"paddle_tpu_moe_rows_visited"})
     # layer b holds every expert and takes one choice of 17 valid tokens
     assert counts["paddle_tpu_moe_rows_here"] >= 17
     assert counts["paddle_tpu_moe_expert_load_max"] >= 17 / 4
@@ -250,7 +252,7 @@ def _layer(inputs, kept=lambda product: product):
     """(loss, rows here) and the gradients by x, the router, w_in and
     w_out."""
     def loss(x, router, w_in, w_out):
-        out, here, _ = moe_ops.moe(x, inputs["valid"], router,
+        out, here, _, _ = moe_ops.moe(x, inputs["valid"], router,
                                    inputs["bias"], w_in, w_out, _K, _FIRST,
                                    kept=kept)
         return jnp.sum(out * inputs["cot"]), here
@@ -263,7 +265,7 @@ def _by_form(inputs, monkeypatch, kept=lambda product: product):
     got = {}
     for form in ("plain", "fused"):
         monkeypatch.setattr(pk, "_INTERPRET", form == "fused")
-        assert moe_ops.experts_form(_D, _W, _N) == form
+        assert moe_ops.experts_form(_D, _W, _N, _K) == form
         got[form] = _layer(inputs, kept)
     return got
 
@@ -396,7 +398,7 @@ def test_rows_outside_the_groups_never_reach_a_sum(form, small_tiles,
         monkeypatch.setattr(moe_ops, "_grouped", lambda rows, w, sizes:
                             _poisoned(grouped, lambda a: jnp.sum(sizes))(
                                 rows, w, sizes))
-    assert moe_ops.experts_form(_D, _W, _N) == form
+    assert moe_ops.experts_form(_D, _W, _N, _K) == form
     _agree(_layer(inputs), want)
 
 
@@ -424,6 +426,19 @@ def test_the_gauges_count_the_expert_layers_by_form(width, interpret, want,
     topo.apply(topo.init_params(jax.random.PRNGKey(0)), {"x": x},
                mode="train")
     assert _moe_gauges() == want
+
+
+@pytest.mark.parametrize("tokens,k,form", [
+    (8192, 4, "fused"), (16384, 8, "fused"), (32768, 8, "plain"),
+    (16384, 16, "plain")])
+def test_the_fused_form_needs_its_pair_lists_in_the_scalar_memory(
+        tokens, k, form, monkeypatch):
+    """The gathers and the pair sums prefetch an int32 a pair whole into
+    the 1 MiB scalar memory: lfm2's top-4 of 8,192 positions and laguna's
+    top-8 of 16,384 (131,072 pairs, 512 KiB) compile for v5e; twice
+    laguna's pairs take the plain form before Mosaic could refuse."""
+    monkeypatch.setattr(pk, "_INTERPRET", True)
+    assert moe_ops.experts_form(2048, 512, tokens, k) == form
 
 
 def test_the_cost_of_the_kernels_from_shapes():

@@ -410,14 +410,14 @@ def test_the_expert_layer_compiles_for_v5e_at_the_published_size(
     if form == "fused":
         monkeypatch.setattr(pk, "_INTERPRET", True)
         monkeypatch.setattr(pk, "_interpret", lambda: False)
-    assert moe_ops.experts_form(2048, 1792, 8192) == form
+    assert moe_ops.experts_form(2048, 1792, 8192, 4) == form
 
     def shape(dims, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
 
     def total(x, router, w_in, w_out, bias, valid):
-        out, here, busiest = moe_ops.moe(x, valid, router, bias, w_in, w_out,
-                                         4, 0)
+        out, here, busiest, _ = moe_ops.moe(x, valid, router, bias, w_in,
+                                            w_out, 4, 0)
         return jnp.sum(out.astype(jnp.float32)), (here, busiest)
 
     was = jax.config.jax_enable_compilation_cache
