@@ -137,6 +137,9 @@ class Context:
         # this trace's Mamba-1 scans by the form they took
         # (ops/ssm.py selective_scan_form)
         self.selective_scans = {"fused": 0, "plain": 0}
+        # this trace's attention calls by the form they took
+        # (ops/attention.py attention_form)
+        self.attentions = {"fused": 0, "plain": 0}
         # this trace's expert layers: the experts each holds of how many,
         # the rows of their sorted buffers, all layers, and the layers by
         # the form their row passes took (layer.moe, ops/moe.py

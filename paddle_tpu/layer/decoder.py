@@ -622,7 +622,10 @@ def gqa_attention(input, heads, kv_heads, head_dim, scale=None, block=512,
     """Causal self-attention with ``heads`` query heads over ``kv_heads``
     shared key-value heads, no positional encoding and no bias; scores are
     multiplied by ``scale`` (1 / sqrt(head_dim) by default). Blockwise
-    (``ops/attention.py``): no [T, T] score matrix is held. With
+    (``ops/attention.py``): no [T, T] score matrix is held; on the TPU,
+    where the shapes tile, as ``ops/pallas_attention.py``'s kernels, and
+    the gauges ``paddle_tpu_attention_fused`` / ``_plain`` count a traced
+    step's layers by form. With
     ``qk_norm`` queries and keys are RMS-normalised with a learned scale,
     each over its whole projection, before the split into heads (the
     OLMo 2 layout), or with ``qk_norm="head"`` each head over its own
@@ -742,6 +745,9 @@ def gqa_attention(input, heads, kv_heads, head_dim, scale=None, block=512,
                 ctx.attention_key_blocks[visited] += sum(
                     end - first for first, end in attention_ops.key_blocks(
                         t, block, True, w))
+            ctx.attentions[attention_ops.attention_form(
+                heads, kv_heads, head_dim,
+                head_dim * (2 if differential is not None else 1))] += 1
             with jax.named_scope("paddle_tpu.window_attention") \
                     if window is not None else contextlib.nullcontext():
                 y = attend(q, k, v)

@@ -5,7 +5,11 @@ block of keys into a running (row max, normaliser, unnormalised output).
 ``parallel/context_parallel.py`` steps it once per ring hop (and once in
 all for ``full_attention``); :func:`blockwise_attention` steps it over
 blocks of keys inside one device, a block of queries at a time, which
-is what ``layer.gqa_attention`` runs.
+is what ``layer.gqa_attention`` runs. Where :func:`attention_form` says
+"fused" (the TPU, shapes that tile), ``blockwise_attention`` runs as
+``ops/pallas_attention.py``'s kernels instead, the same arithmetic with
+each tile's scores kept in VMEM; only :func:`attention_form` and that
+branch import the module.
 """
 
 import functools
@@ -82,6 +86,22 @@ def key_blocks(t, block, causal=True, window=None):
              i + 1 if causal else n) for i in range(n)]
 
 
+def attention_form(heads, kv_heads, d, dv):
+    """"fused" where :func:`blockwise_attention` over ``heads`` query
+    heads, ``kv_heads`` key-value heads, keys of ``d`` and values of
+    ``dv`` runs ``ops/pallas_attention.py``'s kernels, "plain" where it
+    runs the scans below: the backend and the shapes decide
+    (``pallas_attention.fits``), nothing else."""
+    from paddle_tpu.ops import pallas_kernels
+
+    if not pallas_kernels.enabled():
+        return "plain"
+    from paddle_tpu.ops import pallas_attention
+
+    return "fused" if pallas_attention.fits(heads, kv_heads, d, dv) \
+        else "plain"
+
+
 def blockwise_attention(q, k, v, scale, causal=True, lengths=None,
                         block=512, window=None):
     """Attention of q [B, T, H, D] over k [B, T, KV, D] and v
@@ -93,7 +113,15 @@ def blockwise_attention(q, k, v, scale, causal=True, lengths=None,
     diagonal block. ``lengths`` [B] hides the keys of the padded tail.
     With ``window`` (causal) a query sees the ``window`` keys that end
     with its own: key blocks wholly older than that are not visited
-    (:func:`key_blocks`), the block on the window's edge is masked."""
+    (:func:`key_blocks`), the block on the window's edge is masked. Runs
+    in the form :func:`attention_form` chooses; the kernels tile by
+    ``pallas_attention.tile``, not by ``block``."""
+    if attention_form(q.shape[2], k.shape[2], q.shape[3],
+                      v.shape[3]) == "fused":
+        from paddle_tpu.ops import pallas_attention
+
+        return pallas_attention.attention(q, k, v, scale, causal, lengths,
+                                          window)
     b, t, h, d = q.shape
     groups = h // k.shape[2]
     if groups > 1:

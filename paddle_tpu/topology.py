@@ -169,7 +169,8 @@ class Topology:
             # blocks keep for backward, what they hand to later blocks
             # (layer/decoder.py recompute), which key blocks attention
             # visits (gqa_attention), which form its Mamba-1 scans took
-            # (mamba1), what its expert layers hold and which form
+            # (mamba1), which form its attention calls took
+            # (gqa_attention), what its expert layers hold and which form
             # their row passes took (moe)
             registry = observe_metrics.get_registry()
             for name, value, help_ in (
@@ -194,6 +195,12 @@ class Topology:
                     ("selective_scan_plain", ctx.selective_scans["plain"],
                      "Mamba-1 scans of a train step that run as plain "
                      "loops"),
+                    ("attention_fused", ctx.attentions["fused"],
+                     "attention calls of a train step that run as the "
+                     "fused Pallas kernels"),
+                    ("attention_plain", ctx.attentions["plain"],
+                     "attention calls of a train step that run as plain "
+                     "key-block scans"),
                     ("moe_experts_held", ctx.moe["held"],
                      "experts each expert layer of a train step holds "
                      "here"),

@@ -459,3 +459,47 @@ def test_the_expert_layer_compiles_for_v5e_at_the_published_size(
             line[:200]
     # the parent's plain form read 1,026,910,208 bytes
     assert temp < 1_026_910_208
+
+
+@pytest.mark.parametrize("b,heads,kv,d,dv,window", [
+    (4, 48, 8, 128, 128, None), (2, 40, 20, 64, 128, 512)])
+def test_the_attention_kernels_compile_for_v5e_at_the_cells_size(
+        one_chip, b, heads, kv, d, dv, window, monkeypatch):
+    """`ops/attention.py blockwise_attention`, forward and backward, for a
+    described v5e at laguna's full layer (4 rows of 4,096 positions, 48
+    query heads over 8 of 128) and phi's window layer (2 rows, 40 over 20
+    of 64, values of 128, 512 keys): the three named kernels of
+    `ops/pallas_attention.py` and no key-block loop. (Here, beside the
+    other kernels, because only one test file may describe the chip.)"""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from paddle_tpu.ops import attention as attention_ops
+
+    monkeypatch.setattr(pk, "_INTERPRET", True)
+    monkeypatch.setattr(pk, "_interpret", lambda: False)
+    assert attention_ops.attention_form(heads, kv, d, dv) == "fused"
+
+    def shape(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def total(q, k, v, lengths):
+        return jnp.sum(attention_ops.blockwise_attention(
+            q, k, v, d ** -0.5, True, lengths, 512, window).astype(
+                jnp.float32))
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        text = jax.jit(jax.grad(total, argnums=range(3))).lower(
+            shape((b, 4096, heads, d)), shape((b, 4096, kv, d)),
+            shape((b, 4096, kv, dv)), shape((b,), jnp.int32)
+        ).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+    names = ("attention_fwd", "attention_bwd_dq", "attention_bwd_dkv")
+    assert text.count("tpu_custom_call") == len(names)
+    for name in names:
+        assert re.search(r"%%%s(\.\d+)? = .*tpu_custom_call" % name, text), name
+    assert not re.search(r" while\(", text)
